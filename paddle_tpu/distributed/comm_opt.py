@@ -280,7 +280,7 @@ class CommOptTrainStep:
         # donate the state buffers (in-place update in HBM) on real
         # accelerators only: on the CPU backend a DESERIALIZED SPMD
         # executable with input-output aliasing mis-executes (wrong
-        # loss / NaN / segfault on teardown — jax 0.4.x), which would
+        # loss / NaN / segfault on teardown), which would
         # poison the warm-start path this program's AOT entry exists
         # for. Same policy as the serving engine's KV buffers.
         donate = (1, 2) if jax.default_backend() != "cpu" else ()
@@ -459,7 +459,7 @@ class CommOptTrainStep:
 
     def _step(self, param_vals, opt_state, ef, buffer_vals, batch, keys,
               lr):
-        from jax.experimental.shard_map import shard_map
+        from .mesh import shard_map
 
         dp, chunk = self.dp, self.chunk
         loss_of = self._loss_of()
@@ -530,7 +530,7 @@ class CommOptTrainStep:
                       buf_specs, batch_specs, P("dp", None), P()),
             out_specs=(P("dp", "tp"), self._param_specs, self._opt_specs,
                        self._ef_specs, buf_specs),
-            check_rep=False)
+            check_vma=False)
         return fn(param_vals, opt_state, ef, buffer_vals, batch, keys, lr)
 
     # -- program resolution (aot.CompileService) ----------------------------

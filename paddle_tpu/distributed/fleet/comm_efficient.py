@@ -377,9 +377,7 @@ class DGCTrainStep:
         return _unflatten_by(flat, self._order, self._shapes, self._sizes)
 
     def _step(self, param_vals, u, v, batch, key, lr):
-        # jax 0.4.x: shard_map lives under jax.experimental (the
-        # top-level jax.shard_map + check_vma spelling is newer jax)
-        from jax.experimental.shard_map import shard_map
+        from ..mesh import shard_map
 
         loss_of = _loss_of(self.model, self._params, self.loss_fn)
         micro = _split_batch(batch, self.dp)
@@ -420,7 +418,7 @@ class DGCTrainStep:
                       P("dp", None)),
             out_specs=(P("dp"), P(None, None), P("dp", None),
                        P("dp", None)),
-            check_rep=False)
+            check_vma=False)
         loss, g_comb, u, v = fn(param_vals, u, v, micro, keys)
         g_tree = self._unflatten(g_comb[0])
         newp = {k: (param_vals[k].astype(jnp.float32)
@@ -502,8 +500,7 @@ class CompressedAllreduceTrainStep:
         return _unflatten_by(flat, self._order, self._shapes, self._sizes)
 
     def _step(self, param_vals, opt_state, batch, key, lr):
-        # jax 0.4.x import path (see DGCTrainStep._step)
-        from jax.experimental.shard_map import shard_map
+        from ..mesh import shard_map
 
         loss_of = _loss_of(self.model, self._params, self.loss_fn)
         micro = _split_batch(batch, self.dp)
@@ -562,7 +559,7 @@ class CompressedAllreduceTrainStep:
             per_replica, mesh=self._mesh,
             in_specs=(spec_rep, spec_dp0, P("dp", None)),
             out_specs=(P("dp"), P(None, None)),
-            check_rep=False)
+            check_vma=False)
         loss, g_avg = fn(param_vals, micro, keys)
         g_tree = self._unflatten(g_avg[0])
         grads = {k: g_tree[k].astype(param_vals[k].dtype)

@@ -8,6 +8,13 @@ the PADDLE_* env contract, a crashed worker tears down (and with
 ``--max_restarts`` relaunches) the whole local gang — the reference
 launcher's watch/restart loop. ``--nproc_per_node 1`` (TPU pods: one
 process per host under the jax multi-controller runtime) execs in-process.
+
+On a TPU host use ``--nproc_per_node 1``: the repo's design is ONE process
+driving every local chip. The gang's workers each inherit the full
+environment, so on a four-chip host each would claim all four chips, and
+a chip belongs to one process at a time. ``chip_smoke.py --chips 4`` runs
+the hybrid-parallel step that way and never goes through this launcher;
+gangs of more than one worker are for CPU workers and tests.
 """
 from __future__ import annotations
 

@@ -15,10 +15,12 @@ the fastest links (scaling-book layout).
 """
 from __future__ import annotations
 
+import warnings
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401  (the package's one import site)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 AXES = ("pp", "dp", "sharding", "sep", "tp")
@@ -28,6 +30,14 @@ _global_mesh: Optional[Mesh] = None
 
 def build_mesh(dp: int = 1, tp: int = 1, pp: int = 1, sharding: int = 1,
                sep: int = 1, devices: Optional[Sequence] = None) -> Mesh:
+    """The hybrid mesh over ``devices`` (default: every device jax has).
+
+    No degrees at all means data parallel over all of them. Degrees that
+    multiply to fewer than the devices present take the first few (parity
+    testing on a virtual mesh, a tensor-parallel replica on part of a
+    host; the reference requires product == world_size) and say so in a
+    warning: a run that believes it spans a host must not sit on a part
+    of it without a word."""
     devices = list(devices) if devices is not None else jax.devices()
     need = dp * tp * pp * sharding * sep
     if need == 1:
@@ -37,10 +47,26 @@ def build_mesh(dp: int = 1, tp: int = 1, pp: int = 1, sharding: int = 1,
         raise ValueError(
             f"mesh degrees {dp}x{sharding}x{tp}x{pp}x{sep}={need} > "
             f"{len(devices)} devices")
-    # fewer degrees than devices: run on a subset (parity testing on a
-    # virtual mesh; the reference requires product == world_size)
+    if need < len(devices):
+        warnings.warn(
+            f"mesh degrees dp={dp} sharding={sharding} tp={tp} pp={pp} "
+            f"sep={sep} use the first {need} of {len(devices)} devices",
+            stacklevel=2)
     arr = np.asarray(devices[:need]).reshape(pp, dp, sharding, sep, tp)
     return Mesh(arr, AXES)
+
+
+def partial_manual(fn, mesh: Mesh, manual, in_specs, out_specs):
+    """``fn`` under a shard_map that is manual over the axes ``manual``
+    only: placement on the other axes stays with the GSPMD partitioner, so
+    the call nests inside a pjit program. With ``check_vma=False`` jax
+    0.9.0 breaks a partial-manual shard_map (its internal unmatch spec then
+    names every mesh axis), so the check stays on whenever some axis stays
+    automatic."""
+    manual = frozenset(manual)
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     axis_names=manual,
+                     check_vma=frozenset(mesh.axis_names) != manual)
 
 
 def set_mesh(mesh: Mesh):

@@ -53,6 +53,24 @@ def get_jax_device(device: str | None = None):
     return matching[min(idx, len(matching) - 1)]
 
 
+def use_compile_cache() -> str:
+    """Place jax's persistent compilation cache, before the first compile.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins: jax reads it itself, so nothing is
+    set in code. Otherwise the cache is ``<repo root>/.jax_cache`` as an
+    absolute path, whatever the working directory: the path is part of the
+    cache's key, so a directory that moves never hits. Returns the
+    directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    path = os.path.join(root, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def is_compiled_with_cuda() -> bool:
     return False
 

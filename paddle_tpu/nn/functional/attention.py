@@ -64,6 +64,14 @@ def _record(path, reason):
     _last_path["reason"] = reason
 
 
+def forced_path():
+    """"xla" or "flash" where PADDLE_TPU_ATTENTION says so, else None."""
+    import os
+
+    forced = os.environ.get("PADDLE_TPU_ATTENTION", "")
+    return forced if forced in ("xla", "flash") else None
+
+
 def sdpa_raw(q, k, v, causal=False, scale=None):
     """Raw-array causal/full attention with TPU flash routing ([B,L,H,D]).
 
@@ -71,9 +79,9 @@ def sdpa_raw(q, k, v, causal=False, scale=None):
     (e.g. the stacked pipelined Llama). The pallas flash kernel is used
     whenever eligible on TPU; kernel failures propagate (no silent XLA
     fallback). Set PADDLE_TPU_ATTENTION=xla to force the XLA composite."""
-    import os
+    from ...ops.pallas.flash_attention import flash_attention
 
-    forced = os.environ.get("PADDLE_TPU_ATTENTION", "")
+    forced = forced_path()
     if forced == "xla":
         _record("xla", "forced via PADDLE_TPU_ATTENTION")
         return _xla_sdpa(q, k, v, causal=causal, scale=scale)
@@ -81,12 +89,60 @@ def sdpa_raw(q, k, v, causal=False, scale=None):
                 and q.shape[1] % 128 == 0 and q.shape[-1] <= 256
                 and jax.default_backend() == "tpu")
     if eligible or forced == "flash":
-        from ...ops.pallas.flash_attention import flash_attention
         _record("flash", "eligible on tpu" if eligible else "forced")
-        return flash_attention(q, k, v, causal=causal, scale=scale)
+        place = (flash_placement(q.shape[0], q.shape[2], k.shape[2])
+                 if isinstance(q, jax.core.Tracer) else None)   # eager
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               place=place)
     _record("xla", f"ineligible: dtype={q.dtype} shape={q.shape} "
                    f"backend={jax.default_backend()}")
     return _xla_sdpa(q, k, v, causal=causal, scale=scale)
+
+
+def flash_placement(batch, heads, kv_heads):
+    """How the trace's context must place a flash kernel call whose arrays
+    are laid out [batch, heads, ...]: ``None`` where it can run as it is,
+    else a function that wraps the per-device call in a shard_map.
+
+    A Mosaic kernel is legal only where every mesh axis is manual (GSPMD
+    cannot partition it: "wrap the call in a shard_map"), so the wrapper
+    makes manual whatever axes are not manual yet, and splits over them
+    what attention computes independently: batch over the data axes, heads
+    over "tp". A dim its axes do not divide stays whole, and every device
+    of those axes computes all of it. Three contexts reach here:
+
+    * plain jit (the GSPMD train step): the global mesh, every axis;
+    * a partial-manual shard_map (the pipeline's "pp", the sequence
+      parallel "sep"): that shard_map's mesh, the axes it left automatic;
+    * a full-manual shard_map (the comm-opt, DGC and compressed-allreduce
+      steps, a caller's own): nothing is left, the body is per-device
+      already. So is a mesh of one device."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...distributed.mesh import get_mesh, shard_map
+
+    ctx = jax.sharding.get_abstract_mesh()
+    outer = frozenset(ctx.manual_axes)
+    # inside a shard_map the mesh is that shard_map's, which need not be
+    # the global one (ulysses_attention(mesh=...), ring_attention(mesh=...))
+    mesh = ctx if outer else get_mesh()
+    free = {a: n for a, n in mesh.shape.items() if a not in outer}
+    if not free or (not outer and mesh.size == 1):
+        return None
+    data = tuple(a for a in ("dp", "sharding") if free.get(a, 1) > 1)
+    tp = free.get("tp", 1)
+    spec = P(
+        data if data and batch % math.prod(free[a] for a in data) == 0
+        else None,
+        "tp" if tp > 1 and heads % tp == 0 and kv_heads % tp == 0 else None)
+    # one spec fits every array the kernels take and give: [B, H, ...].
+    # Nested, the outer shard_map is partial-manual and so checks varying
+    # axes (mesh.partial_manual); its types reach in here, and the
+    # kernels' outputs must carry them.
+    return lambda fn: shard_map(
+        fn, mesh=None if outer else mesh,         # nested: the context's
+        in_specs=spec, out_specs=spec, axis_names=frozenset(free),
+        check_vma=bool(outer))
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,

@@ -71,7 +71,7 @@ def _dim_numbers(n, channel_last):
 # follows the inputs unless preferred_element_type is requested. Requesting
 # fp32 outputs under autodiff breaks the conv transpose (grad) rule: the
 # cotangent arrives as fp32 while lhs stays bf16, and conv_general_dilated
-# rejects the mix (verified on jax 0.4.37). So fp32 accumulation is an
+# rejects the mix. So fp32 accumulation is an
 # INFERENCE-ONLY, opt-in policy: inside conv_accum_fp32() regions, bf16
 # convs request fp32 accumulation and cast the result back to bf16. The
 # channels-last inference wrapper (framework/layout.py) enables it for

@@ -57,6 +57,43 @@ MOSAIC_SR_TARGETS = ("bfloat16", "float8_e5m2", "float8_e4m3fn",
                      "float8_e4m3b11fnuz")
 
 
+def _row_blocked(kernel, x, scalars, out_dtype, interpret):
+    """Run an elementwise PRNG kernel over [rows, cols] fp32 ``x`` on a
+    grid of row blocks of about 1 MB (whole arrays in VMEM stop fitting
+    at a [4096, 4096] weight). ``kernel(x_ref, *scalar_refs, o_ref)``;
+    ``scalars`` ride in SMEM. Blocks are multiples of 32 rows, the int8
+    sublane tile."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, cols = x.shape
+    br = max(32, (1 << 20) // (4 * cols) // 32 * 32)
+    if br >= rows:
+        br = rows
+    block = pl.BlockSpec((br, cols), lambda i: (i, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(rows, br),),
+        in_specs=[block] + [pl.BlockSpec(memory_space=pltpu.SMEM)
+                            for _ in scalars],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((rows, cols), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(x, *scalars)
+
+
+def _block_random_bits(seed_ref, shape):
+    """int32 PRNG words for this grid step's block: the stream is seeded
+    by (seed, block index) so blocks draw independent bits."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    pltpu.prng_seed(seed_ref[0], pl.program_id(0))
+    return pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.int32)
+
+
 def stochastic_round(x, dtype=jnp.bfloat16, seed: int = 0,
                      interpret: bool = False):
     """fp32 → low-precision-float stochastic rounding (pallas PRNG).
@@ -65,7 +102,6 @@ def stochastic_round(x, dtype=jnp.bfloat16, seed: int = 0,
     rounding is the classic add-uniform-to-discarded-mantissa-bits
     construction (int ops + bitcasts only, so Mosaic never sees an
     unsupported narrowing cast)."""
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     dt = jnp.dtype(dtype)
@@ -79,8 +115,7 @@ def stochastic_round(x, dtype=jnp.bfloat16, seed: int = 0,
             "only the bf16 target is implemented on this backend")
 
     def kernel(x_ref, seed_ref, o_ref):
-        pltpu.prng_seed(seed_ref[0])
-        bits = pltpu.bitcast(pltpu.prng_random_bits(x_ref.shape), jnp.int32)
+        bits = _block_random_bits(seed_ref, x_ref.shape)
         # add U[0, 2^16) to the 16 mantissa bits bf16 truncation drops:
         # carries propagate into the kept bits with probability equal to
         # the dropped fraction — exactly stochastic rounding to bf16
@@ -94,17 +129,9 @@ def stochastic_round(x, dtype=jnp.bfloat16, seed: int = 0,
         # Mosaic has to reroute)
         o_ref[:] = pltpu.bitcast(kept, jnp.float32)
 
-    rows, cols = x.shape
-    out = pl.pallas_call(
-        kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY
-                               if interpret else pltpu.VMEM),
-                  pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY
-                               if interpret else pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows, cols), jnp.float32),
-        interpret=interpret,
-    )(x.astype(jnp.float32), jnp.asarray([seed], dtype=jnp.int32))
+    out = _row_blocked(kernel, x.astype(jnp.float32),
+                       [jnp.asarray([seed], dtype=jnp.int32)],
+                       jnp.float32, interpret)
     return out.astype(jnp.bfloat16)
 
 
@@ -113,45 +140,30 @@ def quantize_int8_stochastic(w, seed: int = 0, interpret: bool = False):
 
     w: [rows, cols] raw array; per-tensor scale. Returns (int8, scale[1,1]).
     """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    w = w.astype(jnp.float32)
+    # per-tensor: the max spans every block, so it is taken before the grid
+    scale = jnp.maximum(jnp.max(jnp.abs(w)) / 127.0, 1e-10).reshape(1, 1)
 
-    def kernel(x_ref, seed_ref, q_ref, s_ref):
-        pltpu.prng_seed(seed_ref[0])
-        amax = jnp.max(jnp.abs(x_ref[:]))
-        scale = jnp.maximum(amax / 127.0, 1e-10)
-        s_ref[0, 0] = scale
-        scaled = x_ref[:] / scale
+    def kernel(x_ref, seed_ref, s_ref, q_ref):
+        scaled = x_ref[:] / s_ref[0, 0]
         # Mosaic's stochastic_round primitive only targets float dtypes
         # (MOSAIC_SR_TARGETS); integer stochastic rounding is floor(x+u)
         # with u ~ U[0,1): E[q] == x. Keep the PRNG word in int32 lanes
         # (shift_right_logical, no uint casts) and narrow the result via
         # fp32 → int32 → int8 — current libtpu rewrites both unsigned
         # converts and direct fp32→int8 truncation onto the
-        # stochastic_round lowering, which rejects integer targets
-        # (BENCH_r05 kernel-gate failure).
-        bits = pltpu.bitcast(pltpu.prng_random_bits(scaled.shape),
-                             jnp.int32)
+        # stochastic_round lowering, which rejects integer targets.
+        bits = _block_random_bits(seed_ref, scaled.shape)
         u = jax.lax.shift_right_logical(bits, 8).astype(jnp.float32) \
             * (1.0 / (1 << 24))
         q = jnp.floor(scaled + u)
         q32 = jnp.clip(q, -127.0, 127.0).astype(jnp.int32)
         q_ref[:] = q32.astype(jnp.int8)
 
-    rows, cols = w.shape
-    q, s = pl.pallas_call(
-        kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY
-                               if interpret else pltpu.VMEM),
-                  pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY
-                                if interpret else pltpu.VMEM),
-                   pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_shape=[jax.ShapeDtypeStruct((rows, cols), jnp.int8),
-                   jax.ShapeDtypeStruct((1, 1), jnp.float32)],
-        interpret=interpret,
-    )(w.astype(jnp.float32), jnp.asarray([seed], dtype=jnp.int32))
-    return q, s
+    q = _row_blocked(kernel, w,
+                     [jnp.asarray([seed], dtype=jnp.int32), scale],
+                     jnp.int8, interpret)
+    return q, scale
 
 
 class Int8Linear(Layer):

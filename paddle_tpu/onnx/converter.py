@@ -20,10 +20,7 @@ import itertools
 
 import numpy as np
 
-try:  # jax >= 0.4.16
-    from jax.extend.core import Literal
-except ImportError:  # pragma: no cover
-    from jax.core import Literal
+from jax.extend.core import Literal
 
 from .proto import onnx_pb2 as P
 

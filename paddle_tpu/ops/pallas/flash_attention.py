@@ -79,11 +79,7 @@ def _recompute_p_ds(q, k, v, do, lse, delta, mask, scale):
     return p, ds
 
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
-_PARALLEL_SEMANTICS = _CompilerParams(
+_PARALLEL_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
@@ -134,6 +130,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lse_ref[0, 0] = (m_scr[:] + jnp.log(safe_l)).astype(jnp.float32)
 
 
+def _out(shape, dtype, *like):
+    """A pallas_call output that varies over the manual mesh axes its
+    inputs ``like`` vary over: a shard_map that checks varying axes asks
+    every output to say (the set is empty anywhere else)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
 def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     """q: [B, Hq, Lq, D], k/v: [B, Hkv, Lk, D] → (out, lse[B, Hq, Lq])."""
     B, Hq, Lq, D = q.shape
@@ -172,8 +176,8 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
                          lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, qp, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, qp, _LANES), jnp.float32),
+            _out((B, Hq, qp, D), q.dtype, q, k, v),
+            _out((B, Hq, qp, _LANES), jnp.float32, q, k, v),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
@@ -297,7 +301,7 @@ def _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret):
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D),
                                lambda b, h, iq, ik: (b, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, qp, D), q.dtype),
+        out_shape=_out((B, Hq, qp, D), q.dtype, q, k, v, do),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_PARALLEL_SEMANTICS,
         interpret=interpret,
@@ -329,8 +333,8 @@ def _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret):
                          lambda b, h, ik, iq: (b, h, ik, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, kp, D), k.dtype),
-            jax.ShapeDtypeStruct((B, Hq, kp, D), v.dtype),
+            _out((B, Hq, kp, D), k.dtype, q, k, v, do),
+            _out((B, Hq, kp, D), v.dtype, q, k, v, do),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
@@ -352,34 +356,41 @@ def _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret):
 # custom_vjp wrapper ([B, H, L, D] layout)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhld(q, k, v, causal, scale, block_q, block_k, interpret):
-    out, _ = _fwd(q, k, v, causal, scale, block_q, block_k, interpret)
-    return out
+def _where_it_is(fn):
+    return fn
 
 
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret):
-    out, lse = _fwd(q, k, v, causal, scale, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_bhld(q, k, v, place, static):
+    return place(lambda *a: _fwd(*a, *static))(q, k, v)[0]
+
+
+def _flash_fwd_rule(q, k, v, place, static):
+    out, lse = place(lambda *a: _fwd(*a, *static))(q, k, v)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, res, do):
-    q, k, v, out, lse = res
-    return _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
-                interpret)
+def _flash_bwd_rule(place, static, res, do):
+    return place(lambda *a: _bwd(*a, *static))(*res, do)
 
 
 _flash_bhld.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, interpret=False):
+                    block_k=None, interpret=False, place=None):
     """Flash attention on paddle layout [batch, seq, heads, head_dim].
 
     GQA supported when q heads are a multiple of kv heads. Returns the same
     layout/dtype as q. Differentiable (custom flash backward kernels).
     Block sizes default to 256x512 (VMEM-sized for D<=256 on v5e+) and can
     be pinned via PADDLE_TPU_FLASH_BLOCK_Q / PADDLE_TPU_FLASH_BLOCK_K.
+
+    ``place`` maps a per-device function over a mesh: it takes the forward
+    or the backward kernel call — arrays laid out [batch, heads, ...] in,
+    the same out — and returns it wrapped in a shard_map. The two are
+    placed one by one, inside the custom_vjp, so jax never differentiates
+    through a shard_map here (0.9.0 cannot, where one nests in another).
     """
     import os
 
@@ -395,6 +406,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     if qh.shape[1] % kh.shape[1] != 0:
         raise ValueError(
             f"q heads {qh.shape[1]} not a multiple of kv heads {kh.shape[1]}")
-    out = _flash_bhld(qh, kh, vh, causal, float(scale), int(block_q),
-                      int(block_k), bool(interpret))
+    out = _flash_bhld(qh, kh, vh, place or _where_it_is,
+                      (causal, float(scale), int(block_q), int(block_k),
+                       bool(interpret)))
     return jnp.swapaxes(out, 1, 2)

@@ -19,7 +19,10 @@ neighbour's output.
 
 GQA maps query head ``h`` onto kv head ``h // (H // n_kv)``; the grid
 tiles kv heads ``kv_heads_per_step`` at a time (the tuner's knob — more
-heads per step amortizes the block DMA, fewer keeps VMEM small).
+heads per step amortizes the block DMA, fewer keeps VMEM small). The
+pool block is ``(1, block_size, g, hd)`` with the tile ``g`` second to
+last, so the TPU lowering's (8, 128) rule admits only the tiles of
+:func:`legal_kv_heads_per_step`.
 
 Numerics match flash attention: bf16 operands into the MXU, fp32
 accumulation and softmax stats. The result is not bitwise-equal to the
@@ -35,11 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_decode", "flash_decode_reference"]
-
-# jax renamed TPUCompilerParams -> CompilerParams across versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+__all__ = ["flash_decode", "flash_decode_reference",
+           "legal_kv_heads_per_step"]
 
 _MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 _LANES = 128
@@ -97,6 +97,12 @@ def _kernel(tables_ref, wp_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
+def legal_kv_heads_per_step(n_kv):
+    """kv-head tiles the TPU lowering accepts, smallest first: a block's
+    second-to-last dim is a multiple of 8 or the whole axis."""
+    return [g for g in range(8, n_kv, 8) if n_kv % g == 0] + [n_kv]
+
+
 def flash_decode(q, kc_pool, vc_pool, tables, write_pos, *, scale=None,
                  kv_heads_per_step=None, interpret=False):
     """One-token paged attention: q [S, H, hd] against pools
@@ -104,8 +110,9 @@ def flash_decode(q, kc_pool, vc_pool, tables, write_pos, *, scale=None,
     [S, max_blocks] (int32), attending positions ``<= write_pos`` [S].
     Returns [S, H, hd] in q's dtype.
 
-    ``kv_heads_per_step`` tiles the kv-head axis (must divide n_kv);
-    defaults to the tuner's choice for the shape, falling back to 1.
+    ``kv_heads_per_step`` tiles the kv-head axis (one of
+    :func:`legal_kv_heads_per_step`); defaults to the tuner's choice for
+    the shape.
     """
     S, H, hd = q.shape
     nb, bs, n_kv, _ = kc_pool.shape
@@ -119,7 +126,7 @@ def flash_decode(q, kc_pool, vc_pool, tables, write_pos, *, scale=None,
         from ... import tuner as _tuner
         g = _tuner.get_config(
             "flash_decode", shapes=((S, H, hd), tuple(kc_pool.shape)),
-            dtype=str(q.dtype)).get("kv_heads_per_step", 1)
+            dtype=str(q.dtype))["kv_heads_per_step"]
     g = int(g)
     if n_kv % g:
         raise ValueError(f"kv_heads_per_step={g} must divide n_kv={n_kv}")
@@ -153,7 +160,7 @@ def flash_decode(q, kc_pool, vc_pool, tables, write_pos, *, scale=None,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(tables.astype(jnp.int32), write_pos.astype(jnp.int32), q, kc_pool,

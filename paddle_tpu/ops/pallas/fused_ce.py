@@ -33,9 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["fused_ce_stats", "fused_ce_loss", "sharded_vocab_ce",
            "fused_ce_reference"]
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 _MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 _LANES = 128
 
@@ -134,7 +131,7 @@ def fused_ce_stats(hidden, w, labels, *, vocab_offset=0, block_n=None,
             pltpu.VMEM((bn, _LANES), jnp.float32),
             pltpu.VMEM((bn, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(hidden, w, labels.astype(jnp.int32)[:, None])

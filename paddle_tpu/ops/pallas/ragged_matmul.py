@@ -27,9 +27,6 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["ragged_group_matmul", "ragged_dot",
            "ragged_group_matmul_reference"]
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _kernel(counts_ref, x_ref, w_ref, o_ref, *, block_m):
     g = pl.program_id(0)
@@ -87,7 +84,7 @@ def ragged_group_matmul(x, w, counts, *, block_m=None, block_n=None,
         functools.partial(_kernel, block_m=bm),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, cp, np_), out_dtype or x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(counts.astype(jnp.int32), x, w)

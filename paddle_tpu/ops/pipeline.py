@@ -23,46 +23,14 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
-
-def _partial_manual_guard(mesh, manual):
-    """jax 0.4.x cannot compile partial-manual shard_map nested under
-    the GSPMD partitioner (XLA aborts in backend_compile). Returns the
-    mesh to run on: the original when fully manual; a reduced
-    single-axis mesh over the same devices when every automatic axis is
-    trivial (size 1 — semantically full-manual); otherwise a python
-    error, never a process abort."""
-    auto = frozenset(mesh.axis_names) - frozenset(manual)
-    if not auto:
-        return mesh
-    if all(mesh.shape[a] == 1 for a in auto) and len(manual) == 1:
-        import numpy as _np
-        from jax.sharding import Mesh as _Mesh
-        name = next(iter(manual))
-        return _Mesh(_np.asarray(mesh.devices).reshape(
-            mesh.shape[name]), (name,))
-    raise NotImplementedError(
-        f"partial-manual shard_map over {sorted(manual)} with "
-        f"non-trivial automatic axes "
-        f"{sorted(a for a in auto if mesh.shape[a] > 1)} is "
-        "unsupported on jax 0.4.x (XLA aborts); build a mesh carrying "
-        "only the manual axis")
+from ..distributed.mesh import get_mesh, partial_manual
 
 
 def _pvary(x, axis_name):
-    """Mark x device-varying over axis_name (pcast on jax>=0.9, pvary
-    before the rename)."""
-    try:
-        return jax.lax.pcast(x, axis_name, to="varying")
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return jax.lax.pvary(x, axis_name)
-    except AttributeError:
-        # jax 0.4.x: no varying-type system (check_rep=False) — identity
-        return x
+    """Mark x device-varying over axis_name."""
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def _shift_right(x, axis_name, n):
@@ -123,7 +91,6 @@ def spmd_pipeline(stage_fn: Callable, stacked_params, x, *, mesh=None,
     Returns y with the same batch dim, computed as stages applied in order.
     """
     if mesh is None:
-        from ..distributed.mesh import get_mesh
         mesh = get_mesh()
     n_stages = mesh.shape[axis_name]
     if n_stages == 1:
@@ -141,21 +108,11 @@ def spmd_pipeline(stage_fn: Callable, stacked_params, x, *, mesh=None,
 
     param_specs = jax.tree_util.tree_map(
         lambda l: P(axis_name, *([None] * (l.ndim - 1))), stacked_params)
-    manual = frozenset({axis_name})
-    mesh = _partial_manual_guard(mesh, manual)
-    # jax 0.9 quirk: check_vma=False breaks partial-manual shard_map (its
-    # internal unmatch spec then names every mesh axis), so keep the vma
-    # check on whenever other mesh axes stay automatic
-    fn = shard_map(
+    fn = partial_manual(
         functools.partial(_pipeline_local, stage_fn=stage_fn,
                           axis_name=axis_name, n_stages=n_stages,
                           n_micro=n_micro),
-        mesh=mesh,
-        in_specs=(param_specs, P()),
-        out_specs=P(),
-        auto=frozenset(mesh.axis_names) - manual,
-        check_rep=False,
-    )
+        mesh, {axis_name}, in_specs=(param_specs, P()), out_specs=P())
     out = fn(stacked_params, micro)
     return out.reshape(b, *out.shape[2:])
 
@@ -311,7 +268,6 @@ def pipeline_1f1b(stage_fn: Callable, last_fn: Callable, stacked_params, x,
     Returns (loss, param_grads, last_param_grads, dx).
     """
     if mesh is None:
-        from ..distributed.mesh import get_mesh
         mesh = get_mesh()
     if last_params is None:
         last_params = {}
@@ -328,18 +284,13 @@ def pipeline_1f1b(stage_fn: Callable, last_fn: Callable, stacked_params, x,
     param_specs = jax.tree_util.tree_map(
         lambda l: P(axis_name, *([None] * (l.ndim - 1))), stacked_params)
     last_specs = jax.tree_util.tree_map(lambda l: P(), last_params)
-    manual = frozenset({axis_name})
-    mesh = _partial_manual_guard(mesh, manual)
-    fn = shard_map(
+    fn = partial_manual(
         functools.partial(_pipeline_1f1b_local, stage_fn=stage_fn,
                           last_fn=last_fn, axis_name=axis_name,
                           n_stages=n_stages, n_micro=n_micro),
-        mesh=mesh,
+        mesh, {axis_name},
         in_specs=(param_specs, last_specs, P(), P()),
-        out_specs=(P(), param_specs, last_specs, P()),
-        auto=frozenset(mesh.axis_names) - manual,
-        check_rep=False,
-    )
+        out_specs=(P(), param_specs, last_specs, P()))
     loss, grads, last_grads, dx = fn(stacked_params, last_params, micro_x,
                                      micro_t)
     return loss, grads, last_grads, dx.reshape(b, *dx.shape[2:])

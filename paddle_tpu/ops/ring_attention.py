@@ -59,12 +59,19 @@ def _chunk_attn_xla(q, k, v, scale, causal):
 
 
 def _chunk_attn(q, k, v, scale, causal):
-    """Route the chunk pair through the pallas flash kernel on TPU."""
-    if jax.default_backend() == "tpu" and q.shape[1] >= 128:
+    """Route the chunk pair through the pallas flash kernel on TPU (or
+    where PADDLE_TPU_ATTENTION says), placed over the mesh axes the ring's
+    shard_map left automatic."""
+    from ..nn.functional.attention import flash_placement, forced_path
+
+    forced = forced_path()
+    if forced == "flash" or (forced is None and q.shape[1] >= 128
+                             and jax.default_backend() == "tpu"):
         from .pallas.flash_attention import _fwd
-        qh = jnp.swapaxes(q, 1, 2)
-        o, lse = _fwd(qh, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
-                      causal, scale, 128, 128, False)
+        place = flash_placement(q.shape[0], q.shape[2], k.shape[2])
+        o, lse = (place or (lambda fn: fn))(
+            lambda *qkv: _fwd(*qkv, causal, scale, 128, 128, False))(
+                *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)))
         return jnp.swapaxes(o, 1, 2), jnp.swapaxes(lse, 1, 2)
     return _chunk_attn_xla(q, k, v, scale, causal)
 
@@ -198,40 +205,15 @@ def _ring_bwd_rule(axis_name, n, causal, scale, res, do):
 ring_attention_local.defvjp(_ring_fwd_rule, _ring_bwd_rule)
 
 
-def _partial_manual_guard(mesh, manual):
-    """jax 0.4.x cannot compile partial-manual shard_map nested under
-    the GSPMD partitioner (XLA aborts in backend_compile). Returns the
-    mesh to run on: the original when fully manual; a reduced
-    single-axis mesh over the same devices when every automatic axis is
-    trivial (size 1 — semantically full-manual); otherwise a python
-    error, never a process abort."""
-    auto = frozenset(mesh.axis_names) - frozenset(manual)
-    if not auto:
-        return mesh
-    if all(mesh.shape[a] == 1 for a in auto) and len(manual) == 1:
-        import numpy as _np
-        from jax.sharding import Mesh as _Mesh
-        name = next(iter(manual))
-        return _Mesh(_np.asarray(mesh.devices).reshape(
-            mesh.shape[name]), (name,))
-    raise NotImplementedError(
-        f"partial-manual shard_map over {sorted(manual)} with "
-        f"non-trivial automatic axes "
-        f"{sorted(a for a in auto if mesh.shape[a] > 1)} is "
-        "unsupported on jax 0.4.x (XLA aborts); build a mesh carrying "
-        "only the manual axis")
-
-
 def ring_attention(q, k, v, mesh=None, axis_name="sep", causal=False,
                    scale=None):
     """Ring attention on full arrays [B, L, H, D]; builds the shard_map.
 
     L must divide evenly by the ``axis_name`` mesh axis size.
     """
-    from jax.experimental.shard_map import shard_map
+    from ..distributed.mesh import get_mesh, partial_manual
 
     if mesh is None:
-        from ..distributed.mesh import get_mesh
         mesh = get_mesh()
     n = mesh.shape[axis_name]
     if scale is None:
@@ -245,14 +227,9 @@ def ring_attention(q, k, v, mesh=None, axis_name="sep", causal=False,
     spec = P(None, axis_name, None, None)
     # manual only over the ring axis: batch/head placement on the other mesh
     # axes (dp/sharding/tp) stays with the GSPMD partitioner, so this nests
-    # inside the pjit train step. jax 0.9 quirk: partial-manual shard_map
-    # requires check_vma=True (its unmatch spec otherwise names every axis).
-    manual = frozenset({axis_name})
-    mesh = _partial_manual_guard(mesh, manual)
-    fn = shard_map(
+    # inside the pjit train step
+    fn = partial_manual(
         functools.partial(ring_attention_local, axis_name=axis_name, n=n,
                           causal=causal, scale=float(scale)),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        auto=frozenset(mesh.axis_names) - manual,
-        check_rep=False)
+        mesh, {axis_name}, in_specs=(spec, spec, spec), out_specs=spec)
     return fn(q, k, v)

@@ -65,40 +65,14 @@ def ulysses_attention_local(q, k, v, axis_name, n, causal, scale):
                               concat_axis=2, tiled=True)
 
 
-def _partial_manual_guard(mesh, manual):
-    """jax 0.4.x cannot compile partial-manual shard_map nested under
-    the GSPMD partitioner (XLA aborts in backend_compile). Returns the
-    mesh to run on: the original when fully manual; a reduced
-    single-axis mesh over the same devices when every automatic axis is
-    trivial (size 1 — semantically full-manual); otherwise a python
-    error, never a process abort."""
-    auto = frozenset(mesh.axis_names) - frozenset(manual)
-    if not auto:
-        return mesh
-    if all(mesh.shape[a] == 1 for a in auto) and len(manual) == 1:
-        import numpy as _np
-        from jax.sharding import Mesh as _Mesh
-        name = next(iter(manual))
-        return _Mesh(_np.asarray(mesh.devices).reshape(
-            mesh.shape[name]), (name,))
-    raise NotImplementedError(
-        f"partial-manual shard_map over {sorted(manual)} with "
-        f"non-trivial automatic axes "
-        f"{sorted(a for a in auto if mesh.shape[a] > 1)} is "
-        "unsupported on jax 0.4.x (XLA aborts); build a mesh carrying "
-        "only the manual axis")
-
-
 def ulysses_attention(q, k, v, mesh=None, axis_name="sep", causal=False,
                       scale=None):
     """All-to-all sequence-parallel attention on full arrays
     [B, L, H, D]; builds the shard_map. L and H must divide by the
     ``axis_name`` mesh axis size."""
-    from jax.experimental.shard_map import shard_map
+    from ..distributed.mesh import get_mesh, partial_manual
 
     if mesh is None:
-        from ..distributed.mesh import get_mesh
-
         mesh = get_mesh()
     n = mesh.shape[axis_name]
     if scale is None:
@@ -112,12 +86,8 @@ def ulysses_attention(q, k, v, mesh=None, axis_name="sep", causal=False,
     if q.shape[2] % n:
         raise ValueError(f"num heads {q.shape[2]} not divisible by {n}")
     spec = P(None, axis_name, None, None)
-    manual = frozenset({axis_name})
-    mesh = _partial_manual_guard(mesh, manual)
-    fn = shard_map(
+    fn = partial_manual(
         functools.partial(ulysses_attention_local, axis_name=axis_name,
                           n=n, causal=causal, scale=float(scale)),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        auto=frozenset(mesh.axis_names) - manual,
-        check_rep=False)
+        mesh, {axis_name}, in_specs=(spec, spec, spec), out_specs=spec)
     return fn(q, k, v)

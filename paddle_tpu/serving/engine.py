@@ -815,9 +815,9 @@ def _tp_jitted(mesh, kind, arch, donate, statics_items):
         return fn
     import functools
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
+    from ..distributed.mesh import shard_map
     from ..text import generation as G
 
     wspec = G._llama_tp_specs() if arch == "llama" else G._gpt_tp_specs()
@@ -830,7 +830,7 @@ def _tp_jitted(mesh, kind, arch, donate, statics_items):
         out_specs = (kv, kv, R, R, R, R)
     body = functools.partial(_TP_IMPLS[kind], **dict(statics_items))
     sm = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     fn = jax.jit(sm, donate_argnums=(1, 2) if donate else ())
     _TP_PROGRAMS[key] = fn
     return fn

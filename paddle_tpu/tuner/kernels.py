@@ -17,7 +17,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from ..cost_model import min_tile
+from ..cost_model import VMEM_LIMIT_BYTES, min_tile
 from .registry import KernelSpec, register
 
 _LANES = 128
@@ -183,12 +183,9 @@ register(KernelSpec(
 # ---------------------------------------------------------------------------
 
 def _fd_space(shapes, dtype):
-    n_kv = shapes[1][2]
-    out = []
-    for g in (1, 2, 4, 8):
-        if g <= n_kv and n_kv % g == 0:
-            out.append({"kv_heads_per_step": g})
-    return out
+    from ..ops.pallas.flash_decode import legal_kv_heads_per_step
+    return [{"kv_heads_per_step": g}
+            for g in legal_kv_heads_per_step(shapes[1][2])]
 
 
 def _fd_build(config, interpret):
@@ -316,17 +313,31 @@ register(KernelSpec(
 # fused sharded-vocab cross-entropy (ISSUE 14 kernel c)
 # ---------------------------------------------------------------------------
 
+def _ce_vmem(H, bn, bv, it):
+    """What one grid step of the stats kernel holds in VMEM: the hidden
+    and weight tiles are whole in H and double-buffered, plus labels,
+    three stats outputs (double-buffered) and scratches one lane tile
+    wide, plus the fp32 logits tile and its softmax temporaries."""
+    return 2 * (bn * H + H * bv) * it + 11 * bn * _LANES * _F32 \
+        + 4 * bn * bv * _F32
+
+
 def _ce_space(shapes, dtype):
     (N, H), (_, V) = shapes[0], shapes[1]
+    it = _itemsize(dtype)
+    # the [H, block_v] weight tile grows with H: keep only tiles that
+    # leave a quarter of the scoped VMEM to the compiler (at H = 4096
+    # bf16 a block_v of 1024 is 8 MB a buffer and is refused)
+    budget = VMEM_LIMIT_BYTES * 3 // 4
     out = []
     for bn in (128, 64, 256):
         if bn > max(N, 64):
             continue
-        for bv in (1024, 512, 2048, 4096):
-            if bv > max(V, 512):
+        for bv in (1024, 512, 2048, 4096, 256, 128):
+            if bv > max(V, 512) or _ce_vmem(H, bn, bv, it) > budget:
                 continue
             out.append({"block_n": bn, "block_v": bv})
-    return out or [{"block_n": 128, "block_v": 1024}]
+    return out or [{"block_n": 64, "block_v": 128}]
 
 
 def _ce_build(config, interpret):
@@ -346,10 +357,8 @@ def _ce_reference(hidden, w, labels):
 def _ce_features(shapes, dtype, config):
     (N, H), (_, V) = shapes[0], shapes[1]
     bn, bv = config["block_n"], config["block_v"]
-    it = _itemsize(dtype)
-    vmem = (bn * H + H * bv) * it + (bn * bv + 6 * bn * _LANES) * _F32
     return {"tiles": [(bn, _sub(dtype)), (bv, _LANES), (H, _LANES)],
-            "vmem_bytes": vmem,
+            "vmem_bytes": _ce_vmem(H, bn, bv, _itemsize(dtype)),
             "steps": _ceil_div(N, bn) * _ceil_div(V, bv)}
 
 

@@ -93,7 +93,7 @@ def test_quantize_int8_stochastic_tpu():
 
 
 def test_stochastic_round_bf16_tpu():
-    """fp32->bf16 stochastic rounding (the BENCH_r05 kernel-gate path):
+    """fp32->bf16 stochastic rounding:
     target dtype gated to MOSAIC_SR_TARGETS, output lands on one of the
     two bracketing bf16 values."""
     import jax

@@ -15,8 +15,10 @@ Measures, with subprocess pairs so every arm pays a REAL process start:
   compiles and token-identical output.
 
 Emits one JSON ledger line; ``ok`` gates the zero-compile + bitwise
-claims. Reused by the gated ``coldstart`` secondary arm in bench.py
-(stale-merge semantics as every other arm).
+claims. Stand-alone: every arm is a child process and this parent never
+imports jax, so the children — one at a time — are each the only jax
+process (a parent that has touched jax owns the chip, and its children
+cannot have it). Runs on the CPU unless JAX_PLATFORMS says otherwise.
 
     JAX_PLATFORMS=cpu python tools/bench_coldstart.py [--json]
 """
@@ -67,6 +69,24 @@ print(json.dumps({
     "setup_s": round(t0 - t_proc, 4),
     "workload_compiles": counter.count if counter.available else None,
     "loss_bits": [np.float32(v).tobytes().hex() for v in losses]}))
+"""
+
+_SAVE_LM_CHILD = r"""
+import dataclasses, json, os, sys
+sys.path.insert(0, %(repo)r)
+import paddle_tpu as paddle
+from paddle_tpu import serving
+from paddle_tpu.text.models.llama import LLAMA_TINY, LlamaForCausalLM
+
+plain, pre = sys.argv[1:3]
+cfg = dataclasses.replace(LLAMA_TINY, dtype="float32", num_hidden_layers=2)
+paddle.seed(0)
+model = LlamaForCausalLM(cfg)
+model.eval()
+serving.save_lm(model, plain, precompile=False)
+serving.save_lm(model, pre, precompile=True, n_slots=2, max_len=64,
+                min_prompt_bucket=8)
+print(json.dumps({"saved": [plain, pre]}))
 """
 
 _SERVING_CHILD = r"""
@@ -134,23 +154,12 @@ def bench_eager_coldstart():
 
 
 def bench_serving_coldstart():
-    import dataclasses
-
-    import paddle_tpu as paddle
-    from paddle_tpu import serving
-    from paddle_tpu.text.models.llama import LLAMA_TINY, LlamaForCausalLM
-
     tmp = tempfile.mkdtemp(prefix="aot-coldstart-lm-")
-    cfg = dataclasses.replace(LLAMA_TINY, dtype="float32",
-                              num_hidden_layers=2)
-    paddle.seed(0)
-    model = LlamaForCausalLM(cfg)
-    model.eval()
     plain = os.path.join(tmp, "lm_plain")
     pre = os.path.join(tmp, "lm_pre")
-    serving.save_lm(model, plain, precompile=False)
-    serving.save_lm(model, pre, precompile=True, n_slots=2, max_len=64,
-                    min_prompt_bucket=8)
+    saved = _child(_SAVE_LM_CHILD % {"repo": REPO}, argv=(plain, pre))
+    if "error" in saved:
+        raise RuntimeError(f"save_lm child failed: {saved['error']}")
     code = _SERVING_CHILD % {"repo": REPO}
     # the plain arm gets the same geometry explicitly so the ONLY delta
     # is the precompiled program set
@@ -174,7 +183,8 @@ def main():
     ap.add_argument("--arm", choices=("eager", "serving", "both"),
                     default="both")
     args = ap.parse_args()
-    record = {"bench": "coldstart", "backend": "cpu"}
+    record = {"bench": "coldstart",
+              "jax_platforms": os.environ["JAX_PLATFORMS"]}
     if args.arm in ("eager", "both"):
         record["eager"] = bench_eager_coldstart()
     if args.arm in ("serving", "both"):

@@ -14,7 +14,9 @@ says whether training recovered and finished with a healthy loss.
 Faults: nan | stall | error | corrupt run in-process; kill launches a
 subprocess that SIGKILLs itself mid-run, then a second subprocess that
 must resume from the durable checkpoint and finish. Exit code 0 iff the
-run recovered and converged.
+run recovered and converged. CPU by default (JAX_PLATFORMS is set to cpu
+unless given); the kill parent imports no jax and its two children run
+one after the other, so each is the only jax process.
 """
 import argparse
 import json

@@ -18,6 +18,10 @@ with bitwise-identical losses. Without this mode a warm cache would
 read as an impossibly-good budget, and with a broken one the tool
 would report cold budget violations that are really cache misses.
 
+CPU by default (JAX_PLATFORMS is set to cpu unless given). The
+``--warm-cache`` parent imports no jax and runs its two children one
+after the other, so each is the only jax process.
+
 Modeled on tools/check_hlo_layout.py. Usage:
 
     JAX_PLATFORMS=cpu python tools/check_retrace.py [--json] [--warm-cache]

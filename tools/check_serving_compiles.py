@@ -48,6 +48,11 @@ programs are shared across replicas — 0 extra lowerings, gated against
 a fresh single engine's budget), do 0 warm compiles on a second pass,
 and keep every request token-identical to batch ``generate()``.
 
+CPU by default (JAX_PLATFORMS is set to cpu unless given). The
+``--warm-cache`` parent imports no jax and runs its two children one
+after the other, so each is the only jax process; every other mode is
+one process.
+
 Modeled on tools/check_retrace.py. Usage:
 
     JAX_PLATFORMS=cpu python tools/check_serving_compiles.py [--json]
@@ -391,17 +396,17 @@ def run_mesh(args):
                if f.rule_id == "unoverlapped-collective"
                and f.severity == "high"]
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.distributed.mesh import shard_map
     from paddle_tpu.distributed.collective_matmul import \
         serial_rowparallel_matmul
     mesh = mesh_mod.build_mesh(tp=tp)
     seeded = shard_map(
         lambda a, b: serial_rowparallel_matmul(a, b, "tp"), mesh=mesh,
         in_specs=(P(None, "tp"), P("tp", None)), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     srep = analysis.audit(
         seeded, np.zeros((4, 8 * tp), np.float32),
         np.zeros((8 * tp, 16 * tp), np.float32), name="seeded-serial")

@@ -26,6 +26,10 @@ subprocesses sharing one paddle_tpu.aot cache directory and asserts the
 SECOND process builds 0 train-step programs (service misses == 0,
 compiled == 0 — the mesh-keyed signature restored the executable).
 
+CPU-only: it checks program text on 8 virtual CPU devices. The
+``--warm-cache`` parent imports no jax and runs its two children one
+after the other.
+
 Usage:
     JAX_PLATFORMS=cpu python tools/check_train_collectives.py [--json]
     JAX_PLATFORMS=cpu python tools/check_train_collectives.py --steps 8
