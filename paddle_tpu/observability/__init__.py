@@ -1,7 +1,7 @@
 """paddle_tpu.observability — unified metrics registry, span tracing,
 and compile-event attribution across train + serve.
 
-Three pieces, one import:
+Four pieces, one import:
 
 * **Metrics registry** (``metrics``): typed ``Counter`` / ``Gauge`` /
   ``Histogram`` with labels on a process-wide ``REGISTRY``; the
@@ -15,10 +15,24 @@ Three pieces, one import:
   every instrumentation site costs one branch until
   ``enable_tracing()`` (or ``PADDLE_TPU_TRACE=1``). Train step phases
   (data / forward / backward / optimizer / checkpoint) and the full
-  serving request lifecycle (queue → admission → prefill chunks →
-  decode → finish) are pre-instrumented; a request's trace id lives on
-  its handle, so a token-identical replay on a rebuilt engine links to
-  the original request's trace.
+  serving request lifecycle (``serving.submit`` / ``submit_call`` →
+  ``queue`` → ``prefill`` / ``prefill_chunk`` → ``decode`` → ``finish``)
+  are pre-instrumented; a request's trace id lives on its handle, so a
+  token-identical replay on a rebuilt engine links to the original
+  request's trace. Engine-wide, each ``serving.step`` is the parent of
+  ``serving.schedule``, ``serving.dispatch``, ``serving.fetch`` and
+  ``serving.emit`` and of the launches inside it. A live span is also a
+  ``jax.profiler.TraceAnnotation`` of its name while the tracer is on;
+  ``span_event(..., parent=)`` writes one from clock reads the caller
+  already took and returns its id.
+* **The serving engine's stamps** (``serving.metrics``, always on, no
+  switch): the clock reads behind those spans are kept whether or not
+  the tracer is on, as ``StepRecord`` / ``LaunchRecord`` /
+  ``SubmitRecord`` tuples in bounded rings on ``EngineMetrics``
+  (``.steps``, ``.launches``, ``.submits``). Read them through
+  ``Engine.stats()["step_phase_seconds"]`` (cumulative seconds by
+  phase), ``serving.metrics.live_metrics()`` (every live engine's rings)
+  or the ``paddle_serving_step_phase_seconds_total{phase}`` family.
 * **Compile attribution** (``compile_attr``): every XLA backend
   compile counted + timed under the subsystem that triggered it
   (``compile_scope``), as metrics and (when tracing) ``xla.compile``

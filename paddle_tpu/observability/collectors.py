@@ -48,6 +48,11 @@ def _serving_families():
     yield _fam("paddle_serving_events_total", "counter",
                "serving-engine counters summed across live engines",
                [({"kind": k}, t[k]) for k in counter_keys])
+    yield _fam("paddle_serving_step_phase_seconds_total", "counter",
+               "seconds of Engine.step() by phase (schedule, dispatch, "
+               "fetch, emit) summed across live engines",
+               [({"phase": k}, v)
+                for k, v in t["step_phase_seconds"].items()])
     gauges = [("engines", t["engines"]),
               ("peak_queue_depth", t["peak_queue_depth"]),
               ("peak_active", t["peak_active"])]
@@ -64,10 +69,7 @@ def _serving_families():
     # merged ITL histogram across live engines (same bucket bounds)
     counts = [0] * (len(DEFAULT_LATENCY_BUCKETS) + 1)
     total_sum, total_count = 0.0, 0
-    for ref in list(sm._ENGINES):
-        m = ref()
-        if m is None or getattr(m, "itl_hist", None) is None:
-            continue
+    for m in sm.live_metrics():
         s, c = m.itl_hist.merge_counts(counts)
         total_sum += s
         total_count += c

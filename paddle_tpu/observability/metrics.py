@@ -14,11 +14,12 @@ slow happens (a compile, a decode step, a checkpoint save); scrapes do
 the aggregation work. An idle registry costs a dict and some ints.
 
 ``Histogram`` supports a count-windowed rolling view for live quantile
-queries: ``window=N`` keeps two generations of bucket counts rotated
-every ``N // 2`` observations, so ``percentile(p)`` reflects roughly
-the last N observations (the serving ITL p50/p95 behind brownout
-shedding and ``EngineOverloaded.retry_after_s``) while the exported
-cumulative buckets never lose history.
+queries: ``window=N`` keeps two generations of raw observations rotated
+every ``N // 2`` observations, so ``percentile(p)`` is the exact
+percentile of roughly the last N observations (the serving ITL p50/p95
+behind brownout shedding and ``EngineOverloaded.retry_after_s``: a
+60 ms step must not read as the middle of its 50-100 ms bucket) while
+the exported cumulative buckets never lose history.
 """
 from __future__ import annotations
 
@@ -163,10 +164,11 @@ class Histogram:
     count-windowed rolling view for quantiles.
 
     Unlabeled (label a histogram by creating one per stream and merging
-    at collect time — see the serving ITL collector). ``percentile(p)``
-    interpolates linearly inside the bucket that holds the rank; with
-    ``window=N`` it covers the last ~N observations (two generations
-    rotated every ``N // 2``), otherwise the full history.
+    at collect time — see the serving ITL collector). With ``window=N``
+    ``percentile(p)`` is exact over the last ~N observations (two
+    generations of raw values rotated every ``N // 2``); otherwise it
+    covers the full history and interpolates linearly inside the bucket
+    that holds the rank.
     """
 
     kind = "histogram"
@@ -185,9 +187,8 @@ class Histogram:
         self.count = 0
         self.window = None if window is None else max(2, int(window))
         if self.window:
-            self._hot = [0] * n
-            self._cold = [0] * n
-            self._hot_n = 0
+            self._hot = []                # raw values, newest generation
+            self._cold = []
         if registry == "default":
             registry = REGISTRY
         if registry is not None:
@@ -201,24 +202,25 @@ class Histogram:
             self.sum += v
             self.count += 1
             if self.window:
-                if self._hot_n >= self.window // 2:
-                    self._cold = self._hot
-                    self._hot = [0] * len(self._counts)
-                    self._hot_n = 0
-                self._hot[i] += 1
-                self._hot_n += 1
-
-    def _view(self):
-        if not self.window:
-            return self._counts
-        return [h + c for h, c in zip(self._hot, self._cold)]
+                if len(self._hot) >= self.window // 2:
+                    self._cold, self._hot = self._hot, []
+                self._hot.append(v)
 
     def percentile(self, p):
-        """Approximate percentile (linear interpolation inside the
-        owning bucket) over the rolling window when one is configured,
-        else over all observations. None before the first observe."""
+        """Percentile over the rolling window when one is configured
+        (exact: linear interpolation between ranks, numpy's default),
+        else over all observations (approximate: linear interpolation
+        inside the owning bucket). None before the first observe."""
         with self._lock:
-            counts = list(self._view())
+            vals = sorted(self._cold + self._hot) if self.window else None
+            counts = list(self._counts)
+        if vals is not None:
+            if not vals:
+                return None
+            k = (len(vals) - 1) * (p / 100.0)
+            lo = int(k)
+            hi = min(lo + 1, len(vals) - 1)
+            return vals[lo] + (vals[hi] - vals[lo]) * (k - lo)
         n = sum(counts)
         if n == 0:
             return None

@@ -22,6 +22,16 @@ crosses many engine steps, or a token-identical replay on a rebuilt
 engine — carries its trace id explicitly (``span(trace_id=...)``), so
 a request's queue/prefill/decode spans link into one trace even across
 an ``EngineSupervisor`` rebuild.
+
+Two kinds of span. A *live* one (``span`` / ``begin_span``) is open
+while its work runs: it nests under the thread's open spans and, while
+the tracer is enabled, is also a ``jax.profiler.TraceAnnotation`` of the
+same name, so a ``jax.profiler`` trace shows it on the device's clock.
+A *stamped* one (``span_event``) is written afterwards from two
+``perf_counter`` reads the caller already took — the serving engine's
+step phases and program launches, whose stamps also feed the always-on
+rings of ``serving.metrics.EngineMetrics`` — and names its parent
+explicitly (``parent=``, the id an earlier ``span_event`` returned).
 """
 from __future__ import annotations
 
@@ -101,9 +111,10 @@ def current_trace_id():
 
 
 class _SpanToken:
-    __slots__ = ("name", "cat", "trace", "span", "parent", "t0", "args")
+    __slots__ = ("name", "cat", "trace", "span", "parent", "t0", "args",
+                 "ann")
 
-    def __init__(self, name, cat, trace, span_id, parent, t0, args):
+    def __init__(self, name, cat, trace, span_id, parent, t0, args, ann):
         self.name = name
         self.cat = cat
         self.trace = trace
@@ -111,26 +122,53 @@ class _SpanToken:
         self.parent = parent
         self.t0 = t0
         self.args = args
+        self.ann = ann
 
 
-def begin_span(name, cat="", trace_id=None, **attrs):
+def _annotate(name):
+    """Enter a ``jax.profiler.TraceAnnotation``: the span as the
+    profiler's trace shows it (a no-op a few hundred nanoseconds long
+    while no profiler session runs). jax is imported on first use: this
+    module loads before everything else."""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+def begin_span(name, cat="", trace_id=None, annotation=None, **attrs):
     """Open a span without a context manager (RecordEvent-style begin/
     end pairs). Returns a token for :func:`end_span`, or None when
-    tracing is disabled."""
+    tracing is disabled.
+
+    The span is also a ``jax.profiler.TraceAnnotation`` under its own
+    name. ``annotation`` gives it another name there, and such a span
+    belongs to the profiler's trace whether or not the tracer is on
+    (``profiler.RecordEvent``: ``UserDefined::<name>``); only its ring
+    entry waits for ``enable()``."""
     if not _ENABLED:
-        return None
+        if annotation is None:
+            return None
+        return _SpanToken(name, cat, None, None, None, 0.0, None,
+                          _annotate(annotation))
     st = _stack()
     parent = st[-1] if st else None
     trace = trace_id or (parent[1] if parent else new_trace_id())
     tok = _SpanToken(name, cat, trace, new_trace_id(),
-                     parent[0] if parent else None,
-                     time.perf_counter(), attrs or None)
+                     parent[0] if parent else None, 0.0, attrs or None,
+                     _annotate(annotation or name))
     st.append((tok.span, trace))
+    tok.t0 = time.perf_counter()
     return tok
 
 
 def end_span(tok, **attrs):
     if tok is None:
+        return
+    t1 = time.perf_counter()
+    tok.ann.__exit__(None, None, None)
+    if tok.span is None:       # the profiler's alone: opened tracer-off
         return
     st = _stack()
     if st and st[-1][0] == tok.span:
@@ -140,7 +178,7 @@ def end_span(tok, **attrs):
     if attrs:
         tok.args = dict(tok.args or {}, **attrs)
     _record(tok.name, tok.cat, tok.trace, tok.span, tok.parent,
-            tok.t0, time.perf_counter() - tok.t0, tok.args)
+            tok.t0, t1 - tok.t0, tok.args)
 
 
 @contextlib.contextmanager
@@ -156,8 +194,12 @@ def span(name, cat="", trace_id=None, **attrs):
         end_span(tok)
 
 
-def instant(name, cat="", trace_id=None, **attrs):
-    """Zero-duration marker (Chrome phase "i")."""
+def instant(name, cat="", trace_id=None, annotation=None, **attrs):
+    """Zero-duration marker (Chrome phase "i"); with ``annotation``
+    also a degenerate range of that name in the profiler's trace,
+    tracer on or off (``profiler.RecordInstantEvent``)."""
+    if annotation is not None:
+        _annotate(annotation).__exit__(None, None, None)
     if not _ENABLED:
         return
     st = getattr(_tls, "stack", None)
@@ -167,14 +209,19 @@ def instant(name, cat="", trace_id=None, **attrs):
             time.perf_counter(), 0.0, attrs or None, ph="i")
 
 
-def span_event(name, t0, t1, cat="", trace_id=None, **attrs):
+def span_event(name, t0, t1, cat="", trace_id=None, parent=None, **attrs):
     """Record an already-timed span from two ``perf_counter`` stamps —
     phases whose begin and end live in different calls (a request's
-    time in queue, its whole decode phase)."""
+    time in queue, its whole decode phase), or whose stamps the caller
+    keeps for its own records (the serving engine's step phases).
+    Returns the span's id, for a later call's ``parent=``; None when
+    tracing is disabled."""
     if not _ENABLED:
-        return
-    _record(name, cat, trace_id, new_trace_id(), None, t0,
-            max(0.0, t1 - t0), attrs or None)
+        return None
+    sid = new_trace_id()
+    _record(name, cat, trace_id, sid, parent, t0, max(0.0, t1 - t0),
+            attrs or None)
+    return sid
 
 
 class _ForwardSpan:

@@ -6,8 +6,9 @@ reference's CUPTI/host tracer is replaced by the XLA/TPU profiler:
 ``start``/``stop`` bracket a ``jax.profiler`` trace whose output
 (perfetto/tensorboard trace dir) covers device kernels, XLA fusions, ICI
 collectives and host python — strictly more than the reference's op-level
-timeline. RecordEvent lowers to jax.profiler.TraceAnnotation so custom
-ranges show up inside the device trace.
+timeline. RecordEvent is a live span of ``observability.tracing``, which
+enters a jax.profiler.TraceAnnotation, so custom ranges show up inside the
+device trace.
 """
 from __future__ import annotations
 
@@ -377,36 +378,29 @@ class Profiler:
 
 
 class RecordEvent:
-    """Custom named range; shows in the device trace (TraceAnnotation)
-    AND, when the observability tracer is on, as a ``user::<name>``
-    span in the in-process ring / Chrome export — so RecordEvent works
-    even without an active jax trace."""
+    """Custom named range: a live span of the observability tracer.
+    It shows in the device trace as ``UserDefined::<name>`` whenever a
+    ``jax.profiler`` trace runs, and, when the tracer is on, as a
+    ``user::<name>`` span in the in-process ring / Chrome export — so
+    RecordEvent works even without an active jax trace."""
 
     def __init__(self, name: str, event_type=None):
         self.name = name
-        self._ann = None
         self._span_tok = None
 
     def begin(self):
         # the UserDefined:: prefix is how the statistic parser routes
         # these into the user-event table (reference groups RecordEvents
         # under TracerEventType.UserDefined) instead of the op summary
-        self._ann = jax.profiler.TraceAnnotation(
-            f"UserDefined::{self.name}")
-        self._ann.__enter__()
         from ..observability import tracing as _trc
-        if _trc.enabled():
-            self._span_tok = _trc.begin_span(f"user::{self.name}",
-                                             cat="user")
+        self._span_tok = _trc.begin_span(
+            f"user::{self.name}", cat="user",
+            annotation=f"UserDefined::{self.name}")
 
     def end(self):
-        if self._span_tok is not None:
-            from ..observability import tracing as _trc
-            _trc.end_span(self._span_tok)
-            self._span_tok = None
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
+        from ..observability import tracing as _trc
+        _trc.end_span(self._span_tok)
+        self._span_tok = None
 
     def __enter__(self):
         self.begin()
@@ -431,8 +425,8 @@ class RecordInstantEvent(RecordEvent):
 
     def begin(self):
         from ..observability import tracing as _trc
-        _trc.instant(f"user::{self.name}", cat="user")
-        super().begin()
+        _trc.instant(f"user::{self.name}", cat="user",
+                     annotation=f"UserDefined::{self.name}")
 
 
 from .statistic import (ProfilerResult, build_summary,  # noqa: E402

@@ -56,6 +56,20 @@ decoding (see serving/speculative.py). ``submit(logit_mask=...)``
 threads a per-request vocab mask through every sampled position
 (prefill, decode, chunk and verify) as a runtime operand — constrained
 decoding with zero extra lowerings, replay/migration-safe.
+
+The engine stamps itself (``time.perf_counter()``, always on): every
+``step()`` with decode-active rows leaves a ``StepRecord`` (begin,
+scheduled, dispatched, fetched, end) and every program launch a
+``LaunchRecord`` (called, dispatched, fetched) in the bounded rings of
+``self.metrics`` (serving/metrics.py), and every ``submit()`` a
+``SubmitRecord`` (entry, return: admission runs inside it when a slot is
+free). The decode launch's called -> fetched is what
+``metrics.mark_decode`` is given, so the ITL estimate behind
+``retry_after_s``, brownout and fleet routing covers the token fetch; and
+when the span tracer is on the same stamps become ``serving.step`` with
+its ``serving.schedule / dispatch / fetch / emit`` children, the
+launches' ``serving.prefill``, ``serving.prefill_chunk``, ``spec.verify``,
+``spec.draft`` spans, and ``serving.submit_call``.
 """
 from __future__ import annotations
 
@@ -70,7 +84,8 @@ from ..observability import tracing as _tracing
 from ..observability.compile_attr import compile_scope as _compile_scope
 from ..tensor import Tensor
 from .kv_cache import PagedKVCache, SlotKVCache
-from .metrics import EngineMetrics, RequestMetrics
+from .metrics import (EngineMetrics, LaunchRecord, RequestMetrics,
+                      StepRecord, SubmitRecord)
 from .scheduler import (EngineOverloaded, FIFOScheduler,  # noqa: F401
                         PriorityScheduler)
 
@@ -1151,6 +1166,11 @@ class Engine:
         self._condemned = False
         self.metrics = EngineMetrics()
         self.metrics.replica = replica_id
+        self._steps = 0           # step() calls so far: the next index
+        self._step = None         # index of the step() now running
+        # tracer on: spans of the running step's launches, held until
+        # the step closes and their parent's id is known
+        self._held_spans = []
         self._by_slot = [None] * self.n_slots
         self._next_id = 0
         self.base_seed = int(base_seed)
@@ -1436,6 +1456,37 @@ class Engine:
                     origin=origin)
         return svc.stats()
 
+    # -- stamps (module docstring) ----------------------------------------
+
+    def _launched(self, program, called, dispatched, fetched, span=None,
+                  h=None, tokens=0, radix_tokens=0, **attrs):
+        """Record one program launch from the stamps its call site took;
+        with the tracer on, the same stamps are the span ``span``."""
+        self.metrics.mark_launch(LaunchRecord(
+            program, self._step, called, dispatched, fetched,
+            None if h is None else h.request_id, tokens, radix_tokens))
+        if span is None or not _tracing._ENABLED:
+            return
+        if h is not None:
+            attrs["request_id"] = h.request_id
+        ev = (span, called, fetched or dispatched,
+              None if h is None else h.trace_id, attrs)
+        if self._step is None:
+            self._launch_spans([ev])
+        else:
+            self._held_spans.append(ev)
+
+    def _launch_spans(self, held, step_span=None, schedule_span=None,
+                      scheduled=0.0):
+        """Write held launch spans: one called before the step was
+        ``scheduled`` (a prefill, a chunk) hangs under its schedule
+        phase, a later one (verify, draft) under the step itself."""
+        for name, t0, t1, trace_id, attrs in held:
+            _tracing.span_event(
+                name, t0, t1, cat="serving", trace_id=trace_id,
+                parent=schedule_span if t0 < scheduled else step_span,
+                **attrs)
+
     # -- request intake ---------------------------------------------------
 
     def _bucket(self, n):
@@ -1483,6 +1534,7 @@ class Engine:
         runtime operand: zero new lowerings, co-batched neighbours
         untouched, and adopt()/replay re-samples under the same mask so
         constrained requests migrate token-identically."""
+        begin = time.perf_counter()
         ids = self._as_ids(prompt)
         if ids.shape[0] < 1:
             raise ValueError("empty prompt")
@@ -1525,7 +1577,16 @@ class Engine:
             self.metrics.requests_rejected += 1
             raise
         self._admit()
+        self._submitted(h, begin)
         return h
+
+    def _submitted(self, h, begin):
+        """Close the stamps of a ``submit()`` / ``adopt()`` call."""
+        end = time.perf_counter()
+        self.metrics.mark_submit(SubmitRecord(h.request_id, begin, end))
+        _tracing.span_event("serving.submit_call", begin, end,
+                            cat="serving", trace_id=h.trace_id,
+                            request_id=h.request_id)
 
     def _retry_after_hint(self):
         """Seconds until a slot plausibly frees: the rolling inter-token
@@ -1595,15 +1656,11 @@ class Engine:
         self.buckets_seen.add(Lb)
         ids = np.zeros((1, Lb), np.int32)
         ids[0, :n_eff] = self._full_ids(h)
-        _tracing.span_event("serving.queue", h._queued_t,
-                            time.perf_counter(), cat="serving",
-                            trace_id=h.trace_id,
+        called = time.perf_counter()
+        _tracing.span_event("serving.queue", h._queued_t, called,
+                            cat="serving", trace_id=h.trace_id,
                             request_id=h.request_id)
-        with _tracing.span("serving.prefill", cat="serving",
-                           trace_id=h.trace_id,
-                           request_id=h.request_id, bucket=Lb,
-                           replay_k=k), \
-                _compile_scope(f"prefill:L{Lb}"):
+        with _compile_scope(f"prefill:L{Lb}"):
             out = self._run_program(
                 "prefill", ("prefill", Lb), self._prefill,
                 (self._w, self.cache.kc, self.cache.vc, self._tok,
@@ -1614,9 +1671,14 @@ class Engine:
                 f"prefill:L{Lb}")
         (self.cache.kc, self.cache.vc, self._tok, self._cur,
          self._keys, tok0) = out
+        dispatched = time.perf_counter()
+        tok0 = int(tok0)
+        self._launched(f"prefill:L{Lb}", called, dispatched,
+                       time.perf_counter(), "serving.prefill", h,
+                       tokens=n_eff, bucket=Lb, replay_k=k)
         self.metrics.prefills += 1
         self.cache.cur_pos[slot] = n_eff
-        self._emit(h, int(tok0))
+        self._emit(h, tok0)
         return True
 
     def _admit_one_paged(self, h, k, n_eff):
@@ -1664,15 +1726,11 @@ class Engine:
         self.buckets_seen.add(Lb)
         ids = np.zeros((1, Lb), np.int32)
         ids[0, :n_eff] = full
-        _tracing.span_event("serving.queue", h._queued_t,
-                            time.perf_counter(), cat="serving",
-                            trace_id=h.trace_id,
+        called = time.perf_counter()
+        _tracing.span_event("serving.queue", h._queued_t, called,
+                            cat="serving", trace_id=h.trace_id,
                             request_id=h.request_id)
-        with _tracing.span("serving.prefill", cat="serving",
-                           trace_id=h.trace_id,
-                           request_id=h.request_id, bucket=Lb,
-                           replay_k=k, n_shared=n_shared), \
-                _compile_scope(f"prefill:L{Lb}"):
+        with _compile_scope(f"prefill:L{Lb}"):
             out = self._run_program(
                 "prefill", ("prefill", Lb), self._prefill,
                 (self._w, self.cache.kc, self.cache.vc, self._tok,
@@ -1684,11 +1742,17 @@ class Engine:
                 self._paged_statics, f"prefill:L{Lb}")
         (self.cache.kc, self.cache.vc, self._tok, self._cur,
          self._keys, tok0) = out
+        dispatched = time.perf_counter()
+        tok0 = int(tok0)
+        self._launched(f"prefill:L{Lb}", called, dispatched,
+                       time.perf_counter(), "serving.prefill", h,
+                       tokens=n_eff, radix_tokens=min(n_shared, n_eff),
+                       bucket=Lb, replay_k=k, n_shared=n_shared)
         self.metrics.prefills += 1
         self.cache.cur_pos[slot] = n_eff
         if self.prefix_sharing:
             self.cache.commit_prefix(slot, full)
-        self._emit(h, int(tok0))
+        self._emit(h, tok0)
         if self._spec is not None and not h.finished:
             self._spec.on_admit(h, full)
         return True
@@ -1705,11 +1769,8 @@ class Engine:
         ids = np.zeros((1, C), np.int32)
         ids[0, :end - start] = cs.ids[start:end]
         is_final = end >= cs.n_eff
-        with _tracing.span("serving.prefill_chunk", cat="serving",
-                           trace_id=h.trace_id,
-                           request_id=h.request_id, start=start,
-                           final=is_final), \
-                _compile_scope("chunk"):
+        called = time.perf_counter()
+        with _compile_scope("chunk"):
             out = self._run_program(
                 "chunk", ("chunk",), self._chunk,
                 (self._w, self.cache.kc, self.cache.vc, self._tok,
@@ -1723,6 +1784,18 @@ class Engine:
                 "chunk")
         (self.cache.kc, self.cache.vc, self._tok, self._cur,
          self._keys, tok0) = out
+        dispatched = time.perf_counter()
+        fetched = None
+        if is_final:
+            # only the final chunk's token is sampled state: the others'
+            # results stay on the device, unfetched
+            tok0 = int(tok0)
+            fetched = time.perf_counter()
+        self._launched(
+            "chunk", called, dispatched, fetched,
+            "serving.prefill_chunk", h, tokens=end - start,
+            radix_tokens=max(0, min(cs.n_shared, end) - start),
+            start=start, final=is_final)
         self.chunk_used = True
         self.metrics.chunk_steps += 1
         cs.next = end
@@ -1732,7 +1805,7 @@ class Engine:
             self.cache.cur_pos[h.slot] = cs.n_eff
             if self.prefix_sharing:
                 self.cache.commit_prefix(h.slot, cs.ids)
-            self._emit(h, int(tok0))
+            self._emit(h, tok0)
             if self._spec is not None and not h.finished:
                 self._spec.on_admit(h, cs.ids)
 
@@ -1821,7 +1894,7 @@ class Engine:
         handle._engine = self
         handle.replica_id = self.replica_id
         handle.model_fingerprint = self.model_fingerprint
-        handle._queued_t = time.perf_counter()
+        begin = handle._queued_t = time.perf_counter()
         self._next_id = max(self._next_id, handle.request_id + 1)
         self.metrics.requests_submitted += 1
         _tracing.instant("serving.adopt", cat="serving",
@@ -1831,6 +1904,7 @@ class Engine:
         self.scheduler.enqueue(handle,
                                retry_after_s=self._retry_after_hint())
         self._admit()
+        self._submitted(handle, begin)
         return handle
 
     def cancel(self, handle):
@@ -1883,6 +1957,22 @@ class Engine:
         of requests that were decoding this step."""
         if self._condemned:
             return 0     # a supervisor replaced this engine incarnation
+        self._step = self._steps
+        self._steps += 1
+        try:
+            return self._step_stamped()
+        finally:
+            self._step = None
+            if self._held_spans:      # the step raised, or decoded nothing
+                self._launch_spans(self._held_spans)
+                self._held_spans = []
+
+    def _step_stamped(self):
+        """``step()``'s body. The clock is read at each boundary once;
+        the reads become the step's ``StepRecord`` and, with the tracer
+        on, its spans."""
+        begin = time.perf_counter()
+        launches = self.metrics.launches_recorded
         self._expire()
         self._admit()
         paged = self.kv_layout == "paged"
@@ -1907,19 +1997,36 @@ class Engine:
                                 active=self.cache.n_active)
         if not n_active:
             return 0
+        kind = ("decode" if self.metrics.launches_recorded == launches
+                else "admit")
+        scheduled = time.perf_counter()
         if self._spec is not None:
-            return self._spec_step(active, n_active)
-        self._decode_once(active, n_active)
+            kind = "spec"
+            dispatched, fetched = self._spec_step(active, n_active)
+        else:
+            dispatched, fetched = self._decode_once(active, n_active)
+        end = time.perf_counter()
+        rec = StepRecord(self._step, kind, begin, scheduled,
+                         dispatched or end, fetched or end, end, n_active)
+        self.metrics.mark_step(rec)
+        if _tracing._ENABLED:
+            sid = _tracing.span_event("serving.step", begin, end,
+                                      cat="serving", step=rec.index,
+                                      kind=kind, n_active=n_active)
+            phases = [_tracing.span_event(f"serving.{phase}", t0, t1,
+                                          cat="serving", parent=sid)
+                      for phase, t0, t1 in rec.intervals()]
+            self._launch_spans(self._held_spans, sid, phases[0], scheduled)
+            self._held_spans = []
         return n_active
 
     def _decode_once(self, active, n_active):
         """One fused decode-step invocation over ``active`` rows: every
-        active slot advances exactly one token."""
+        active slot advances exactly one token. Returns when its call
+        had returned and when its tokens were on the host."""
         paged = self.kv_layout == "paged"
-        t0 = time.perf_counter()
-        with _tracing.span("serving.decode_step", cat="serving",
-                           n_active=n_active), \
-                _compile_scope("decode"):
+        called = time.perf_counter()
+        with _compile_scope("decode"):
             if paged:
                 out = self._run_program(
                     "decode", ("decode",), self._decode,
@@ -1937,11 +2044,16 @@ class Engine:
                     self._decode_statics, "decode")
         nxt, self.cache.kc, self.cache.vc, self._cur, self._keys = out
         self._tok = nxt
-        self.metrics.mark_decode(time.perf_counter() - t0)
+        dispatched = time.perf_counter()
         toks = np.asarray(nxt)
+        fetched = time.perf_counter()
+        self.metrics.mark_decode(fetched - called)
+        self._launched("decode", called, dispatched, fetched,
+                       tokens=n_active)
         for slot in np.nonzero(active)[0]:
             h = self._by_slot[int(slot)]
             self._emit(h, int(toks[slot]))
+        return dispatched, fetched
 
     # -- speculative decoding (draft-verify; serving/speculative.py) ------
 
@@ -1999,15 +2111,19 @@ class Engine:
                 plain[h.slot] = True
             else:
                 plan.append((h, np.asarray(props[:k_cap], np.int32)))
+        # the step's stamps: the first target launch's dispatched and
+        # the last one's fetched (None where nothing was launched)
+        dispatched = fetched = None
         if plain.any():
-            self._decode_once(plain, int(plain.sum()))
+            dispatched, fetched = self._decode_once(plain, int(plain.sum()))
         for h, props in plan:
             if h.finished or h.slot is None:
                 continue        # finished/preempted earlier this step
             if not self._ensure_spec_capacity(h, len(props)):
                 continue        # preempted while reserving draft lines
-            self._verify_one(h, props)
-        return n_active
+            d, fetched = self._verify_one(h, props)
+            dispatched = dispatched or d
+        return dispatched, fetched
 
     def _verify_one(self, h, props):
         """Verify one slot's draft chunk and emit its accepted tokens
@@ -2018,10 +2134,8 @@ class Engine:
         ids = np.zeros((1, K1), np.int32)
         ids[0, 0] = h.tokens[-1]
         ids[0, 1:1 + k_eff] = props
-        t0 = time.perf_counter()
-        with _tracing.span("spec.verify", cat="serving",
-                           trace_id=h.trace_id, request_id=h.request_id,
-                           k=k_eff), _compile_scope("verify"):
+        called = time.perf_counter()
+        with _compile_scope("verify"):
             out = self._run_program(
                 "verify", ("verify", K1), self._verify,
                 (self._w, self.cache.kc, self.cache.vc, self._keys, ids,
@@ -2032,8 +2146,12 @@ class Engine:
                 self._paged_statics, "spec.verify")
         self.cache.kc, self.cache.vc, samples, chain = out
         self.verify_used = True
+        dispatched = time.perf_counter()
         samples = np.asarray(samples)
         chain = np.asarray(chain)
+        fetched = time.perf_counter()
+        self._launched("spec.verify", called, dispatched, fetched,
+                       "spec.verify", h, tokens=k_eff + 1, k=k_eff)
         m = 0
         while m < k_eff and samples[m] == props[m]:
             m += 1
@@ -2049,7 +2167,7 @@ class Engine:
         cur_h[slot] = p + e
         keys_h[slot] = chain[e - 1]
         self._tok, self._cur, self._keys = tok_h, cur_h, keys_h
-        self.metrics.mark_decode(time.perf_counter() - t0, tokens=e)
+        self.metrics.mark_decode(fetched - called, tokens=e)
         self.metrics.spec_steps += 1
         self.metrics.spec_proposed_tokens += k_eff
         self.metrics.spec_accepted_tokens += m
@@ -2057,8 +2175,9 @@ class Engine:
         for t in samples[:e]:
             self._emit(h, int(t))
             if h.finished:
-                return
+                return dispatched, fetched
         self._spec.after_verify(h, int(samples[e - 1]), p + e)
+        return dispatched, fetched
 
     def _emit(self, h, token):
         if self._condemned:

@@ -5,9 +5,19 @@ latencies, tokens/sec. Per-engine: slot occupancy and queue depth sampled
 every decode step, admission/eviction counters. Snapshots surface through
 ``paddle_tpu.profiler.serving_counters()`` (the same counter plumbing as
 the eager dispatch cache) and feed tools/bench_serving.py's JSON ledger.
+
+Per-engine too, always on: the ``perf_counter`` stamps the engine takes
+inside ``Engine.step()`` and around every program it launches, as
+:class:`StepRecord` / :class:`LaunchRecord` / :class:`SubmitRecord`
+tuples in bounded rings (``EngineMetrics.steps``, ``.launches``,
+``.submits``; :func:`live_metrics` finds every live engine's), with
+cumulative seconds by phase beside them. The same stamps feed
+``mark_decode`` (so the ITL estimate covers the token fetch) and, when
+the span tracer is on, its ``serving.step`` spans.
 """
 from __future__ import annotations
 
+import collections
 import time
 import weakref
 
@@ -70,6 +80,56 @@ class RequestMetrics:
         return self.n_tokens / dt if dt > 0 else float("inf")
 
 
+PHASES = ("schedule", "dispatch", "fetch", "emit")
+RING = 8192     # records a ring keeps: minutes of steps at 10 ms a step
+
+
+class StepRecord(collections.namedtuple(
+        "StepRecord", "index kind begin scheduled dispatched fetched end "
+                      "n_active")):
+    """One ``Engine.step()`` that had decode-active rows. ``kind`` is
+    ``decode`` (nothing prefilled), ``admit`` (a bucket prefill or a
+    chunk ran beside the decode) or ``spec``. The stamps are
+    ``time.perf_counter()`` reads, in order: ``begin``; ``scheduled``
+    (expiry, admission, the chunk tick, decode capacity and the
+    occupancy sample are done); ``dispatched`` (the decode program's
+    call has returned: argument copies, upload and enqueue are behind
+    it); ``fetched`` (its tokens are on the host); ``end`` (every token
+    emitted). A ``spec`` step launches several programs: ``dispatched``
+    is the first target launch's (the draft's proposals lie before it)
+    and ``fetched`` the last one's; the launches between them are in
+    the launch ring under the step's index."""
+    __slots__ = ()
+
+    def intervals(self):
+        """``[(phase, t0, t1)]`` of the four phases, in order."""
+        return list(zip(PHASES, self[2:6], self[3:7]))
+
+    def phases(self):
+        """Seconds of the four phases; they sum to ``end - begin``."""
+        return {p: t1 - t0 for p, t0, t1 in self.intervals()}
+
+
+# One engine program invoked: ``program`` as the compile scope names it
+# (``prefill:L<bucket>``, ``chunk``, ``decode``, ``spec.verify``,
+# ``spec.draft``, ``spec.draft:L<bucket>``), ``step`` the index of the
+# ``Engine.step()`` it ran in (None inside ``submit()`` / ``adopt()``),
+# ``called`` before the arguments were built, ``dispatched`` when the call
+# returned, ``fetched`` when the engine had its result on the host (None
+# where it fetches none: a non-final chunk), the request it served (None
+# for a batched program), its tokens, and those of them the radix served.
+LaunchRecord = collections.namedtuple(
+    "LaunchRecord", "program step called dispatched fetched request_id "
+                    "tokens radix_tokens")
+
+# One ``Engine.submit()`` or ``adopt()`` that enqueued its request, from
+# entry to return: validation, the queue, and, where a slot was free, the
+# admission itself (block allocation with radix eviction, a bucket
+# prefill's launch: that one also has its ``LaunchRecord``, with no step).
+SubmitRecord = collections.namedtuple("SubmitRecord",
+                                      "request_id begin end")
+
+
 class EngineMetrics:
     """Aggregate counters for one Engine; registered in the module-wide
     ledger so profiler.serving_counters() sees every live engine."""
@@ -125,7 +185,30 @@ class EngineMetrics:
         # registry's merged paddle_serving_itl_seconds family
         self.itl_hist = Histogram("serving_itl_seconds_local",
                                   window=64, registry=None)
+        # the engine's own stamps (module docstring): the newest RING
+        # records of each kind, and what no ring forgets
+        self.steps = collections.deque(maxlen=RING)
+        self.launches = collections.deque(maxlen=RING)
+        self.submits = collections.deque(maxlen=RING)
+        self.steps_recorded = 0
+        self.launches_recorded = 0
+        self.phase_seconds = dict.fromkeys(PHASES, 0.0)
+        self.submit_seconds = 0.0
         _register(self)
+
+    def mark_step(self, rec):
+        self.steps.append(rec)
+        self.steps_recorded += 1
+        for phase, t0, t1 in rec.intervals():
+            self.phase_seconds[phase] += t1 - t0
+
+    def mark_launch(self, rec):
+        self.launches.append(rec)
+        self.launches_recorded += 1
+
+    def mark_submit(self, rec):
+        self.submits.append(rec)
+        self.submit_seconds += rec.end - rec.begin
 
     def sample(self, occupancy, queue_depth, active=0, pool_free=None,
                pool_total=None):
@@ -151,8 +234,11 @@ class EngineMetrics:
 
     def mark_decode(self, duration_s, tokens=1):
         """Record one target-model step (fused decode OR speculative
-        verify). ``tokens`` is how many tokens the step emitted per
-        participating request: the ITL histogram records PER-EMITTED-
+        verify): ``duration_s`` from the launch's call to its tokens on
+        the host (dispatch is asynchronous on the chip; without the
+        fetch this would time the enqueue). ``tokens`` is how many
+        tokens the step emitted per participating request: the ITL
+        histogram records PER-EMITTED-
         TOKEN intervals (``tokens`` observations of
         ``duration_s/tokens``), so the brownout SLO p95 and the
         ``retry_after_s`` hint stay meaningful when one speculative
@@ -227,6 +313,11 @@ class EngineMetrics:
                                 else round(itl * 1e3, 3)),
             "itl_p95_ms": (None if p95 is None
                            else round(p95 * 1e3, 3)),
+            "steps_recorded": self.steps_recorded,
+            "launches_recorded": self.launches_recorded,
+            "step_phase_seconds": {k: round(v, 6) for k, v in
+                                   self.phase_seconds.items()},
+            "submit_seconds": round(self.submit_seconds, 6),
             "replica": self.replica,
             "tp": self.tp,
             "kv_pool_bytes_per_device": self.kv_pool_bytes_per_device,
@@ -240,6 +331,17 @@ _ENGINES = []   # weakrefs; dead engines drop out of the global snapshot
 
 def _register(m):
     _ENGINES.append(weakref.ref(m))
+
+
+def live_metrics():
+    """The ``EngineMetrics`` of every live engine, oldest first: the way
+    to their rings (``.steps``, ``.launches``, ``.submits``:
+    :class:`StepRecord`, :class:`LaunchRecord` and :class:`SubmitRecord`,
+    oldest record first, every stamp a ``time.perf_counter()`` of this
+    process) for whoever holds no engine."""
+    live = [(ref, ref()) for ref in _ENGINES]
+    _ENGINES[:] = [ref for ref, m in live if m is not None]
+    return [m for _, m in live if m is not None]
 
 
 def global_counters():
@@ -257,15 +359,13 @@ def global_counters():
         "spec_steps": 0, "draft_steps": 0, "spec_proposed_tokens": 0,
         "spec_accepted_tokens": 0, "spec_emitted_tokens": 0,
         "spec_acceptance_rate": None,
+        "step_phase_seconds": dict.fromkeys(PHASES, 0.0),
     }
-    live = []
-    for ref in _ENGINES:
-        m = ref()
-        if m is None:
-            continue
-        live.append(ref)
+    for m in live_metrics():
         s = m.snapshot()
         total["engines"] += 1
+        for k, v in m.phase_seconds.items():
+            total["step_phase_seconds"][k] += v
         for k in ("requests_submitted", "requests_completed",
                   "requests_rejected", "requests_timed_out",
                   "requests_cancelled", "requests_shed",
@@ -284,7 +384,6 @@ def global_counters():
             total["pool_low_watermark"] = (
                 s["pool_low_watermark"] if lw is None
                 else min(lw, s["pool_low_watermark"]))
-    _ENGINES[:] = live
     if total["prompt_tokens"]:
         total["prefix_hit_rate"] = round(
             total["prefix_hit_tokens"] / total["prompt_tokens"], 4)

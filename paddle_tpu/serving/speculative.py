@@ -46,6 +46,7 @@ blocks, so unverified tokens can never be published for sharing.
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import numpy as np
 
@@ -197,7 +198,6 @@ class _ModelDraft:
         (prompt + replayed tokens) — the admission/replay counterpart of
         the target prefill. The draft then chains from the TARGET's
         sampled token, not its own first guess."""
-        from ..observability import tracing as _tracing
         from ..observability.compile_attr import compile_scope
         eng = self.engine
         slot, n_eff = h.slot, len(full)
@@ -206,9 +206,8 @@ class _ModelDraft:
         ids = np.zeros((1, Lb), np.int32)
         ids[0, :n_eff] = full
         prefill, _ = self._programs()
-        with _tracing.span("spec.draft_prefill", cat="serving",
-                           trace_id=h.trace_id, request_id=h.request_id,
-                           bucket=Lb), compile_scope(f"spec.draft:L{Lb}"):
+        called = time.perf_counter()
+        with compile_scope(f"spec.draft:L{Lb}"):
             out = eng._run_program(
                 "draft_prefill", ("draft_prefill", Lb), prefill,
                 (self._w, self.kc, self.vc, self.tok, self.cur,
@@ -217,7 +216,11 @@ class _ModelDraft:
                  eng._vmask[slot].copy()),
                 self._statics, f"spec.draft:L{Lb}")
         self.kc, self.vc, tok, self.cur, self.keys, _ = out
+        dispatched = time.perf_counter()
         tok = self._host(tok)
+        eng._launched(f"spec.draft:L{Lb}", called, dispatched,
+                      time.perf_counter(), "spec.draft_prefill", h,
+                      tokens=n_eff, bucket=Lb)
         tok[slot] = h.tokens[-1]
         self.tok = tok
 
@@ -226,7 +229,6 @@ class _ModelDraft:
         verify-eligible slot at once; the first k outputs are the
         proposals (the extra step writes the k-th proposal's KV so a
         full accept leaves no draft-cache hole)."""
-        from ..observability import tracing as _tracing
         from ..observability.compile_attr import compile_scope
         eng = self.engine
         if not cand:
@@ -237,10 +239,9 @@ class _ModelDraft:
         _, decode = self._programs()
         outs = {h.slot: [] for h, _ in cand}
         k = eng.spec.k
-        with _tracing.span("spec.draft", cat="serving",
-                           n_slots=len(cand), k=k), \
-                compile_scope("spec.draft"):
-            for _ in range(k + 1):
+        with compile_scope("spec.draft"):
+            for i in range(k + 1):
+                called = time.perf_counter()
                 out = eng._run_program(
                     "draft_decode", ("draft_decode",), decode,
                     (self._w, self.kc, self.vc, self.tok, self.cur,
@@ -248,7 +249,11 @@ class _ModelDraft:
                     self._statics, "spec.draft")
                 nxt, self.kc, self.vc, self.cur, self.keys = out
                 self.tok = nxt
+                dispatched = time.perf_counter()
                 toks = np.asarray(nxt)
+                eng._launched("spec.draft", called, dispatched,
+                              time.perf_counter(), "spec.draft",
+                              tokens=len(cand), draft_step=i, k=k)
                 for h, _ in cand:
                     outs[h.slot].append(int(toks[h.slot]))
                 eng.metrics.draft_steps += 1

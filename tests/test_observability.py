@@ -228,12 +228,12 @@ def test_serving_request_trace_full_lifecycle(model):
     by_name = {}
     for s in obs.spans():
         if (s.get("args") or {}).get("request_id") == h.request_id \
-                or s["name"] == "serving.decode_step":
+                or s["name"] == "serving.step":
             by_name.setdefault(s["name"], []).append(s)
     for phase in ("serving.submit", "serving.queue", "serving.prefill",
                   "serving.decode", "serving.finish"):
         assert phase in by_name, f"missing {phase}"
-    assert "serving.decode_step" in by_name
+    assert "serving.step" in by_name
     # every request-scoped phase links to the handle's one trace id
     for phase in ("serving.submit", "serving.queue", "serving.prefill",
                   "serving.decode", "serving.finish"):
