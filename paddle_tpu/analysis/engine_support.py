@@ -8,7 +8,11 @@ from __future__ import annotations
 
 def engine_donates(engine) -> bool:
     """True when the engine was built on the donating prefill/decode
-    programs (KV buffers/pool updated in place)."""
+    programs (KV buffers/pool updated in place). For the paged layout
+    "in place" is the whole of it: the decode, chunk and verify
+    programs carry the pool through their layer loop
+    (``serving.engine._scan_layers_over_pool``), so a donated pool is
+    the output's buffer and only the rows written move."""
     from ..serving import engine as E
 
     if getattr(engine, "tp", 1) > 1:
@@ -22,7 +26,10 @@ def lower_decode_program(engine) -> str:
     """Lower the engine's fused decode step against its live state and
     return the StableHLO text — the same program the engine executes
     (slot, paged or tensor-parallel layout), so dtype/padding/collective
-    rules audit real serving HLO, not a proxy."""
+    rules audit real serving HLO, not a proxy. In the paged and tp
+    programs the layer loop is one ``while`` whose carry holds the K and
+    V pools flat over layers, ``[L*nb, bs, kv, hd]``; the text shows
+    them reshaped back to ``[L, nb, bs, kv, hd]`` on return."""
     import jax
     import jax.numpy as jnp
 

@@ -342,6 +342,37 @@ def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
     return kc, vc, tok, cur_pos, keys, tok0
 
 
+def _scan_layers_over_pool(layer, stack, x, kc, vc, block_ids, row_ids):
+    """The layer loop of the paged programs that write the pool (decode,
+    chunk, verify, the tp copies). The pools ride ``lax.scan`` as a
+    CARRY, flat over layers — ``[L, nb, bs, kv, hd]`` bitcast to ONE pool
+    of ``L*nb`` blocks — so a donated pool is the output's buffer and
+    each layer's rows are scattered in place; as ``xs`` in / ``ys`` out
+    both whole pools were copied, sliced and re-stacked every call.
+
+    ``layer(x, lw, kc_pool, vc_pool, block_ids, row_ids)`` is a per-layer
+    body of ``text/generation.py`` with the rest bound: to it a pool of
+    ``L*nb`` blocks is a pool, and layer ``i`` gets its indices moved to
+    its range, ``block_ids + i*nb`` and ``row_ids + i*nb*bs`` (block
+    ``i*nb`` is that layer's trash block). Ids are int32, so the pool's
+    rows over all layers, ``L*nb*bs``, stay under 2**31 (196 704 at 6 x
+    2049 x 16). Returns ``(x, kc, vc)``, the pools shaped as they came."""
+    L, nb, bs = kc.shape[:3]
+    assert L * nb * bs < 2 ** 31, "pool rows over all layers overflow int32"
+
+    def one(cx, lw_i):
+        lw, i = lw_i
+        x2, k2, v2 = layer(cx["x"], lw, cx["kc"], cx["vc"],
+                           block_ids + i * nb, row_ids + i * (nb * bs))
+        return {"x": x2, "kc": k2, "vc": v2}, None
+
+    flat = (L * nb,) + kc.shape[2:]
+    cx, _ = jax.lax.scan(
+        one, {"x": x, "kc": kc.reshape(flat), "vc": vc.reshape(flat)},
+        (stack, jnp.arange(L, dtype=jnp.int32)))
+    return cx["x"], cx["kc"].reshape(kc.shape), cx["vc"].reshape(vc.shape)
+
+
 def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
                        temps, vmasks, *, arch, n_heads, n_kv, eps, theta,
                        do_sample, top_k, top_p, block_size,
@@ -354,7 +385,8 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
     tuner-registered pallas flash-decode kernel (block-table-aware DMA +
     online softmax, no gathered view). ONE program for the life of the
     engine — the block table is a plain runtime operand of static
-    shape."""
+    shape; the pool is a carry of the layer loop
+    (``_scan_layers_over_pool``), written in place when donated."""
     from ..text import generation as G
 
     S = tok.shape[0]
@@ -366,34 +398,29 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
         xt = jnp.take(w["embed"], tok, axis=0)[:, None]
         stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
 
-        def one(cx, lw_kv):
-            xt2, kc_l, vc_l = G._llama_decode_layer_paged(
-                cx["x"], lw_kv, lw_kv["kc"], lw_kv["vc"], tables, dest,
-                cur_pos, cur_pos, n_heads=n_heads, n_kv=n_kv, eps=eps,
-                theta=theta, block_size=block_size,
-                flash_decode=flash_decode)
-            return {"x": xt2}, (kc_l, vc_l)
+        def layer(xc, lw, kc_p, vc_p, blocks, rows):
+            return G._llama_decode_layer_paged(
+                xc, lw, kc_p, vc_p, blocks, rows, cur_pos, cur_pos,
+                n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
+                block_size=block_size, flash_decode=flash_decode)
     else:
         xt = (jnp.take(w["wte"], tok, axis=0)
               + jnp.take(w["wpe"], cur_pos, axis=0))[:, None]
         stack = {k: w[k] for k in G._GPT_STACK_KEYS}
 
-        def one(cx, lw_kv):
-            xt2, kc_l, vc_l = G._gpt_decode_layer_paged(
-                cx["x"], lw_kv, lw_kv["kc"], lw_kv["vc"], tables, dest,
-                cur_pos, n_heads=n_heads, block_size=block_size,
+        def layer(xc, lw, kc_p, vc_p, blocks, rows):
+            return G._gpt_decode_layer_paged(
+                xc, lw, kc_p, vc_p, blocks, rows, cur_pos,
+                n_heads=n_heads, block_size=block_size,
                 flash_decode=flash_decode)
-            return {"x": xt2}, (kc_l, vc_l)
 
-    lw_kv = dict(stack)
-    lw_kv["kc"] = kc
-    lw_kv["vc"] = vc
-    cx, (kc, vc) = jax.lax.scan(one, {"x": xt}, lw_kv)
+    xt, kc, vc = _scan_layers_over_pool(layer, stack, xt, kc, vc, tables,
+                                        dest)
     if arch == "llama":
-        hidden = G._rms(cx["x"][:, 0], w["norm"], eps)
+        hidden = G._rms(xt[:, 0], w["norm"], eps)
         logits = hidden @ w["head"]
     else:
-        logits = G._ln(cx["x"][:, 0], w["lnfw"], w["lnfb"]) @ w["head"]
+        logits = G._ln(xt[:, 0], w["lnfw"], w["lnfb"]) @ w["head"]
     logits = jnp.where(vmasks > 0, logits, -jnp.inf)
 
     split = jax.vmap(jax.random.split)(keys)        # [S, 2, 2]
@@ -423,7 +450,8 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
     runtime operand gating the sampling side effects), so chunked
     prefill costs exactly ONE extra lowering, independent of prompt
     length. Sampling uses the admission-seeded PRNG chain with the
-    supervisor-replay ``skip`` fast-forward, like the one-shot paths."""
+    supervisor-replay ``skip`` fast-forward, like the one-shot paths.
+    The pool is a carry of the layer loop, as in the decode program."""
     from ..text import generation as G
 
     C = ids.shape[1]
@@ -437,33 +465,29 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
         x = jnp.take(w["embed"], ids, axis=0)
         stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
 
-        def one(cx, lw_kv):
-            x2, kc_l, vc_l = G._llama_chunk_layer(
-                cx["x"], lw_kv, lw_kv["kc"], lw_kv["vc"], table_row, gpos,
-                wdest, n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
+        def layer(xc, lw, kc_p, vc_p, blocks, rows):
+            return G._llama_chunk_layer(
+                xc, lw, kc_p, vc_p, blocks, gpos, rows,
+                n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
                 block_size=block_size)
-            return {"x": x2}, (kc_l, vc_l)
     else:
         x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][gpos][None]
         stack = {k: w[k] for k in G._GPT_STACK_KEYS}
 
-        def one(cx, lw_kv):
-            x2, kc_l, vc_l = G._gpt_chunk_layer(
-                cx["x"], lw_kv, lw_kv["kc"], lw_kv["vc"], table_row, gpos,
-                wdest, n_heads=n_heads, block_size=block_size)
-            return {"x": x2}, (kc_l, vc_l)
+        def layer(xc, lw, kc_p, vc_p, blocks, rows):
+            return G._gpt_chunk_layer(
+                xc, lw, kc_p, vc_p, blocks, gpos, rows,
+                n_heads=n_heads, block_size=block_size)
 
-    lw_kv = dict(stack)
-    lw_kv["kc"] = kc
-    lw_kv["vc"] = vc
-    cx, (kc, vc) = jax.lax.scan(one, {"x": x}, lw_kv)
+    x, kc, vc = _scan_layers_over_pool(layer, stack, x, kc, vc, table_row,
+                                       wdest)
     li = jnp.clip(n_prompt - 1 - chunk_start, 0, C - 1)
     if arch == "llama":
         hlast = jax.lax.dynamic_index_in_dim(
-            G._rms(cx["x"], w["norm"], eps)[0], li, 0, keepdims=False)
+            G._rms(x, w["norm"], eps)[0], li, 0, keepdims=False)
         logits0 = hlast @ w["head"]
     else:
-        xlast = jax.lax.dynamic_index_in_dim(cx["x"][0], li, 0,
+        xlast = jax.lax.dynamic_index_in_dim(x[0], li, 0,
                                              keepdims=False)
         logits0 = G._ln(xlast, w["lnfw"], w["lnfb"]) @ w["head"]
 
@@ -573,7 +597,8 @@ def _tp_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys, temps,
     kv-head shard into its pool shard and attends over its local head
     group; the o-/down-projections and the vocab head are overlapped
     collective-matmuls, so the decode HLO contains only
-    ``collective_permute`` ops — nothing serializes after a dot."""
+    ``collective_permute`` ops — nothing serializes after a dot. The
+    LOCAL pool shard is a carry of the layer loop, as on one device."""
     from ..text import generation as G
 
     S = tok.shape[0]
@@ -585,31 +610,27 @@ def _tp_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys, temps,
         xt = jnp.take(w["embed"], tok, axis=0)[:, None]
         stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
 
-        def one(cx, lw_kv):
-            xt2, kc_l, vc_l = G._llama_decode_layer_paged_tp(
-                cx["x"], lw_kv, lw_kv["kc"], lw_kv["vc"], tables, dest,
-                cur_pos, cur_pos, n_heads=n_heads, n_kv=n_kv, eps=eps,
-                theta=theta, block_size=block_size, tp=tp)
-            return {"x": xt2}, (kc_l, vc_l)
+        def layer(xc, lw, kc_p, vc_p, blocks, rows):
+            return G._llama_decode_layer_paged_tp(
+                xc, lw, kc_p, vc_p, blocks, rows, cur_pos, cur_pos,
+                n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
+                block_size=block_size, tp=tp)
     else:
         xt = (jnp.take(w["wte"], tok, axis=0)
               + jnp.take(w["wpe"], cur_pos, axis=0))[:, None]
         stack = {k: w[k] for k in G._GPT_STACK_KEYS}
 
-        def one(cx, lw_kv):
-            xt2, kc_l, vc_l = G._gpt_decode_layer_paged_tp(
-                cx["x"], lw_kv, lw_kv["kc"], lw_kv["vc"], tables, dest,
-                cur_pos, n_heads=n_heads, block_size=block_size, tp=tp)
-            return {"x": xt2}, (kc_l, vc_l)
+        def layer(xc, lw, kc_p, vc_p, blocks, rows):
+            return G._gpt_decode_layer_paged_tp(
+                xc, lw, kc_p, vc_p, blocks, rows, cur_pos,
+                n_heads=n_heads, block_size=block_size, tp=tp)
 
-    lw_kv = dict(stack)
-    lw_kv["kc"] = kc
-    lw_kv["vc"] = vc
-    cx, (kc, vc) = jax.lax.scan(one, {"x": xt}, lw_kv)
+    xt, kc, vc = _scan_layers_over_pool(layer, stack, xt, kc, vc, tables,
+                                        dest)
     if arch == "llama":
-        hidden = G._rms(cx["x"][:, 0], w["norm"], eps)
+        hidden = G._rms(xt[:, 0], w["norm"], eps)
     else:
-        hidden = G._ln(cx["x"][:, 0], w["lnfw"], w["lnfb"])
+        hidden = G._ln(xt[:, 0], w["lnfw"], w["lnfb"])
     logits = G.matmul_allgather(hidden, w["head"], G._TP_AXIS, tp)
     logits = jnp.where(vmasks > 0, logits, -jnp.inf)
 
@@ -634,7 +655,7 @@ def _tp_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
     """Tensor-parallel chunked-prefill step (inside shard_map): the SAME
     one-extra-lowering contract as ``_paged_chunk_impl`` — every chunk
     of every long prompt shares this program, ``is_final`` gating the
-    sampling side effects as a runtime operand."""
+    sampling side effects as a runtime operand (pool shard: a carry)."""
     from ..text import generation as G
 
     C = ids.shape[1]
@@ -648,34 +669,30 @@ def _tp_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
         x = jnp.take(w["embed"], ids, axis=0)
         stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
 
-        def one(cx, lw_kv):
-            x2, kc_l, vc_l = G._llama_chunk_layer_tp(
-                cx["x"], lw_kv, lw_kv["kc"], lw_kv["vc"], table_row, gpos,
-                wdest, n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
+        def layer(xc, lw, kc_p, vc_p, blocks, rows):
+            return G._llama_chunk_layer_tp(
+                xc, lw, kc_p, vc_p, blocks, gpos, rows,
+                n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
                 block_size=block_size, tp=tp)
-            return {"x": x2}, (kc_l, vc_l)
     else:
         x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][gpos][None]
         stack = {k: w[k] for k in G._GPT_STACK_KEYS}
 
-        def one(cx, lw_kv):
-            x2, kc_l, vc_l = G._gpt_chunk_layer_tp(
-                cx["x"], lw_kv, lw_kv["kc"], lw_kv["vc"], table_row, gpos,
-                wdest, n_heads=n_heads, block_size=block_size, tp=tp)
-            return {"x": x2}, (kc_l, vc_l)
+        def layer(xc, lw, kc_p, vc_p, blocks, rows):
+            return G._gpt_chunk_layer_tp(
+                xc, lw, kc_p, vc_p, blocks, gpos, rows,
+                n_heads=n_heads, block_size=block_size, tp=tp)
 
-    lw_kv = dict(stack)
-    lw_kv["kc"] = kc
-    lw_kv["vc"] = vc
-    cx, (kc, vc) = jax.lax.scan(one, {"x": x}, lw_kv)
+    x, kc, vc = _scan_layers_over_pool(layer, stack, x, kc, vc, table_row,
+                                       wdest)
     li = jnp.clip(n_prompt - 1 - chunk_start, 0, C - 1)
     if arch == "llama":
         hlast = jax.lax.dynamic_index_in_dim(
-            G._rms(cx["x"], w["norm"], eps)[0], li, 0, keepdims=False)
+            G._rms(x, w["norm"], eps)[0], li, 0, keepdims=False)
         logits0 = G.matmul_allgather(hlast[None], w["head"], G._TP_AXIS,
                                      tp)[0]
     else:
-        xlast = jax.lax.dynamic_index_in_dim(cx["x"][0], li, 0,
+        xlast = jax.lax.dynamic_index_in_dim(x[0], li, 0,
                                              keepdims=False)
         logits0 = G.matmul_allgather(
             G._ln(xlast, w["lnfw"], w["lnfb"])[None], w["head"],
@@ -711,7 +728,8 @@ def _spec_verify_impl(w, kc, vc, keys, ids, start, slot, table_row,
     math). ``ids`` [1, k+1] = [last emitted token, d_1..d_k] at global
     positions ``start + j``; candidate K/V scatters through the slot's
     block-table row with positions at/above ``n_write`` (draft width
-    clamped by remaining budget / max_len) trash-redirected.
+    clamped by remaining budget / max_len) trash-redirected; the pool
+    is a carry of the layer loop, as in the decode and chunk programs.
 
     Token-identical acceptance, on-device half: starting from the
     slot's CURRENT chain key (``keys[slot]``), each position re-runs the
@@ -739,30 +757,26 @@ def _spec_verify_impl(w, kc, vc, keys, ids, start, slot, table_row,
         x = jnp.take(w["embed"], ids, axis=0)
         stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
 
-        def one(cx, lw_kv):
-            x2, kc_l, vc_l = G._llama_verify_layer(
-                cx["x"], lw_kv, lw_kv["kc"], lw_kv["vc"], table_row, gpos,
-                wdest, n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
+        def layer(xc, lw, kc_p, vc_p, blocks, rows):
+            return G._llama_verify_layer(
+                xc, lw, kc_p, vc_p, blocks, gpos, rows,
+                n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
                 block_size=block_size)
-            return {"x": x2}, (kc_l, vc_l)
     else:
         x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][gpos][None]
         stack = {k: w[k] for k in G._GPT_STACK_KEYS}
 
-        def one(cx, lw_kv):
-            x2, kc_l, vc_l = G._gpt_verify_layer(
-                cx["x"], lw_kv, lw_kv["kc"], lw_kv["vc"], table_row, gpos,
-                wdest, n_heads=n_heads, block_size=block_size)
-            return {"x": x2}, (kc_l, vc_l)
+        def layer(xc, lw, kc_p, vc_p, blocks, rows):
+            return G._gpt_verify_layer(
+                xc, lw, kc_p, vc_p, blocks, gpos, rows,
+                n_heads=n_heads, block_size=block_size)
 
-    lw_kv = dict(stack)
-    lw_kv["kc"] = kc
-    lw_kv["vc"] = vc
-    cx, (kc, vc) = jax.lax.scan(one, {"x": x}, lw_kv)
+    x, kc, vc = _scan_layers_over_pool(layer, stack, x, kc, vc, table_row,
+                                       wdest)
     if arch == "llama":
-        logits = G._rms(cx["x"], w["norm"], eps)[0] @ w["head"]
+        logits = G._rms(x, w["norm"], eps)[0] @ w["head"]
     else:
-        logits = G._ln(cx["x"][0], w["lnfw"], w["lnfb"]) @ w["head"]
+        logits = G._ln(x[0], w["lnfw"], w["lnfb"]) @ w["head"]
     logits = jnp.where(vmask[None, :] > 0, logits, -jnp.inf)   # [K1, V]
 
     def samp(key, logits_i):
