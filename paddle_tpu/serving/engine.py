@@ -1,5 +1,6 @@
 """Iteration-level (continuous) batching engine over the stacked-weight
-Llama/GPT decode path.
+Llama/GPT decode path (and, through the llama bodies, a decoder whose
+layers differ in kind and route their feed-forward: ``_make_arch``).
 
 Design (ROADMAP north star: serve concurrent, asynchronously arriving
 requests without ever recompiling):
@@ -271,9 +272,10 @@ def _decode_impl(w, kc, vc, tok, cur_pos, active, keys, temps, vmasks, *,
 
 
 def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
-                        seed, skip, temp, table_row, skip_write, vmask, *,
-                        arch, n_heads, n_kv, eps, theta, do_sample, top_k,
-                        top_p, block_size):
+                        seed, skip, temp, table_row, skip_write, vmask,
+                        moe=None, *, arch, n_heads, n_kv, eps, theta,
+                        do_sample, top_k, top_p, block_size, kinds=None,
+                        window=None, moe_k=0):
     """Paged prefill: the SAME full causal forward as ``_prefill_impl``
     (so the first sampled token is bit-identical to the slot engine and
     ``generate()``), but K/V lands in the paged pool through the slot's
@@ -281,20 +283,31 @@ def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
     ``skip_write`` (radix-shared prefix, already resident from the
     producing request) and at/above ``n_prompt`` (bucket padding)
     redirect into the trash block, so shared blocks are NEVER rewritten
-    and prefix sharing cannot perturb a co-batched neighbour."""
+    and prefix sharing cannot perturb a co-batched neighbour.
+
+    ``kinds`` / ``window`` / ``moe_k`` describe a model whose layers
+    differ in kind and route their feed-forward (``_make_arch``); its
+    counters ``moe`` come in last and go out last, with the picks of the
+    prompt's own positions added."""
     from ..text import generation as G
 
     Lb = ids.shape[1]
     if arch == "llama":
         x = jnp.take(w["embed"], ids, axis=0)
         pos = jnp.arange(Lb)
-        stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
+        real = jnp.arange(Lb) < n_prompt
 
-        def one(xc, lw):
-            return G._llama_prefill_layer(xc, lw, pos, n_heads=n_heads,
-                                          n_kv=n_kv, eps=eps, theta=theta)
+        def layer_of(win):
+            def one(xc, lw):
+                return G._llama_prefill_layer(
+                    xc, lw, pos, n_heads=n_heads, n_kv=n_kv, eps=eps,
+                    theta=theta, window=win, moe_k=moe_k, valid=real)
+            return one
 
-        x, kvs = jax.lax.scan(one, x, stack)
+        x, kvs = _scan_layers(_by_kind(layer_of, kinds, window),
+                              G._llama_stack(w), x)
+        if moe is not None:
+            moe = _count_picks(moe, kvs[2])
         hlast = jax.lax.dynamic_index_in_dim(
             G._rms(x, w["norm"], eps)[0], n_prompt - 1, 0, keepdims=False)
         logits0 = hlast @ w["head"]
@@ -319,10 +332,19 @@ def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
                      j % block_size)             # trash block rows
     L, nb, bs = kc.shape[0], kc.shape[1], kc.shape[2]
     kvh, hd = kc.shape[3], kc.shape[4]
-    kc = kc.reshape(L, nb * bs, kvh, hd).at[:, dest].set(
-        kvs[0][:, 0]).reshape(L, nb, bs, kvh, hd)
-    vc = vc.reshape(L, nb * bs, kvh, hd).at[:, dest].set(
-        kvs[1][:, 0]).reshape(L, nb, bs, kvh, hd)
+    # written as rows of the pool flat over layers, the form the chunk and
+    # decode programs write and gather (``generation._paged_view``):
+    # scattered a layer's slab at a time, a pool whose lines fill under a
+    # tile (4 KV heads) is relaid whole around the scatter (four copies of
+    # 1.07 GB at depth 8, compiled for a described v5e); at 32 heads a
+    # 256-token bucket reads 9.49 ms this way against 9.28 by slabs (my
+    # chip run, PR 27), so there is one form
+    rows = (dest[None, :] + (nb * bs) * jnp.arange(L)[:, None]).reshape(
+        L * Lb)
+    kc = kc.reshape(L * nb * bs, kvh, hd).at[rows].set(
+        kvs[0][:, 0].reshape(L * Lb, kvh, hd)).reshape(L, nb, bs, kvh, hd)
+    vc = vc.reshape(L * nb * bs, kvh, hd).at[rows].set(
+        kvs[1][:, 0].reshape(L * Lb, kvh, hd)).reshape(L, nb, bs, kvh, hd)
 
     key = jax.random.PRNGKey(seed)
     key = jax.lax.fori_loop(0, skip,
@@ -339,7 +361,40 @@ def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
     tok = tok.at[slot].set(tok0)
     cur_pos = cur_pos.at[slot].set(n_prompt.astype(jnp.int32))
     keys = keys.at[slot].set(key)
+    if moe is not None:
+        return kc, vc, tok, cur_pos, keys, tok0, moe
     return kc, vc, tok, cur_pos, keys, tok0
+
+
+def _by_kind(make, kinds, window):
+    """The per-layer bodies of a program's layer loop: ``make(window)``
+    once for a model whose layers are all alike (``kinds`` None), else
+    one for each layer, a ``sliding_attention`` layer with the model's
+    window and any other without."""
+    if kinds is None:
+        return make(None)
+    return tuple(make(window if k == "sliding_attention" else None)
+                 for k in kinds)
+
+
+def _layer_of(stack, i):
+    """Layer ``i``'s leaves of a weight stack whose leaves are ``[L, ...]``
+    arrays or, an expert bank's, a tuple of the layers' own arrays."""
+    return {k: a[i] for k, a in stack.items()}
+
+
+def _scan_layers(layer, stack, x):
+    """The layer loop of the prefill programs: ``layer(x, lw) -> (x,
+    ys)``, the ``ys`` of every layer stacked ``[L, ...]``. A tuple of
+    bodies is a model whose layers differ in kind, one body a layer: its
+    loop is unrolled (see ``_scan_layers_over_pool``)."""
+    if not isinstance(layer, tuple):
+        return jax.lax.scan(layer, x, stack)
+    ys = []
+    for i, body in enumerate(layer):
+        x, y = body(x, _layer_of(stack, i))
+        ys.append(y)
+    return x, jax.tree.map(lambda *a: jnp.stack(a), *ys)
 
 
 def _scan_layers_over_pool(layer, stack, x, kc, vc, block_ids, row_ids):
@@ -356,9 +411,34 @@ def _scan_layers_over_pool(layer, stack, x, kc, vc, block_ids, row_ids):
     its range, ``block_ids + i*nb`` and ``row_ids + i*nb*bs`` (block
     ``i*nb`` is that layer's trash block). Ids are int32, so the pool's
     rows over all layers, ``L*nb*bs``, stay under 2**31 (196 704 at 6 x
-    2049 x 16). Returns ``(x, kc, vc)``, the pools shaped as they came."""
+    2049 x 16).
+
+    A tuple of bodies is a model whose layers differ in kind
+    (``_by_kind``), one body a layer, and its loop is unrolled: a window
+    layer's shapes differ from a full one's, and the expert banks are
+    the model's own arrays, a tuple of ``L`` that no scan can take as
+    ``xs``. Stacking them for a scan over periods of the pattern would
+    hold nine tenths of the model twice while the engine is built (6.3
+    GB more at the 8 layers the benchmark's cell serves, whose build
+    peaks at 11.2 of the chip's 15.75 GB: my chip run, PR 27), and the
+    plain reference reads the model's copy afterwards. The flat pool is
+    threaded through the layers the same way.
+
+    Returns ``(x, kc, vc)``, the pools shaped as they came, and after
+    them, stacked ``[L, ...]``, whatever a body returns beyond its three
+    (a routed layer's picks)."""
     L, nb, bs = kc.shape[:3]
     assert L * nb * bs < 2 ** 31, "pool rows over all layers overflow int32"
+    flat = (L * nb,) + kc.shape[2:]
+    if isinstance(layer, tuple):
+        k2, v2, more = kc.reshape(flat), vc.reshape(flat), []
+        for i, body in enumerate(layer):
+            x, k2, v2, *rest = body(x, _layer_of(stack, i), k2, v2,
+                                    block_ids + i * nb,
+                                    row_ids + i * (nb * bs))
+            more.append(tuple(rest))
+        return (x, k2.reshape(kc.shape), v2.reshape(vc.shape)) + tuple(
+            jax.tree.map(lambda *a: jnp.stack(a), *more))
 
     def one(cx, lw_i):
         lw, i = lw_i
@@ -366,17 +446,28 @@ def _scan_layers_over_pool(layer, stack, x, kc, vc, block_ids, row_ids):
                            block_ids + i * nb, row_ids + i * (nb * bs))
         return {"x": x2, "kc": k2, "vc": v2}, None
 
-    flat = (L * nb,) + kc.shape[2:]
     cx, _ = jax.lax.scan(
         one, {"x": x, "kc": kc.reshape(flat), "vc": vc.reshape(flat)},
         (stack, jnp.arange(L, dtype=jnp.int32)))
     return cx["x"], cx["kc"].reshape(kc.shape), cx["vc"].reshape(vc.shape)
 
 
+def _count_picks(moe, picks, decode=False):
+    """The routed layers' counters (``serving/metrics.py``) after one
+    program call whose rows made ``picks`` ``[L, E]``."""
+    out = dict(moe, expert_tokens=moe["expert_tokens"] + picks)
+    if decode:
+        out["experts_hit"] = moe["experts_hit"] + jnp.sum(
+            picks > 0, axis=1, dtype=jnp.int32)
+        out["decode_calls"] = moe["decode_calls"] + 1
+    return out
+
+
 def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
-                       temps, vmasks, *, arch, n_heads, n_kv, eps, theta,
-                       do_sample, top_k, top_p, block_size,
-                       flash_decode=False):
+                       temps, vmasks, moe=None, *, arch, n_heads, n_kv, eps,
+                       theta, do_sample, top_k, top_p, block_size,
+                       flash_decode=False, kinds=None, window=None,
+                       moe_k=0):
     """One fused paged decode step: every decode-active slot advances a
     token at its own position, writing K/V through its block table
     (inactive rows scatter into the trash block so a freed slot's stale
@@ -386,7 +477,9 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
     online softmax, no gathered view). ONE program for the life of the
     engine — the block table is a plain runtime operand of static
     shape; the pool is a carry of the layer loop
-    (``_scan_layers_over_pool``), written in place when donated."""
+    (``_scan_layers_over_pool``), written in place when donated.
+    ``kinds`` / ``window`` / ``moe_k`` / ``moe`` as in the prefill
+    program; the routed layers compute and count the active rows alone."""
     from ..text import generation as G
 
     S = tok.shape[0]
@@ -396,13 +489,18 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
                      cur_pos % block_size)
     if arch == "llama":
         xt = jnp.take(w["embed"], tok, axis=0)[:, None]
-        stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
+        stack = G._llama_stack(w)
 
-        def layer(xc, lw, kc_p, vc_p, blocks, rows):
-            return G._llama_decode_layer_paged(
-                xc, lw, kc_p, vc_p, blocks, rows, cur_pos, cur_pos,
-                n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
-                block_size=block_size, flash_decode=flash_decode)
+        def layer_of(win):
+            def layer(xc, lw, kc_p, vc_p, blocks, rows):
+                return G._llama_decode_layer_paged(
+                    xc, lw, kc_p, vc_p, blocks, rows, cur_pos, cur_pos,
+                    n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
+                    block_size=block_size, flash_decode=flash_decode,
+                    window=win, moe_k=moe_k, valid=active)
+            return layer
+
+        layer = _by_kind(layer_of, kinds, window)
     else:
         xt = (jnp.take(w["wte"], tok, axis=0)
               + jnp.take(w["wpe"], cur_pos, axis=0))[:, None]
@@ -414,8 +512,8 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
                 n_heads=n_heads, block_size=block_size,
                 flash_decode=flash_decode)
 
-    xt, kc, vc = _scan_layers_over_pool(layer, stack, xt, kc, vc, tables,
-                                        dest)
+    xt, kc, vc, *picks = _scan_layers_over_pool(layer, stack, xt, kc, vc,
+                                                tables, dest)
     if arch == "llama":
         hidden = G._rms(xt[:, 0], w["norm"], eps)
         logits = hidden @ w["head"]
@@ -434,13 +532,17 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
     nxt = jnp.where(active, nxt, tok)
     new_keys = jnp.where(active[:, None], new_keys, keys)
     cur2 = jnp.where(active, cur_pos + 1, cur_pos)
+    if moe is not None:
+        return nxt, kc, vc, cur2, new_keys, _count_picks(moe, picks[0],
+                                                         decode=True)
     return nxt, kc, vc, cur2, new_keys
 
 
 def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
                       n_prompt, slot, table_row, skip_write, is_final,
-                      seed, skip, temp, vmask, *, arch, n_heads, n_kv, eps,
-                      theta, do_sample, top_k, top_p, block_size):
+                      seed, skip, temp, vmask, moe=None, *, arch, n_heads,
+                      n_kv, eps, theta, do_sample, top_k, top_p, block_size,
+                      kinds=None, window=None, moe_k=0):
     """One block-aligned prefill CHUNK of one slot, co-schedulable with
     the fused decode step: processes ``ids`` ([1, C], global positions
     ``chunk_start + j``) through every layer, scattering its K/V into
@@ -451,7 +553,8 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
     prefill costs exactly ONE extra lowering, independent of prompt
     length. Sampling uses the admission-seeded PRNG chain with the
     supervisor-replay ``skip`` fast-forward, like the one-shot paths.
-    The pool is a carry of the layer loop, as in the decode program."""
+    The pool is a carry of the layer loop, as in the decode program;
+    ``kinds`` / ``window`` / ``moe_k`` / ``moe`` as in the prefill one."""
     from ..text import generation as G
 
     C = ids.shape[1]
@@ -463,13 +566,18 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
                       gpos % block_size)
     if arch == "llama":
         x = jnp.take(w["embed"], ids, axis=0)
-        stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
+        stack = G._llama_stack(w)
 
-        def layer(xc, lw, kc_p, vc_p, blocks, rows):
-            return G._llama_chunk_layer(
-                xc, lw, kc_p, vc_p, blocks, gpos, rows,
-                n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
-                block_size=block_size)
+        def layer_of(win):
+            def layer(xc, lw, kc_p, vc_p, blocks, rows):
+                return G._llama_chunk_layer(
+                    xc, lw, kc_p, vc_p, blocks, gpos, rows,
+                    n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
+                    block_size=block_size, window=win, moe_k=moe_k,
+                    valid=gpos < n_prompt)
+            return layer
+
+        layer = _by_kind(layer_of, kinds, window)
     else:
         x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][gpos][None]
         stack = {k: w[k] for k in G._GPT_STACK_KEYS}
@@ -479,8 +587,8 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
                 xc, lw, kc_p, vc_p, blocks, gpos, rows,
                 n_heads=n_heads, block_size=block_size)
 
-    x, kc, vc = _scan_layers_over_pool(layer, stack, x, kc, vc, table_row,
-                                       wdest)
+    x, kc, vc, *picks = _scan_layers_over_pool(layer, stack, x, kc, vc,
+                                               table_row, wdest)
     li = jnp.clip(n_prompt - 1 - chunk_start, 0, C - 1)
     if arch == "llama":
         hlast = jax.lax.dynamic_index_in_dim(
@@ -509,6 +617,8 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
                         cur_pos.at[slot].set(n_prompt.astype(jnp.int32)),
                         cur_pos)
     keys = jnp.where(fin, keys.at[slot].set(key), keys)
+    if moe is not None:
+        return kc, vc, tok, cur_pos, keys, tok0, _count_picks(moe, picks[0])
     return kc, vc, tok, cur_pos, keys, tok0
 
 
@@ -795,7 +905,11 @@ def _spec_verify_impl(w, kc, vc, keys, ids, start, slot, table_row,
 
 _STATICS = ("arch", "n_heads", "n_kv", "eps", "theta", "do_sample",
             "top_k", "top_p")
+# kinds / window / moe_k: a model whose layers differ in kind and route
+# their feed-forward (``_make_arch``); absent from every other model's
+# statics, so those programs and their cache keys are what they were
 _PAGED_STATICS = _STATICS + ("block_size",)
+_KIND_STATICS = ("kinds", "window", "moe_k")
 _PAGED_DECODE_STATICS = _PAGED_STATICS + ("flash_decode",)
 _TP_STATICS = _PAGED_STATICS + ("tp",)
 
@@ -812,10 +926,11 @@ def _serving_code_token():
 
         from ..aot import keys as _akeys
         from ..distributed import collective_matmul as _cm
+        from ..nn import routed_ffn as _rf
         from ..ops.pallas import flash_decode as _fd
         from ..text import generation as G
         from . import speculative as _spec
-        _CODE_TOKEN = _akeys.code_token(G, _cm, _fd, _spec,
+        _CODE_TOKEN = _akeys.code_token(G, _cm, _fd, _rf, _spec,
                                         sys.modules[__name__])
     return _CODE_TOKEN
 
@@ -870,19 +985,20 @@ _DECODE = jax.jit(_decode_impl, static_argnames=_STATICS)
 _DECODE_DONATED = jax.jit(_decode_impl, static_argnames=_STATICS,
                           donate_argnums=(1, 2))
 _PAGED_PREFILL = jax.jit(_paged_prefill_impl,
-                         static_argnames=_PAGED_STATICS)
-_PAGED_PREFILL_DONATED = jax.jit(_paged_prefill_impl,
-                                 static_argnames=_PAGED_STATICS,
-                                 donate_argnums=(1, 2))
-_PAGED_DECODE = jax.jit(_paged_decode_impl,
-                        static_argnames=_PAGED_DECODE_STATICS)
-_PAGED_DECODE_DONATED = jax.jit(_paged_decode_impl,
-                                static_argnames=_PAGED_DECODE_STATICS,
-                                donate_argnums=(1, 2))
-_PAGED_CHUNK = jax.jit(_paged_chunk_impl, static_argnames=_PAGED_STATICS)
-_PAGED_CHUNK_DONATED = jax.jit(_paged_chunk_impl,
-                               static_argnames=_PAGED_STATICS,
-                               donate_argnums=(1, 2))
+                         static_argnames=_PAGED_STATICS + _KIND_STATICS)
+_PAGED_PREFILL_DONATED = jax.jit(
+    _paged_prefill_impl, static_argnames=_PAGED_STATICS + _KIND_STATICS,
+    donate_argnums=(1, 2))
+_PAGED_DECODE = jax.jit(
+    _paged_decode_impl, static_argnames=_PAGED_DECODE_STATICS + _KIND_STATICS)
+_PAGED_DECODE_DONATED = jax.jit(
+    _paged_decode_impl, static_argnames=_PAGED_DECODE_STATICS + _KIND_STATICS,
+    donate_argnums=(1, 2))
+_PAGED_CHUNK = jax.jit(_paged_chunk_impl,
+                       static_argnames=_PAGED_STATICS + _KIND_STATICS)
+_PAGED_CHUNK_DONATED = jax.jit(
+    _paged_chunk_impl, static_argnames=_PAGED_STATICS + _KIND_STATICS,
+    donate_argnums=(1, 2))
 _SPEC_VERIFY = jax.jit(_spec_verify_impl, static_argnames=_PAGED_STATICS)
 _SPEC_VERIFY_DONATED = jax.jit(_spec_verify_impl,
                                static_argnames=_PAGED_STATICS,
@@ -895,12 +1011,25 @@ def _make_arch(model):
 
     name = type(model).__name__
     c = model.config
-    hd = c.hidden_size // c.num_attention_heads
+    hd = getattr(c, "head_dim", None) \
+        or c.hidden_size // c.num_attention_heads
     if name == "LlamaForCausalLM":
         w = G._stacked_weights(model)
         hp = dict(arch="llama", n_heads=c.num_attention_heads,
                   n_kv=c.num_key_value_heads, eps=c.rms_norm_eps,
                   theta=c.rope_theta)
+        kvh = c.num_key_value_heads
+        dtype = w["embed"].dtype
+    elif name == "MellumForCausalLM":
+        # the llama bodies, told what differs: each layer's kind, the
+        # window of the sliding ones, and the experts a token is routed
+        # to; each layer's rotary table, the router and the expert banks
+        # ride the stacked weights (theta is never read)
+        w = model.stacked_weights()
+        hp = dict(arch="llama", n_heads=c.num_attention_heads,
+                  n_kv=c.num_key_value_heads, eps=c.rms_norm_eps,
+                  theta=0.0, kinds=tuple(c.layer_types),
+                  window=c.sliding_window, moe_k=c.num_experts_per_tok)
         kvh = c.num_key_value_heads
         dtype = w["embed"].dtype
     elif name == "GPTForCausalLM":
@@ -911,8 +1040,8 @@ def _make_arch(model):
         dtype = w["wte"].dtype
     else:
         raise TypeError(
-            f"serving.Engine supports LlamaForCausalLM / GPTForCausalLM, "
-            f"got {name}")
+            f"serving.Engine supports LlamaForCausalLM / GPTForCausalLM / "
+            f"MellumForCausalLM, got {name}")
     geo = dict(n_layers=c.num_hidden_layers, kv_heads=kvh, head_dim=hd,
                dtype=dtype, max_pos=c.max_position_embeddings)
     return w, hp, geo
@@ -937,8 +1066,9 @@ def _model_fingerprint(model, hp, statics, eos_token_id, w):
         cfg_repr = repr(sorted(dataclasses.asdict(cfg).items()))
     except TypeError:
         cfg_repr = repr(cfg)
-    wspec = tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                         for k, v in w.items()))
+    wspec = tuple(sorted(
+        (k, tuple((tuple(a.shape), str(a.dtype))
+                  for a in jax.tree.leaves(v))) for k, v in w.items()))
     parts = (type(model).__name__, cfg_repr,
              tuple(sorted(hp.items())), tuple(sorted(statics.items())),
              eos_token_id, wspec)
@@ -1057,6 +1187,23 @@ class Engine:
                  mesh=None, replica_id=None, flash_decode=False,
                  speculative=None):
         self._w, self._hp, geo = _make_arch(model)
+        if "kinds" in self._hp:
+            # a model of layer kinds with routed experts runs through the
+            # paged single-device gathered programs alone: what else it
+            # is asked for is refused by name, never served another way
+            for asked, missing in (
+                    (int(tp) > 1, "tp > 1: the tensor-parallel bodies have "
+                     "no routed feed-forward and take no window"),
+                    (speculative is not None, "speculative=...: the verify "
+                     "body has no routed feed-forward and takes no window"),
+                    (flash_decode, "flash_decode=True: the flash-decode "
+                     "kernel takes no window"),
+                    (kv_layout == "slot", "kv_layout='slot': the slot "
+                     "bodies take no window and no rotary table")):
+                if asked:
+                    raise ValueError(
+                        f"serving.Engine cannot serve "
+                        f"{type(model).__name__} with {missing}")
         #: fleet identity: stamped onto handles and carried by
         #: RequestTimeout/RequestShed/EngineOverloaded (None standalone)
         self.replica_id = replica_id
@@ -1180,6 +1327,15 @@ class Engine:
         self._condemned = False
         self.metrics = EngineMetrics()
         self.metrics.replica = replica_id
+        if "wr" in self._w:
+            # the routed layers' counters live on the device, threaded
+            # through the programs beside the pool (last argument in,
+            # last value out); only a snapshot fetches them
+            layers, experts = self._w["wr"].shape[0], self._w["wr"].shape[-1]
+            self.metrics.moe = {
+                "expert_tokens": jnp.zeros((layers, experts), jnp.int32),
+                "experts_hit": jnp.zeros((layers,), jnp.int32),
+                "decode_calls": jnp.zeros((), jnp.int32)}
         self._steps = 0           # step() calls so far: the next index
         self._step = None         # index of the step() now running
         # tracer on: spans of the running step's launches, held until
@@ -1407,6 +1563,8 @@ class Engine:
             buckets = self._aot_buckets()
         specs = []
         vrow = jax.ShapeDtypeStruct((self._vocab,), np.float32)
+        moe = () if self.metrics.moe is None else (
+            jax.tree.map(sds, self.metrics.moe),)
         if self.kv_layout == "paged":
             # TP programs bake their statics into the shard_map closure
             stat = {} if self.tp > 1 else self._paged_statics
@@ -1418,12 +1576,12 @@ class Engine:
                 specs.append((
                     "prefill", ("prefill", int(Lb)), self._prefill,
                     (w, kc, vc, tok, cur, keys, ids, i32, i32, u32, i32,
-                     f32, trow, i32, vrow),
+                     f32, trow, i32, vrow) + moe,
                     stat, f"prefill:L{Lb}"))
             specs.append((
                 "decode", ("decode",), self._decode,
                 (w, kc, vc, tables, tok, cur, active, keys, temps,
-                 vmasks),
+                 vmasks) + moe,
                 {} if self.tp > 1 else self._decode_statics, "decode"))
             if self.spec is not None:
                 K1 = self.spec.k + 1
@@ -1440,7 +1598,7 @@ class Engine:
                 specs.append((
                     "chunk", ("chunk",), self._chunk,
                     (w, kc, vc, tok, cur, keys, ids, i32, i32, i32, trow,
-                     i32, i32, u32, i32, f32, vrow),
+                     i32, i32, u32, i32, f32, vrow) + moe,
                     stat, "chunk"))
         else:
             for Lb in buckets:
@@ -1471,6 +1629,19 @@ class Engine:
         return svc.stats()
 
     # -- stamps (module docstring) ----------------------------------------
+
+    def _moe_in(self):
+        """The routed layers' counters as a program's last argument: a
+        one-tuple, or none where the model routes nothing."""
+        return () if self.metrics.moe is None else (self.metrics.moe,)
+
+    def _moe_out(self, out):
+        """Keep the counters a program returned last; the rest of its
+        values, as a model that routes nothing returns them."""
+        if self.metrics.moe is None:
+            return out
+        *out, self.metrics.moe = out
+        return out
 
     def _launched(self, program, called, dispatched, fetched, span=None,
                   h=None, tokens=0, radix_tokens=0, **attrs):
@@ -1752,10 +1923,11 @@ class Engine:
                  np.int32(slot), np.uint32(h.seed), np.int32(k),
                  np.float32(h.temperature),
                  self.cache.block_tables[slot].copy(),
-                 np.int32(n_shared), self._vmask[slot].copy()),
+                 np.int32(n_shared), self._vmask[slot].copy())
+                + self._moe_in(),
                 self._paged_statics, f"prefill:L{Lb}")
         (self.cache.kc, self.cache.vc, self._tok, self._cur,
-         self._keys, tok0) = out
+         self._keys, tok0) = self._moe_out(out)
         dispatched = time.perf_counter()
         tok0 = int(tok0)
         self._launched(f"prefill:L{Lb}", called, dispatched,
@@ -1794,10 +1966,10 @@ class Engine:
                  np.int32(cs.n_shared), np.int32(1 if is_final else 0),
                  np.uint32(h.seed), np.int32(cs.skip),
                  np.float32(h.temperature),
-                 self._vmask[h.slot].copy()), self._paged_statics,
-                "chunk")
+                 self._vmask[h.slot].copy()) + self._moe_in(),
+                self._paged_statics, "chunk")
         (self.cache.kc, self.cache.vc, self._tok, self._cur,
-         self._keys, tok0) = out
+         self._keys, tok0) = self._moe_out(out)
         dispatched = time.perf_counter()
         fetched = None
         if is_final:
@@ -2047,7 +2219,7 @@ class Engine:
                     (self._w, self.cache.kc, self.cache.vc,
                      self.cache.block_tables.copy(), self._tok,
                      self._cur, active, self._keys, self._temps,
-                     self._vmask.copy()),
+                     self._vmask.copy()) + self._moe_in(),
                     self._decode_statics, "decode")
             else:
                 out = self._run_program(
@@ -2056,7 +2228,8 @@ class Engine:
                      self._tok, self._cur, active, self._keys,
                      self._temps, self._vmask.copy()),
                     self._decode_statics, "decode")
-        nxt, self.cache.kc, self.cache.vc, self._cur, self._keys = out
+        nxt, self.cache.kc, self.cache.vc, self._cur, self._keys = \
+            self._moe_out(out)
         self._tok = nxt
         dispatched = time.perf_counter()
         toks = np.asarray(nxt)

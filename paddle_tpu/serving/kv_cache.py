@@ -29,6 +29,7 @@ engine bookkeeping never dispatches device ops between steps.
 from __future__ import annotations
 
 import collections
+import heapq
 
 import numpy as np
 
@@ -186,6 +187,7 @@ class RadixIndex:
         self.n_nodes = 0
         self._clock = 0
         self._touch = {}            # node -> last-use tick (LRU eviction)
+        self._blocks = set()        # the pool blocks the nodes hold
 
     def _chunks(self, tokens):
         bs = self.block_size
@@ -239,6 +241,7 @@ class RadixIndex:
                 child = _RadixNode(parent=node, key=key, block=int(b))
                 node.children[key] = child
                 pool.ref(child.block)
+                self._blocks.add(child.block)
                 self.n_nodes += 1
                 inserted += 1
             self._touch[child] = self._clock
@@ -255,9 +258,13 @@ class RadixIndex:
 
     def evictable_blocks(self, pool):
         """Number of index-held blocks reclaimable right now (leaf
-        chain): blocks only the index references."""
-        return sum(1 for n in self._nodes()
-                   if pool.refcount[n.block] == 1)
+        chain): blocks only the index references. Asked before every
+        admission pass, so it reads the pool's refcounts at the index's
+        blocks in one numpy pass instead of walking the trie."""
+        if not self._blocks:
+            return 0
+        held = np.fromiter(self._blocks, np.int64, len(self._blocks))
+        return int(np.count_nonzero(pool.refcount[held] == 1))
 
     def _nodes(self):
         stack = list(self.root.children.values())
@@ -269,19 +276,30 @@ class RadixIndex:
     def evict(self, pool, need=1):
         """Drop least-recently-matched leaves whose block nobody else
         references until ``need`` blocks are freed (or no progress).
-        Returns the number of blocks actually freed."""
-        freed = 0
-        while freed < need:
-            cand = [n for n in self._leaves()
-                    if pool.refcount[n.block] == 1]
-            if not cand:
-                break
-            victim = min(cand, key=lambda n: self._touch.get(n, 0))
+        Returns the number of blocks actually freed. ONE walk of the
+        trie a call: the evictable leaves go on a heap by last use, and
+        a parent whose last child went joins it (walking every leaf anew
+        for each block made an admission of a 2.5 k-token prompt into a
+        full pool of 8 k blocks take 93 ms on the chip's host, PR 27)."""
+        heap = [(self._touch.get(n, 0), i, n)
+                for i, n in enumerate(self._leaves())
+                if pool.refcount[n.block] == 1]
+        heapq.heapify(heap)
+        order, freed = len(heap), 0
+        while freed < need and heap:
+            _, _, victim = heapq.heappop(heap)
+            parent = victim.parent
             pool.deref(victim.block)
-            del victim.parent.children[victim.key]
+            del parent.children[victim.key]
             self._touch.pop(victim, None)
+            self._blocks.discard(victim.block)
             self.n_nodes -= 1
             freed += 1
+            if parent is not self.root and not parent.children \
+                    and pool.refcount[parent.block] == 1:
+                heapq.heappush(heap, (self._touch.get(parent, 0), order,
+                                      parent))
+                order += 1
         return freed
 
     def clear(self, pool):
@@ -290,6 +308,7 @@ class RadixIndex:
         self.root = _RadixNode()
         self.n_nodes = 0
         self._touch = {}
+        self._blocks = set()
 
 
 class PagedKVCache:
@@ -425,6 +444,10 @@ class PagedKVCache:
         for b in shared:
             self.pool.ref(b)
             blocks.append(b)
+        # what the free list cannot cover is evicted in one call
+        short = need_blocks - len(shared) - self.pool.n_free
+        if short > 0:
+            self.radix.evict(self.pool, need=short)
         ok = True
         for _ in range(need_blocks - len(shared)):
             b = self._alloc_or_evict()

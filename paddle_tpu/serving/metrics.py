@@ -176,6 +176,10 @@ class EngineMetrics:
         # engines) — surfaces underscoring at a glance in the profiler
         # serving line and the snapshot
         self.tp = 1
+        # routed feed-forward (None where the model routes nothing): the
+        # counters the programs keep on the device and hand on from call
+        # to call, set by the engine; moe_counters() fetches them
+        self.moe = None
         self.kv_pool_bytes_per_device = None
         self.collectives_per_decode_step = None
         # decode-step wall times, histogram-backed: the ~64-observation
@@ -270,6 +274,22 @@ class EngineMetrics:
         None before the first decode step."""
         return self.itl_hist.percentile(95)
 
+    def moe_counters(self):
+        """The routed layers' load, fetched from the device now (the
+        only fetch there is of it: no step makes one). ``expert_tokens``
+        ``[layer][expert]``: the picks an expert has computed, over every
+        prefill, chunk and decode call, padding and idle slots left out;
+        ``experts_hit`` ``[layer]``: the distinct experts a decode call
+        touched, summed over ``decode_calls`` calls. None where the
+        model routes nothing."""
+        if self.moe is None:
+            return None
+        import numpy as np
+        return {"expert_tokens": np.asarray(
+                    self.moe["expert_tokens"]).tolist(),
+                "experts_hit": np.asarray(self.moe["experts_hit"]).tolist(),
+                "decode_calls": int(self.moe["decode_calls"])}
+
     def snapshot(self):
         n = max(self.samples, 1)
         itl = self.itl_estimate()
@@ -323,6 +343,7 @@ class EngineMetrics:
             "kv_pool_bytes_per_device": self.kv_pool_bytes_per_device,
             "collectives_per_decode_step":
                 self.collectives_per_decode_step,
+            **({} if self.moe is None else {"moe": self.moe_counters()}),
         }
 
 

@@ -75,6 +75,178 @@ def _rope_rows(q, k, pos, theta, dtype):
     return rot(q), rot(k)
 
 
+def _rotate(q, k, pos, lw, theta, dt, per_row=False):
+    """Rotary embedding of q and k. ``pos`` is ``[L]`` for ``[B, L, H, D]``
+    rows of one sequence, or with ``per_row`` ``[B]`` for one token a row.
+    A layer that carries its own table (``lw["rope_inv"]`` ``[D/2]``, the
+    angle a position, and ``lw["rope_scale"]``, the factor on cos and sin:
+    a model whose layer kinds differ in their tables) is rotated by it;
+    any other by ``theta``'s plain table."""
+    if "rope_inv" not in lw:
+        return (_rope_rows if per_row else _rope)(q, k, pos, theta, dt)
+    freqs = pos[:, None].astype(jnp.float32) * lw["rope_inv"][None, :]
+    cos = jnp.cos(freqs) * lw["rope_scale"]
+    sin = jnp.sin(freqs) * lw["rope_scale"]
+    if per_row:
+        cos, sin = cos[:, None, None, :], sin[:, None, None, :]
+    else:
+        cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+
+    def rot(x):
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                               axis=-1).astype(dt)
+
+    return rot(q), rot(k)
+
+
+#: Keys beyond which :func:`_attend` walks the view in tiles of this many
+#: with an online softmax. One pass over float32 scores ``[4, 8, 512,
+#: 8192]`` (a 512 chunk against an 8192-line view) compiles to a softmax
+#: fusion the chip's compiler has no schedule for: 46.9 ms for 537 MB (my
+#: chip run, PR 27; its cost model gives no estimate either), where the
+#: same fusion over 1552 keys takes 0.155 ms and up to 4096 keys is
+#: estimated in proportion. Views up to 4096 keys stay on the one pass.
+_ATTEND_TILE = 2048
+
+
+def _attend(qh, kh, vh, allowed, dt):
+    """Masked softmax attention of ``qh`` ``[B, H, Q, hd]`` over ``kh``,
+    ``vh`` ``[B, n_kv, T, hd]`` under ``allowed`` (bool, broadcast to
+    ``[B, H, Q, T]``) -> ``[B, H, Q, hd]``. Each KV head serves ``H /
+    n_kv`` query heads, and the group contracts against its one head: no
+    copy of K or V per query head is built. A view of more than two
+    ``_ATTEND_TILE`` keys is walked tile by tile (:func:`_attend_tiled`)."""
+    B, H, Q, hd = qh.shape
+    n_kv, T = kh.shape[1], kh.shape[2]
+    if T > 2 * _ATTEND_TILE:
+        return _attend_tiled(qh, kh, vh, allowed, dt)
+    scale = jnp.sqrt(jnp.float32(hd))
+    if n_kv == H:
+        s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
+                       preferred_element_type=jnp.float32) / scale
+        p = jax.nn.softmax(jnp.where(allowed, s, -1e30), axis=-1).astype(dt)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, vh)
+    g = H // n_kv
+    s = jnp.einsum("bngqd,bnkd->bngqk", qh.reshape(B, n_kv, g, Q, hd), kh,
+                   preferred_element_type=jnp.float32).reshape(
+                       B, H, Q, -1) / scale
+    p = jax.nn.softmax(jnp.where(allowed, s, -1e30), axis=-1).astype(dt)
+    return jnp.einsum("bngqk,bnkd->bngqd", p.reshape(B, n_kv, g, Q, -1),
+                      vh).reshape(B, H, Q, hd)
+
+
+def _attend_tiled(qh, kh, vh, allowed, dt):
+    """:func:`_attend` over ``T / _ATTEND_TILE`` tiles of keys, one after
+    the other, carrying each row's running maximum, its sum of exponentials
+    and its weighted values in float32 (the online softmax): the same sum,
+    with scores never wider than a tile."""
+    B, H, Q, hd = qh.shape
+    n_kv, T = kh.shape[1], kh.shape[2]
+    ok = jnp.broadcast_to(allowed, allowed.shape[:-2] + (Q, T))
+    pad = -T % _ATTEND_TILE
+    if pad:                  # whole tiles: keys that no row may see
+        kh, vh = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                  for a in (kh, vh))
+        ok = jnp.pad(ok, [(0, 0)] * (ok.ndim - 1) + [(0, pad)])
+        T += pad
+    g, nt = H // n_kv, T // _ATTEND_TILE
+    scale = jnp.sqrt(jnp.float32(hd))
+    qg = qh.reshape(B, n_kv, g, Q, hd)
+    ok = jnp.moveaxis(ok.reshape(ok.shape[:-1] + (nt, _ATTEND_TILE)), -2, 0)
+    if ok.ndim == 5:                      # [nt, B|1, H|1, Q, tile]
+        ok = ok[:, :, :, None]            # over the group, beside n_kv
+
+    def tiles(a):                         # [B, n, T, hd] -> [nt, B, n, t, hd]
+        return jnp.moveaxis(a.reshape(B, n_kv, nt, _ATTEND_TILE, hd), 2, 0)
+
+    def one(carry, tile):
+        top, total, acc = carry
+        k_t, v_t, ok_t = tile
+        s = jnp.einsum("bngqd,bnkd->bngqk", qg, k_t,
+                       preferred_element_type=jnp.float32) / scale
+        s = jnp.where(ok_t, s, -1e30)
+        top2 = jnp.maximum(top, jnp.max(s, axis=-1))
+        # a key that may not be seen weighs nothing, also in a tile that
+        # holds no other (the one pass gives such a row equal weights)
+        p = jnp.where(ok_t, jnp.exp(s - top2[..., None]), 0.0)
+        shrink = jnp.exp(top - top2)
+        acc = acc * shrink[..., None] + jnp.einsum(
+            "bngqk,bnkd->bngqd", p.astype(dt), v_t,
+            preferred_element_type=jnp.float32)
+        return (top2, total * shrink + jnp.sum(p, axis=-1), acc), None
+
+    start = (jnp.full((B, n_kv, g, Q), -1e30, jnp.float32),
+             jnp.zeros((B, n_kv, g, Q), jnp.float32),
+             jnp.zeros((B, n_kv, g, Q, hd), jnp.float32))
+    (_, total, acc), _ = jax.lax.scan(one, start,
+                                      (tiles(kh), tiles(vh), ok))
+    return (acc / jnp.maximum(total, 1e-30)[..., None]).astype(dt).reshape(
+        B, H, Q, hd)
+
+
+def _attend_rows(q, kview, vview, valid, dt):
+    """One query token a row: ``q`` ``[S, H, hd]`` over the row's view
+    ``kview``, ``vview`` ``[S, T, n_kv, hd]`` under ``valid`` ``[S, T]``
+    -> ``[S, H, hd]``; grouped as :func:`_attend`."""
+    S, H, hd = q.shape
+    n_kv = kview.shape[2]
+    scale = jnp.sqrt(jnp.float32(hd))
+    if n_kv == H:
+        s = jnp.einsum("bhd,bthd->bht", q, kview,
+                       preferred_element_type=jnp.float32) / scale
+        s = jnp.where(valid[:, None, :], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1).astype(dt)
+        return jnp.einsum("bht,bthd->bhd", p, vview)
+    g = H // n_kv
+    s = jnp.einsum("bngd,btnd->bngt", q.reshape(S, n_kv, g, hd), kview,
+                   preferred_element_type=jnp.float32).reshape(
+                       S, H, -1) / scale
+    s = jnp.where(valid[:, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(dt)
+    return jnp.einsum("bngt,btnd->bngd", p.reshape(S, n_kv, g, -1),
+                      vview).reshape(S, H, hd)
+
+
+def _window_blocks(window, rows, block_size):
+    """How many blocks can hold a key that ``rows`` consecutive query
+    positions may see through a window of ``window`` (each itself and the
+    ``window - 1`` before it): the span of ``window - 1 + rows``
+    positions, wherever it starts in its first block."""
+    return (window + rows - 3) // block_size + 2
+
+
+def _window_tables(tables, first_pos, rows, window, block_size):
+    """Block tables ``[S, mb]`` cut to the blocks that can hold a key
+    which ``rows`` consecutive query positions from ``first_pos`` ``[S]``
+    on may see through ``window``: ``(tables [S, n], first line [S, 1])``,
+    the view's first line being the first such block's. Where that is no
+    fewer blocks than the whole table: the tables as they are, and 0."""
+    n = _window_blocks(window, rows, block_size)
+    if n >= tables.shape[1]:
+        return tables, 0
+    first = jnp.maximum(first_pos - (window - 1), 0) // block_size
+    cols = jnp.minimum(first[:, None] + jnp.arange(n)[None, :],
+                       tables.shape[1] - 1)
+    return jnp.take_along_axis(tables, cols, axis=1), \
+        (first * block_size)[:, None]
+
+
+def _feed_forward(h2, lw, moe_k, valid):
+    """The layer's second half on normed rows ``h2``: the dense SwiGLU of
+    ``wg``/``wu``/``wd``, or, where the layer carries a router ``wr``
+    and expert banks, the routed one (``nn/routed_ffn.py``: every pick
+    computed, none dropped; ``valid`` marks the rows that are tokens).
+    Returns ``(y, picks)``, ``picks`` ``[E]`` rows an expert or None."""
+    if "wr" not in lw:
+        return (jax.nn.silu(h2 @ lw["wg"]) * (h2 @ lw["wu"])) @ lw["wd"], \
+            None
+    from ..nn.routed_ffn import routed_ffn
+    y, picks = routed_ffn(h2.reshape(-1, h2.shape[-1]), lw["wr"], lw["wg"],
+                          lw["wu"], lw["wd"], moe_k, valid)
+    return y.reshape(h2.shape), picks
+
+
 def _nucleus_filter(logits, top_p):
     """Top-p (nucleus) mask: keep exactly the smallest set of tokens
     whose cumulative probability reaches top_p (ties broken by sort
@@ -129,34 +301,44 @@ def _prompt_mask(ids, pad_token_id, attention_mask):
 # ---------------------------------------------------------------------------
 
 _LLAMA_STACK_KEYS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd", "ln1", "ln2")
+# what a layer may carry besides: a router over its (then batched) expert
+# banks, and a rotary table of its own
+_LLAMA_KIND_KEYS = ("wr", "rope_inv", "rope_scale")
 
 
-def _llama_prefill_layer(x, lw, pos, *, n_heads, n_kv, eps, theta):
+def _llama_stack(w):
+    """The per-layer leaves ``[L, ...]`` of a stacked weight tree."""
+    return {k: w[k] for k in _LLAMA_STACK_KEYS + _LLAMA_KIND_KEYS if k in w}
+
+
+def _llama_prefill_layer(x, lw, pos, *, n_heads, n_kv, eps, theta,
+                         window=None, moe_k=0, valid=None):
     """One Llama decoder layer over a full [B, L] prompt (causal).
-    Returns (x, (k, v)) with k/v [B, L, n_kv, hd] for the KV cache."""
+    Returns (x, (k, v)) with k/v [B, L, n_kv, hd] for the KV cache.
+
+    The head size is the projection's width over the heads (a config may
+    state one that is not ``h // n_heads``). ``window``: a row sees itself
+    and the ``window - 1`` before it. A layer with a rotary table of its
+    own is rotated by it (:func:`_rotate`), one with a router runs the
+    routed feed-forward over ``moe_k`` experts a row
+    (:func:`_feed_forward`) and returns ``(x, (k, v, picks))``."""
     B, L, h = x.shape
-    hd = h // n_heads
+    hd = lw["wq"].shape[-1] // n_heads
     dt = x.dtype
     h1 = _rms(x, lw["ln1"], eps)
     q = (h1 @ lw["wq"]).reshape(B, L, n_heads, hd)
     k = (h1 @ lw["wk"]).reshape(B, L, n_kv, hd)
     v = (h1 @ lw["wv"]).reshape(B, L, n_kv, hd)
-    q, k = _rope(q, k, pos, theta, dt)
-    qh = jnp.swapaxes(q, 1, 2)
-    kh = jnp.repeat(jnp.swapaxes(k, 1, 2), n_heads // n_kv, axis=1)
-    vh = jnp.repeat(jnp.swapaxes(v, 1, 2), n_heads // n_kv, axis=1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
-                   preferred_element_type=jnp.float32) / jnp.sqrt(
-                       jnp.float32(hd))
+    q, k = _rotate(q, k, pos, lw, theta, dt)
     cm = jnp.tril(jnp.ones((L, L), bool))
-    s = jnp.where(cm, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1).astype(dt)
-    o = jnp.einsum("bhqk,bhkd->bhqd", p, vh)
-    o = jnp.swapaxes(o, 1, 2).reshape(B, L, h)
+    if window is not None:
+        cm = cm & ~jnp.tril(jnp.ones((L, L), bool), -window)
+    o = _attend(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                jnp.swapaxes(v, 1, 2), cm, dt)
+    o = jnp.swapaxes(o, 1, 2).reshape(B, L, n_heads * hd)
     x = x + o @ lw["wo"]
-    h2 = _rms(x, lw["ln2"], eps)
-    x = x + (jax.nn.silu(h2 @ lw["wg"]) * (h2 @ lw["wu"])) @ lw["wd"]
-    return x, (k, v)
+    y, picks = _feed_forward(_rms(x, lw["ln2"], eps), lw, moe_k, valid)
+    return x + y, ((k, v) if picks is None else (k, v, picks))
 
 
 def _llama_decode_layer(xt, lw, kc_l, vc_l, write_idx, rope_pos, key_mask,
@@ -171,8 +353,7 @@ def _llama_decode_layer(xt, lw, kc_l, vc_l, write_idx, rope_pos, key_mask,
     prompt padding lines).
     """
     B, T = kc_l.shape[0], kc_l.shape[1]
-    h = xt.shape[-1]
-    hd = h // n_heads
+    hd = lw["wq"].shape[-1] // n_heads
     dt = xt.dtype
     h1 = _rms(xt, lw["ln1"], eps)
     q = (h1 @ lw["wq"]).reshape(B, 1, n_heads, hd)
@@ -182,17 +363,11 @@ def _llama_decode_layer(xt, lw, kc_l, vc_l, write_idx, rope_pos, key_mask,
     rows = jnp.arange(B)
     kc_l = kc_l.at[rows, write_idx].set(k[:, 0])
     vc_l = vc_l.at[rows, write_idx].set(v[:, 0])
-    kh = jnp.repeat(kc_l, n_heads // n_kv, axis=2)       # [B, T, H, hd]
-    vh = jnp.repeat(vc_l, n_heads // n_kv, axis=2)
-    s = jnp.einsum("bhd,bthd->bht", q[:, 0], kh,
-                   preferred_element_type=jnp.float32) / jnp.sqrt(
-                       jnp.float32(hd))
     valid = jnp.arange(T)[None, :] <= write_idx[:, None]
     if key_mask is not None:
         valid = jnp.logical_and(valid, key_mask)
-    s = jnp.where(valid[:, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1).astype(dt)
-    o = jnp.einsum("bht,bthd->bhd", p, vh).reshape(B, 1, h)
+    o = _attend_rows(q[:, 0], kc_l, vc_l, valid, dt).reshape(
+        B, 1, n_heads * hd)
     xt2 = xt + o @ lw["wo"]
     h2 = _rms(xt2, lw["ln2"], eps)
     xt2 = xt2 + (jax.nn.silu(h2 @ lw["wg"]) * (h2 @ lw["wu"])) @ lw["wd"]
@@ -212,7 +387,7 @@ def _generate_jit(w, input_ids, prompt_len_mask, key, *, n_heads, n_kv, eps,
     [B, L0 + max_new]."""
     B, L0 = input_ids.shape
     h = w["embed"].shape[1]
-    hd = h // n_heads
+    hd = w["wq"].shape[-1] // n_heads
     T = L0 + max_new
     nL = w["wq"].shape[0]
     dt = w["embed"].dtype
@@ -306,61 +481,83 @@ def _paged_view(pool_l, tables, block_size):
     """Gather contiguous per-slot K or V views through block tables:
     pool_l [n_blocks, bs, kv, hd], tables [S, mb] -> [S, mb*bs, kv, hd]
     (view index == logical position; unused table entries point at the
-    trash block and sit beyond the causal bound)."""
-    v = pool_l[tables]                       # [S, mb, bs, kv, hd]
+    trash block and sit beyond the causal bound).
+
+    Two forms, chosen by the pool's KV heads, each measured where the
+    other is worse. By whole blocks: at 32 heads a decode step of 16
+    slots x 2048 lines spends 25.99 ms on the device this way and 31.06
+    gathered by lines (448 against 393 tokens/s end to end; my chip run,
+    PR 27). By lines of the flat ``[n_blocks*bs, kv, hd]`` form, the one
+    the scatters write, under 8 heads: there a line fills under a tile,
+    the chip's compiler wants a pool gathered by blocks in another
+    layout and first copies all of it (537 MB a gather at 4 heads x 4
+    layers x 8193 blocks, compiled for a described v5e:
+    ``tests/test_tpu_compile.py``), while by lines the 16 x 8192 view
+    takes 1.56 ms."""
     S, mb = tables.shape
-    return v.reshape(S, mb * block_size, pool_l.shape[-2],
-                     pool_l.shape[-1])
+    nb, bs, kv, hd = pool_l.shape
+    if kv < 8:
+        rows = (tables[:, :, None] * bs + jnp.arange(bs)).reshape(S, mb * bs)
+        return pool_l.reshape(nb * bs, kv, hd)[rows]
+    return pool_l[tables].reshape(S, mb * bs, kv, hd)
 
 
 def _paged_decode_attention(q, kc_pool, vc_pool, tables, write_pos,
-                            block_size, flash, dt):
+                            block_size, flash, dt, window=None):
     """One-token paged attention: q [S, H, hd] over the pool through
     block tables. ``flash=True`` runs the tuner-registered pallas
     flash-decode kernel (block DMA straight off the table rows + online
     softmax — no [S, T] gather materializes; interpret mode on CPU);
     False keeps the gathered XLA form. Both share the causal contract
     ``view position <= write_pos``; the flash output is token-identical,
-    not bitwise (online-softmax reduction order)."""
+    not bitwise (online-softmax reduction order).
+
+    ``window`` (gathered form only): a row sees its own position and the
+    ``window - 1`` before it, and only the blocks that can hold one of
+    those are gathered (``_window_blocks``: 65 of 512 at a window of
+    1024, blocks of 16 and 8192 lines), from the row's first such block
+    on; where that is no fewer than the whole table, the whole view is
+    masked instead."""
     S, H, hd = q.shape
-    n_kv = kc_pool.shape[2]
     if flash:
         from ..ops.pallas.flash_decode import flash_decode
         return flash_decode(
             q, kc_pool, vc_pool, tables, write_pos,
             interpret=jax.default_backend() == "cpu").astype(dt)
+    first = 0
+    if window is not None:
+        tables, first = _window_tables(tables, write_pos, 1, window,
+                                       block_size)
     kview = _paged_view(kc_pool, tables, block_size)   # [S, T, n_kv, hd]
     vview = _paged_view(vc_pool, tables, block_size)
-    kh = jnp.repeat(kview, H // n_kv, axis=2)
-    vh = jnp.repeat(vview, H // n_kv, axis=2)
-    s = jnp.einsum("bhd,bthd->bht", q, kh,
-                   preferred_element_type=jnp.float32) / jnp.sqrt(
-                       jnp.float32(hd))
-    T = kview.shape[1]
-    valid = jnp.arange(T)[None, :] <= write_pos[:, None]
-    s = jnp.where(valid[:, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1).astype(dt)
-    return jnp.einsum("bht,bthd->bhd", p, vh)
+    kpos = first + jnp.arange(kview.shape[1])[None, :]
+    valid = kpos <= write_pos[:, None]
+    if window is not None:
+        valid = valid & (write_pos[:, None] - kpos < window)
+    return _attend_rows(q, kview, vview, valid, dt)
 
 
 def _llama_decode_layer_paged(xt, lw, kc_pool, vc_pool, tables, dest,
                               write_pos, rope_pos, *, n_heads, n_kv, eps,
-                              theta, block_size, flash_decode=False):
+                              theta, block_size, flash_decode=False,
+                              window=None, moe_k=0, valid=None):
     """One Llama decoder layer advancing every slot one token against
     the paged pool: the new K/V scatters to flat pool index ``dest``
     (trash-redirected for inactive rows), then attention gathers each
     slot's view through its block-table row. kc_pool/vc_pool
     [n_blocks, bs, n_kv, hd] (one layer); tables [S, mb]; dest [S];
-    write_pos/rope_pos [S]."""
+    write_pos/rope_pos [S]. ``window``, the layer's own rotary table and
+    the routed feed-forward as in :func:`_llama_prefill_layer`; ``valid``
+    [S] marks the rows that decode (the routed layer computes and counts
+    no other), and a routed layer returns ``picks`` as a fourth value."""
     S = xt.shape[0]
-    h = xt.shape[-1]
-    hd = h // n_heads
+    hd = lw["wq"].shape[-1] // n_heads
     dt = xt.dtype
     h1 = _rms(xt, lw["ln1"], eps)
     q = (h1 @ lw["wq"]).reshape(S, 1, n_heads, hd)
     k = (h1 @ lw["wk"]).reshape(S, 1, n_kv, hd)
     v = (h1 @ lw["wv"]).reshape(S, 1, n_kv, hd)
-    q, k = _rope_rows(q, k, rope_pos, theta, dt)
+    q, k = _rotate(q, k, rope_pos, lw, theta, dt, per_row=True)
     nb, bs = kc_pool.shape[0], kc_pool.shape[1]
     kc_pool = kc_pool.reshape(nb * bs, n_kv, hd).at[dest].set(
         k[:, 0]).reshape(nb, bs, n_kv, hd)
@@ -368,11 +565,12 @@ def _llama_decode_layer_paged(xt, lw, kc_pool, vc_pool, tables, dest,
         v[:, 0]).reshape(nb, bs, n_kv, hd)
     o = _paged_decode_attention(q[:, 0], kc_pool, vc_pool, tables,
                                 write_pos, block_size, flash_decode,
-                                dt).reshape(S, 1, h)
+                                dt, window).reshape(S, 1, n_heads * hd)
     xt2 = xt + o @ lw["wo"]
-    h2 = _rms(xt2, lw["ln2"], eps)
-    xt2 = xt2 + (jax.nn.silu(h2 @ lw["wg"]) * (h2 @ lw["wu"])) @ lw["wd"]
-    return xt2, kc_pool, vc_pool
+    y, picks = _feed_forward(_rms(xt2, lw["ln2"], eps), lw, moe_k, valid)
+    xt2 = xt2 + y
+    return (xt2, kc_pool, vc_pool) if picks is None \
+        else (xt2, kc_pool, vc_pool, picks)
 
 
 def _gpt_decode_layer_paged(xt, lw, kc_pool, vc_pool, tables, dest,
@@ -406,44 +604,48 @@ def _gpt_decode_layer_paged(xt, lw, kc_pool, vc_pool, tables, dest,
 
 
 def _llama_chunk_layer(x, lw, kc_pool, vc_pool, table_row, gpos, wdest, *,
-                       n_heads, n_kv, eps, theta, block_size):
+                       n_heads, n_kv, eps, theta, block_size, window=None,
+                       moe_k=0, valid=None):
     """One Llama layer over one block-aligned prefill CHUNK of a single
     slot: x [1, C, h] at global positions ``gpos`` [C]; the chunk's K/V
     scatter to flat pool indices ``wdest`` [C] (shared-prefix / pad
     positions trash-redirected), then the chunk rows attend to the
     slot's full gathered view (earlier chunks + this one) under the
-    causal bound ``view_pos <= gpos``."""
+    causal bound ``view_pos <= gpos``. Under a ``window`` the view is
+    the blocks that can hold a key one of the chunk's rows may see (97
+    of 512 at a window of 1024 and a chunk of 512), from the first such
+    block on. Rotary table, routed feed-forward, ``valid`` [C] and the
+    fourth return value as in :func:`_llama_decode_layer_paged`."""
     B, C, h = x.shape
-    hd = h // n_heads
+    hd = lw["wq"].shape[-1] // n_heads
     dt = x.dtype
     h1 = _rms(x, lw["ln1"], eps)
     q = (h1 @ lw["wq"]).reshape(B, C, n_heads, hd)
     k = (h1 @ lw["wk"]).reshape(B, C, n_kv, hd)
     v = (h1 @ lw["wv"]).reshape(B, C, n_kv, hd)
-    q, k = _rope(q, k, gpos, theta, dt)
+    q, k = _rotate(q, k, gpos, lw, theta, dt)
     nb, bs = kc_pool.shape[0], kc_pool.shape[1]
     kc_pool = kc_pool.reshape(nb * bs, n_kv, hd).at[wdest].set(
         k[0]).reshape(nb, bs, n_kv, hd)
     vc_pool = vc_pool.reshape(nb * bs, n_kv, hd).at[wdest].set(
         v[0]).reshape(nb, bs, n_kv, hd)
-    kview = _paged_view(kc_pool, table_row[None], block_size)  # [1,T,kv,hd]
-    vview = _paged_view(vc_pool, table_row[None], block_size)
-    qh = jnp.swapaxes(q, 1, 2)                                 # [1,H,C,hd]
-    kh = jnp.repeat(jnp.swapaxes(kview, 1, 2), n_heads // n_kv, axis=1)
-    vh = jnp.repeat(jnp.swapaxes(vview, 1, 2), n_heads // n_kv, axis=1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
-                   preferred_element_type=jnp.float32) / jnp.sqrt(
-                       jnp.float32(hd))
-    T = kview.shape[1]
-    cm = jnp.arange(T)[None, :] <= gpos[:, None]               # [C, T]
-    s = jnp.where(cm[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1).astype(dt)
-    o = jnp.einsum("bhqk,bhkd->bhqd", p, vh)
-    o = jnp.swapaxes(o, 1, 2).reshape(B, C, h)
+    table, first = table_row[None], 0
+    if window is not None:
+        table, first = _window_tables(table, gpos[:1], C, window, block_size)
+    kview = _paged_view(kc_pool, table, block_size)            # [1,T,kv,hd]
+    vview = _paged_view(vc_pool, table, block_size)
+    kpos = (first + jnp.arange(kview.shape[1])[None, :])[0]
+    cm = kpos[None, :] <= gpos[:, None]                        # [C, T]
+    if window is not None:
+        cm = cm & (gpos[:, None] - kpos[None, :] < window)
+    o = _attend(jnp.swapaxes(q, 1, 2), jnp.swapaxes(kview, 1, 2),
+                jnp.swapaxes(vview, 1, 2), cm[None, None], dt)
+    o = jnp.swapaxes(o, 1, 2).reshape(B, C, n_heads * hd)
     x = x + o @ lw["wo"]
-    h2 = _rms(x, lw["ln2"], eps)
-    x = x + (jax.nn.silu(h2 @ lw["wg"]) * (h2 @ lw["wu"])) @ lw["wd"]
-    return x, kc_pool, vc_pool
+    y, picks = _feed_forward(_rms(x, lw["ln2"], eps), lw, moe_k, valid)
+    x = x + y
+    return (x, kc_pool, vc_pool) if picks is None \
+        else (x, kc_pool, vc_pool, picks)
 
 
 def _gpt_chunk_layer(x, lw, kc_pool, vc_pool, table_row, gpos, wdest, *,
@@ -573,8 +775,8 @@ def _llama_prefill_layer_tp(x, lw, pos, *, n_heads, n_kv, eps, theta, tp):
     Returns (x_replicated, (k_local, v_local)) — k/v carry this device's
     ``n_kv // tp`` head shard for the sharded KV pool."""
     B, L, h = x.shape
-    hd = h // n_heads
     hl, kvl = n_heads // tp, n_kv // tp
+    hd = lw["wq"].shape[-1] // hl
     dt = x.dtype
     h1 = _rms(x, lw["ln1"], eps)
     q = (h1 @ lw["wq"]).reshape(B, L, hl, hd)
@@ -591,7 +793,7 @@ def _llama_prefill_layer_tp(x, lw, pos, *, n_heads, n_kv, eps, theta, tp):
     s = jnp.where(cm, s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(dt)
     o = jnp.einsum("bhqk,bhkd->bhqd", p, vh)
-    o = jnp.swapaxes(o, 1, 2).reshape(B, L, h // tp)
+    o = jnp.swapaxes(o, 1, 2).reshape(B, L, hl * hd)
     x = x + ring_rowparallel_matmul(o, lw["wo"], _TP_AXIS, tp)
     h2 = _rms(x, lw["ln2"], eps)
     act = jax.nn.silu(h2 @ lw["wg"]) * (h2 @ lw["wu"])
@@ -608,8 +810,8 @@ def _llama_decode_layer_paged_tp(xt, lw, kc_pool, vc_pool, tables, dest,
     (the activations they produce are replicated for the next layer)."""
     S = xt.shape[0]
     h = xt.shape[-1]
-    hd = h // n_heads
     hl, kvl = n_heads // tp, n_kv // tp
+    hd = lw["wq"].shape[-1] // hl
     dt = xt.dtype
     h1 = _rms(xt, lw["ln1"], eps)
     q = (h1 @ lw["wq"]).reshape(S, 1, hl, hd)
@@ -632,7 +834,7 @@ def _llama_decode_layer_paged_tp(xt, lw, kc_pool, vc_pool, tables, dest,
     valid = jnp.arange(T)[None, :] <= write_pos[:, None]
     s = jnp.where(valid[:, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(dt)
-    o = jnp.einsum("bht,bthd->bhd", p, vh).reshape(S, 1, h // tp)
+    o = jnp.einsum("bht,bthd->bhd", p, vh).reshape(S, 1, hl * hd)
     xt2 = xt + ring_rowparallel_matmul(o, lw["wo"], _TP_AXIS, tp)
     h2 = _rms(xt2, lw["ln2"], eps)
     act = jax.nn.silu(h2 @ lw["wg"]) * (h2 @ lw["wu"])
@@ -645,8 +847,8 @@ def _llama_chunk_layer_tp(x, lw, kc_pool, vc_pool, table_row, gpos, wdest,
     """TP variant of :func:`_llama_chunk_layer` (one prefill chunk of
     one slot against the sharded pool)."""
     B, C, h = x.shape
-    hd = h // n_heads
     hl, kvl = n_heads // tp, n_kv // tp
+    hd = lw["wq"].shape[-1] // hl
     dt = x.dtype
     h1 = _rms(x, lw["ln1"], eps)
     q = (h1 @ lw["wq"]).reshape(B, C, hl, hd)
@@ -671,7 +873,7 @@ def _llama_chunk_layer_tp(x, lw, kc_pool, vc_pool, table_row, gpos, wdest,
     s = jnp.where(cm[None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(dt)
     o = jnp.einsum("bhqk,bhkd->bhqd", p, vh)
-    o = jnp.swapaxes(o, 1, 2).reshape(B, C, h // tp)
+    o = jnp.swapaxes(o, 1, 2).reshape(B, C, hl * hd)
     x = x + ring_rowparallel_matmul(o, lw["wo"], _TP_AXIS, tp)
     h2 = _rms(x, lw["ln2"], eps)
     act = jax.nn.silu(h2 @ lw["wg"]) * (h2 @ lw["wu"])

@@ -11,6 +11,9 @@ from .llama import (  # noqa: F401
     LlamaModel,
 )
 from .llama_pipe import LlamaForCausalLMPipe  # noqa: F401
+from .mellum import (  # noqa: F401
+    MELLUM_TINY, MellumConfig, MellumForCausalLM,
+)
 from .t5 import (  # noqa: F401
     T5_TINY, T5Config, T5ForConditionalGeneration, T5Model,
 )

@@ -295,3 +295,102 @@ def test_paged_program_keeps_the_pool_in_place(kind, one_chip,
         if n in (pool_elements, pool_elements // _LAYERS):
             moved.append((op, shape))
     assert not moved
+
+
+# the routed serving cell's engine: 64 experts of 2304 x 896, 32 query and
+# 4 KV heads of 128 under a hidden size of 2304, a 98304-row head, depth 8
+# (three window layers of 1024 to one full layer, twice), 16 slots x 8192,
+# block 16, chunk 512
+_R_LAYERS, _R_HIDDEN, _R_KV, _R_EXPERTS, _R_FF, _R_VOCAB = 8, 2304, 4, 64, \
+    896, 98304
+_R_BLOCKS, _R_TABLE, _R_CHUNK, _R_WINDOW = 8193, 512, 512, 1024
+_R_POOL = (_R_LAYERS, _R_BLOCKS, _BS, _R_KV, _HD)
+
+
+def _routed_program(kind):
+    """A paged program of the engine for a model of layer kinds with
+    routed experts, as the engine jits it: the shapes of its arguments
+    (the expert banks a tuple of the layers' own arrays, the counters
+    last) and its statics."""
+    from paddle_tpu.serving import engine as E
+
+    L, S, V, h = _R_LAYERS, _ENGINE_SLOTS, _R_VOCAB, _R_HIDDEN
+    bf, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+    w = {"wq": (L, h, _H * _HD), "wk": (L, h, _R_KV * _HD),
+         "wv": (L, h, _R_KV * _HD), "wo": (L, _H * _HD, h),
+         "wr": (L, h, _R_EXPERTS), "ln1": (L, h), "ln2": (L, h),
+         "embed": (V, h), "norm": (h,), "head": (h, V)}
+    w = {k: (s, bf) for k, s in w.items()}
+    w.update(rope_inv=((L, _HD // 2), f32), rope_scale=((L,), f32))
+    for name, shape in (("wg", (_R_EXPERTS, h, _R_FF)),
+                        ("wu", (_R_EXPERTS, h, _R_FF)),
+                        ("wd", (_R_EXPERTS, _R_FF, h))):
+        w[name] = [(shape, bf)] * L
+    pool, scalar = (_R_POOL, bf), ((), i32)
+    slots, keys = ((S,), i32), ((S, 2), jnp.uint32)
+    moe = {"expert_tokens": ((L, _R_EXPERTS), i32), "experts_hit": ((L,), i32),
+           "decode_calls": ((), i32)}
+    statics = dict(arch="llama", n_heads=_H, n_kv=_R_KV, eps=1e-6, theta=0.0,
+                   do_sample=False, top_k=0, top_p=None, block_size=_BS,
+                   kinds=(("sliding_attention",) * 3 + ("full_attention",))
+                   * (L // 4),
+                   window=_R_WINDOW, moe_k=8)
+    row, temp, vmask = ((_R_TABLE,), i32), ((), f32), ((V,), f32)
+    if kind == "decode":
+        return (E._PAGED_DECODE_DONATED,
+                [w, pool, pool, ((S, _R_TABLE), i32), slots, slots,
+                 ((S,), jnp.bool_), keys, ((S,), f32), ((S, V), f32), moe],
+                dict(statics, flash_decode=False))
+    if kind == "chunk":
+        return (E._PAGED_CHUNK_DONATED,
+                [w, pool, pool, slots, slots, keys, ((1, _R_CHUNK), i32),
+                 scalar, scalar, scalar, row, scalar, scalar,
+                 ((), jnp.uint32), scalar, temp, vmask, moe], statics)
+    return (E._PAGED_PREFILL_DONATED,
+            [w, pool, pool, slots, slots, keys, ((1, 512), i32), scalar,
+             scalar, ((), jnp.uint32), scalar, temp, row, scalar, vmask, moe],
+            statics)
+
+
+@pytest.mark.parametrize("kind", ("decode", "chunk", "prefill"))
+def test_routed_program_compiles_and_copies_neither_pool_nor_banks(
+        kind, one_chip, no_compile_cache):
+    """At the published widths: a layer's expert banks reach their
+    matmuls as the arrays they are (a slice of a stacked bank was copied
+    first: 3 GB a decode step at depth 4), the pool with its 4 KV
+    heads is written in place and never relaid whole before a gather
+    (gathered by blocks it was: 8 copies of 537 MB a step), a window
+    layer's view is the window's blocks, not ``max_len``, and the chunk's
+    full layer walks its 8192 keys in tiles (one pass over float32
+    ``[4, 8, 512, 8192]`` scores took 47 ms on the chip). Every expert is
+    applied to every row: no grouped-matmul kernel is in any of the
+    three."""
+    fn, shapes, statics = _routed_program(kind)
+    args = jax.tree.map(
+        lambda sd: jax.ShapeDtypeStruct(*sd, sharding=one_chip), shapes,
+        is_leaf=lambda x: isinstance(x, tuple) and len(x) == 2
+        and isinstance(x[1], type(jnp.bfloat16)))
+    compiled = fn.lower(*args, **statics).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    pool_elements = int(np.prod(_R_POOL))
+    bank_elements = _R_EXPERTS * _R_HIDDEN * _R_FF
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 2 * pool_elements  # both donated
+    # under two banks of temporaries, in all three (a pool is four)
+    assert mem.temp_size_in_bytes < 4 * bank_elements
+    assert not re.search(r"f32\[4,8,512,8192\]", text)
+    moved = []
+    for shape, op in re.findall(
+            r"= \w+\[([\d,]+)\]\S* (copy|slice|dynamic-slice|"
+            r"dynamic-update-slice)\(", text):
+        n = int(np.prod([int(d) for d in shape.split(",")]))
+        if n in (pool_elements, pool_elements // _R_LAYERS, bank_elements):
+            moved.append((op, shape))
+    assert not moved
+    if kind == "decode":
+        # of four layers three gather 65 blocks a slot, one the whole table
+        views = re.findall(r"= bf16\[16,(\d+),4,128\]\S* gather\(", text)
+        assert sorted(set(views)) == ["1040", "8192"]
+        assert views.count("8192") == 2 * (_R_LAYERS // 4)
+        assert views.count("1040") == 6 * (_R_LAYERS // 4)
