@@ -281,7 +281,7 @@ def audit_engine(engine, compile_budget=None, rules=None,
     fresh process pays the union, so that is the honest budget."""
     import jax
 
-    from .engine_support import engine_donates, lower_decode_program
+    from .engine_support import lower_decode_program
 
     supervisor = None
     if hasattr(engine, "buckets_seen_total") and hasattr(engine, "engine"):
@@ -310,14 +310,12 @@ def audit_engine(engine, compile_budget=None, rules=None,
         "compile_budget": (compile_budget if compile_budget is not None
                            else engine.compile_budget),
         "backend": jax.default_backend(),
-        "donate": engine_donates(engine),
+        "donate": engine._donate,
         "kv_heads": engine.cache.kv_heads,
         "head_dim": engine.cache.head_dim,
-        "kv_layout": getattr(engine, "kv_layout", "slot"),
-        "block_size": getattr(engine, "block_size", None),
-        "n_blocks": (engine.cache.pool.n_blocks
-                     if hasattr(engine.cache, "pool") else None),
-        "prefill_chunk": getattr(engine, "prefill_chunk", None),
+        "block_size": engine.block_size,
+        "n_blocks": engine.cache.pool.n_blocks,
+        "prefill_chunk": engine.prefill_chunk,
         "chunk_used": chunk_used,
         "tp": getattr(engine, "tp", 1),
         "mesh": (engine.tp_geometry()
@@ -388,8 +386,6 @@ def audit_fleet(fleet, compile_budget=None, rules=None,
     per replica and is reported per replica)."""
     import jax
 
-    from .engine_support import engine_donates
-
     replicas = list(fleet.replicas.values())
     buckets: set = set()
     chunk_used = False
@@ -416,13 +412,11 @@ def audit_fleet(fleet, compile_budget=None, rules=None,
         "decode_used": decode_used,
         "compile_budget": compile_budget,
         "backend": jax.default_backend(),
-        "donate": engine_donates(first),
+        "donate": first._donate,
         "kv_heads": first.cache.kv_heads,
         "head_dim": first.cache.head_dim,
-        "kv_layout": first.kv_layout,
         "block_size": first.block_size,
-        "n_blocks": (first.cache.pool.n_blocks
-                     if hasattr(first.cache, "pool") else None),
+        "n_blocks": first.cache.pool.n_blocks,
         "prefill_chunk": first.prefill_chunk,
         "chunk_used": chunk_used,
         "fleet": {"name": fleet.name, "n_replicas": len(replicas),

@@ -377,7 +377,7 @@ def _padding_waste(view):
                 "padding-waste", "low",
                 f"KV cache max_len={m['max_len']} is not a multiple of "
                 "8 — every KV line pads its sublane dim",
-                location="serving.SlotKVCache",
+                location="serving.PagedKVCache",
                 suggested_fix="round max_len up to a multiple of 8")
         lane = m.get("kv_heads", 0) * m.get("head_dim", 0)
         if lane and lane % _LANE:
@@ -386,7 +386,7 @@ def _padding_waste(view):
                 "padding-waste", "low",
                 f"KV lane width kv_heads*head_dim={lane} pads to "
                 f"{-(-lane // _LANE) * _LANE} ({waste:.1f}x KV HBM "
-                "waste)", location="serving.SlotKVCache",
+                "waste)", location="serving.PagedKVCache",
                 suggested_fix="choose head_dim so kv_heads*head_dim is "
                 "a multiple of 128, or pack heads before caching")
         bs = m.get("block_size")

@@ -48,8 +48,8 @@ def _xla_sdpa(q, k, v, mask=None, causal=False, dropout_p=0.0, scale=None,
     return jnp.swapaxes(out, 1, 2)  # [B, L, H, D]
 
 
-# Which kernel the last sdpa_raw trace chose, and why — recorded so
-# bench.py can assert/report the attention path instead of a silent
+# Which kernel the last sdpa_raw trace chose, and why — recorded so a
+# caller can assert/report the attention path instead of a silent
 # fallback hiding a 30x regression (round-1 verdict, weak #3).
 _last_path = {"path": None, "reason": None}
 

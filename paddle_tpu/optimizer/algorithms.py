@@ -47,7 +47,7 @@ def _zeros_like(p, dtype=None):
     """Zero accumulator matching ``p``. Off-trace this is a host
     allocation + device_put, NOT jnp.zeros_like: the latter is itself a
     tiny XLA program, and moment init would be the only backend compile
-    left in a warm AOT-cached fresh process (tools/bench_coldstart.py).
+    left in a warm AOT-cached fresh process.
     Under an outer trace it stays a traced constant as before."""
     import jax
     import numpy as np

@@ -3,10 +3,9 @@
 Reference pairing: paddle/fluid/inference is the reference deployment
 runtime (Config/Predictor over a saved program, one request at a time);
 this package is its many-concurrent-requests counterpart: a paged,
-prefix-shared KV cache (block pool + radix index; slot layout kept for
-A/B) + iteration-level batching engine whose whole decode step is
-one fixed-shape jitted XLA program (see engine.py), with a
-latency/throughput ledger in metrics.py.
+prefix-shared KV cache (block pool + radix index) + iteration-level
+batching engine whose whole decode step is one fixed-shape jitted XLA
+program (see engine.py), with a latency/throughput ledger in metrics.py.
 
 Quick start::
 
@@ -30,8 +29,7 @@ from __future__ import annotations
 from .engine import (AdoptMismatch, Engine, RequestCancelled,  # noqa: F401
                      RequestHandle, RequestShed, RequestTimeout)
 from .fleet import REPLICA_STATES, ReplicaFleet  # noqa: F401
-from .kv_cache import (BlockPool, PagedKVCache, RadixIndex,  # noqa: F401
-                       SlotKVCache)
+from .kv_cache import BlockPool, PagedKVCache, RadixIndex  # noqa: F401
 from .metrics import EngineMetrics, RequestMetrics, ledger  # noqa: F401
 from .resilience import (EngineDraining, EngineSupervisor,  # noqa: F401
                          ServingAborted)
@@ -40,8 +38,7 @@ from .scheduler import (EngineOverloaded, FIFOScheduler,    # noqa: F401
 from .speculative import SpecConfig  # noqa: F401
 
 __all__ = ["Engine", "RequestHandle", "RequestTimeout", "RequestShed",
-           "RequestCancelled", "AdoptMismatch", "SlotKVCache",
-           "PagedKVCache", "BlockPool",
+           "RequestCancelled", "AdoptMismatch", "PagedKVCache", "BlockPool",
            "RadixIndex", "EngineMetrics",
            "RequestMetrics", "ledger", "EngineOverloaded", "FIFOScheduler",
            "PriorityScheduler", "EngineSupervisor", "ServingAborted",

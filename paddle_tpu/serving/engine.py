@@ -8,14 +8,13 @@ requests without ever recompiling):
 - ``submit()`` enqueues a request; admission prefills it **directly into
   its KV storage** with a program bucketed to the next power-of-two
   prompt length (bounded compile count: one prefill program per bucket).
-  The default ``kv_layout="paged"`` draws fixed-size blocks from a
-  shared pool through host-side block tables (runtime operands — zero
-  extra lowerings): requests hold ``ceil(len/block_size)`` blocks
-  instead of worst-case ``max_len`` lines, common prompt prefixes are
-  deduped through a refcounted radix index, prompts longer than
+  The KV cache is paged: fixed-size blocks drawn from a shared pool
+  through host-side block tables (runtime operands — zero extra
+  lowerings): requests hold ``ceil(len/block_size)`` blocks instead of
+  worst-case ``max_len`` lines, common prompt prefixes are deduped
+  through a refcounted radix index, prompts longer than
   ``prefill_chunk`` prefill in block-aligned chunks co-scheduled with
   decode, and pool exhaustion preempts (token-identical replay later).
-  ``kv_layout="slot"`` keeps the PR-4 one-slab-per-slot layout.
 - ``step()`` advances ALL decode-active slots one token with a single
   fused jitted decode program of static shape ``[n_slots, ...]`` — new
   requests join between steps, finished ones free their slot/blocks
@@ -39,8 +38,9 @@ the device.
 ``Engine(tp=N)`` shards the whole program set over a ``tp`` mesh axis
 (one engine across N chips): column-parallel qkv/gate-up, row-parallel
 o-/down-proj, vocab-sharded head, kv-heads-split paged pool — each
-program becomes ONE shard_map SPMD lowering (budget unchanged) whose TP
-dots are overlapped collective-matmuls
+program is the single-device one traced with the static ``tp=N`` inside
+ONE shard_map SPMD lowering (budget unchanged), whose TP dots are
+overlapped collective-matmuls
 (``distributed.collective_matmul``), and sampling runs on the
 ring-gathered full logits with the same PRNG chains, so output stays
 token-identical to the single-device engine. Host-side bookkeeping,
@@ -74,6 +74,7 @@ launches' ``serving.prefill``, ``serving.prefill_chunk``, ``spec.verify``,
 """
 from __future__ import annotations
 
+import functools
 import time
 
 import numpy as np
@@ -84,7 +85,7 @@ import jax.numpy as jnp
 from ..observability import tracing as _tracing
 from ..observability.compile_attr import compile_scope as _compile_scope
 from ..tensor import Tensor
-from .kv_cache import PagedKVCache, SlotKVCache
+from .kv_cache import PagedKVCache
 from .metrics import (EngineMetrics, LaunchRecord, RequestMetrics,
                       StepRecord, SubmitRecord)
 from .scheduler import (EngineOverloaded, FIFOScheduler,  # noqa: F401
@@ -147,12 +148,71 @@ class AdoptMismatch(RuntimeError):
 # shares the compile cache)
 # ---------------------------------------------------------------------------
 
-def _prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot, seed,
-                  skip, temp, vmask, *, arch, n_heads, n_kv, eps, theta,
-                  do_sample, top_k, top_p):
-    """Prefill one request (ids [1, Lb], right-padded to its bucket) into
-    KV slot ``slot``, sample its first token, and register the request's
-    PRNG chain. One compile per bucket length Lb.
+def _layer_body(kind, arch, tp, *, n_heads, n_kv, eps, theta,
+                block_size=None, flash_decode=False, window=None, moe_k=0,
+                valid=None):
+    """Which per-layer body of ``text/generation.py`` serves a program:
+    by the program's ``kind``, the ``arch`` and whether the program is
+    sharded (``tp > 1``: the ``_tp`` bodies, run inside ``shard_map``),
+    with the keywords that body takes bound; its operands stay the
+    program's to pass. The gpt bodies know neither grouped heads nor a
+    rotary table. A window, routed experts (``moe_k`` and the rows that
+    count, ``valid``) and the flash-decode kernel exist in the
+    one-device bodies alone: ``Engine.__init__`` refuses each of them
+    to an engine with ``tp > 1``, and so does this."""
+    from ..text import generation as G
+
+    name = {"prefill": "prefill_layer", "decode": "decode_layer_paged",
+            "chunk": "chunk_layer", "verify": "verify_layer"}[kind]
+    kw = {"n_heads": n_heads}
+    if arch == "llama":
+        kw.update(n_kv=n_kv, eps=eps, theta=theta)
+    if kind != "prefill":
+        kw["block_size"] = block_size
+    if tp > 1:
+        if window is not None or moe_k or flash_decode:
+            raise ValueError("the tensor-parallel bodies take no window, "
+                             "no routed feed-forward and no flash kernel")
+        return functools.partial(getattr(G, f"_{arch}_{name}_tp"), tp=tp,
+                                 **kw)
+    if kind == "decode":
+        kw["flash_decode"] = flash_decode
+    if arch == "llama" and kind != "verify":
+        kw.update(window=window, moe_k=moe_k, valid=valid)
+    return functools.partial(getattr(G, f"_{arch}_{name}"), **kw)
+
+
+def _head(hidden, w, tp):
+    """Logits of ``hidden`` (``[h]`` or ``[rows, h]``, after the final
+    norm). On one device a plain matmul. Sharded, the head's columns are
+    split over the vocabulary and ride a ring all-gather matmul, so every
+    device samples from the FULL logits row and the token stream is the
+    single-device engine's."""
+    if tp == 1:
+        return hidden @ w["head"]
+    from ..text import generation as G
+
+    if hidden.ndim == 1:
+        return G.matmul_allgather(hidden[None], w["head"], G._TP_AXIS,
+                                  tp)[0]
+    return G.matmul_allgather(hidden, w["head"], G._TP_AXIS, tp)
+
+
+def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
+                        seed, skip, temp, table_row, skip_write, vmask,
+                        moe=None, *, arch, n_heads, n_kv, eps, theta,
+                        do_sample, top_k, top_p, block_size, kinds=None,
+                        window=None, moe_k=0, tp=1):
+    """Prefill one request (ids [1, Lb], right-padded to its bucket):
+    the same full causal forward as ``generate()``'s (so the first
+    sampled token is bit-identical to it), sample the first token and
+    register the request's PRNG chain. One compile per bucket length Lb.
+    K/V lands in the paged pool through the slot's block-table row — a
+    block-aligned masked scatter. Positions below ``skip_write``
+    (radix-shared prefix, already resident from the producing request)
+    and at/above ``n_prompt`` (bucket padding) redirect into the trash
+    block, so shared blocks are NEVER rewritten and prefix sharing
+    cannot perturb a co-batched neighbour.
 
     ``skip`` (int32 operand, 0 on normal admission) is the supervisor
     replay path: the admission-seeded key chain is fast-forwarded past
@@ -160,135 +220,19 @@ def _prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot, seed,
     so a request re-prefilled as ``prompt + tokens_emitted_so_far``
     samples its next token with exactly the key the uninterrupted run
     would have used. Being a runtime operand, replay shares the ONE
-    prefill program per bucket with normal admission."""
-    from ..text import generation as G
-
-    Lb = ids.shape[1]
-    if arch == "llama":
-        x = jnp.take(w["embed"], ids, axis=0)
-        pos = jnp.arange(Lb)
-        stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
-
-        def one(xc, lw):
-            return G._llama_prefill_layer(xc, lw, pos, n_heads=n_heads,
-                                          n_kv=n_kv, eps=eps, theta=theta)
-
-        x, kvs = jax.lax.scan(one, x, stack)
-        hlast = jax.lax.dynamic_index_in_dim(
-            G._rms(x, w["norm"], eps)[0], n_prompt - 1, 0, keepdims=False)
-        logits0 = hlast @ w["head"]
-    else:
-        pos = jnp.arange(Lb)
-        x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][pos][None]
-        stack = {k: w[k] for k in G._GPT_STACK_KEYS}
-
-        def one(xc, lw):
-            return G._gpt_prefill_layer(xc, lw, n_heads=n_heads)
-
-        x, kvs = jax.lax.scan(one, x, stack)
-        xlast = jax.lax.dynamic_index_in_dim(x[0], n_prompt - 1, 0,
-                                             keepdims=False)
-        logits0 = G._ln(xlast, w["lnfw"], w["lnfb"]) @ w["head"]
-
-    # bucket-pad KV lines beyond n_prompt land in the slot too, but the
-    # decode causal bound (<= write line) only exposes a line after the
-    # step that overwrote it with real KV — stale lines are never read
-    kc = jax.lax.dynamic_update_slice(kc, kvs[0], (0, slot, 0, 0, 0))
-    vc = jax.lax.dynamic_update_slice(vc, kvs[1], (0, slot, 0, 0, 0))
-
-    key = jax.random.PRNGKey(seed)
-    key = jax.lax.fori_loop(0, skip,
-                            lambda _, k: jax.random.split(k)[0], key)
-    key, sk = jax.random.split(key)
-    logits0 = jnp.where(vmask > 0, logits0, -jnp.inf)
-    logits_f = G._filter_logits(logits0[None], temp, do_sample, top_k,
-                                top_p)
-    if do_sample:
-        tok0 = jax.random.categorical(sk, logits_f, axis=-1)[0]
-    else:
-        tok0 = jnp.argmax(logits_f, axis=-1)[0]
-    tok0 = tok0.astype(jnp.int32)
-    tok = tok.at[slot].set(tok0)
-    cur_pos = cur_pos.at[slot].set(n_prompt.astype(jnp.int32))
-    keys = keys.at[slot].set(key)
-    return kc, vc, tok, cur_pos, keys, tok0
-
-
-def _decode_impl(w, kc, vc, tok, cur_pos, active, keys, temps, vmasks, *,
-                 arch, n_heads, n_kv, eps, theta, do_sample, top_k, top_p):
-    """One fused decode step: every active slot advances one token at its
-    own position (inactive slots compute masked garbage and keep their
-    state). ONE program for the life of the engine. ``vmasks`` [S, V] is
-    the per-request vocab mask (grammar/JSON-constrained decoding): a
-    plain runtime operand — all-ones rows sample unconstrained, so
-    masking adds zero lowerings."""
-    from ..text import generation as G
-
-    if arch == "llama":
-        xt = jnp.take(w["embed"], tok, axis=0)[:, None]
-        stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
-
-        def one(cx, lw_kv):
-            xt2, kc_l, vc_l = G._llama_decode_layer(
-                cx["x"], lw_kv, lw_kv["kc"], lw_kv["vc"], cur_pos,
-                cur_pos, None, n_heads=n_heads, n_kv=n_kv, eps=eps,
-                theta=theta)
-            return {"x": xt2}, (kc_l, vc_l)
-    else:
-        xt = (jnp.take(w["wte"], tok, axis=0)
-              + jnp.take(w["wpe"], cur_pos, axis=0))[:, None]
-        stack = {k: w[k] for k in G._GPT_STACK_KEYS}
-
-        def one(cx, lw_kv):
-            xt2, kc_l, vc_l = G._gpt_decode_layer(
-                cx["x"], lw_kv, lw_kv["kc"], lw_kv["vc"], cur_pos, None,
-                n_heads=n_heads)
-            return {"x": xt2}, (kc_l, vc_l)
-
-    lw_kv = dict(stack)
-    lw_kv["kc"] = kc
-    lw_kv["vc"] = vc
-    cx, (kc, vc) = jax.lax.scan(one, {"x": xt}, lw_kv)
-    if arch == "llama":
-        hidden = G._rms(cx["x"][:, 0], w["norm"], eps)
-        logits = hidden @ w["head"]
-    else:
-        logits = G._ln(cx["x"][:, 0], w["lnfw"], w["lnfb"]) @ w["head"]
-    logits = jnp.where(vmasks > 0, logits, -jnp.inf)
-
-    split = jax.vmap(jax.random.split)(keys)        # [S, 2, 2]
-    new_keys, sks = split[:, 0], split[:, 1]
-    logits_f = G._filter_logits(logits, temps, do_sample, top_k, top_p)
-    if do_sample:
-        nxt = jax.vmap(jax.random.categorical)(sks, logits_f)
-    else:
-        nxt = jnp.argmax(logits_f, axis=-1)
-    nxt = nxt.astype(jnp.int32)
-    # inactive slots hold position: token, key chain and cur_pos freeze
-    nxt = jnp.where(active, nxt, tok)
-    new_keys = jnp.where(active[:, None], new_keys, keys)
-    cur2 = jnp.where(active, cur_pos + 1, cur_pos)
-    return nxt, kc, vc, cur2, new_keys
-
-
-def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
-                        seed, skip, temp, table_row, skip_write, vmask,
-                        moe=None, *, arch, n_heads, n_kv, eps, theta,
-                        do_sample, top_k, top_p, block_size, kinds=None,
-                        window=None, moe_k=0):
-    """Paged prefill: the SAME full causal forward as ``_prefill_impl``
-    (so the first sampled token is bit-identical to the slot engine and
-    ``generate()``), but K/V lands in the paged pool through the slot's
-    block-table row — a block-aligned masked scatter. Positions below
-    ``skip_write`` (radix-shared prefix, already resident from the
-    producing request) and at/above ``n_prompt`` (bucket padding)
-    redirect into the trash block, so shared blocks are NEVER rewritten
-    and prefix sharing cannot perturb a co-batched neighbour.
+    prefill program per bucket with normal admission.
 
     ``kinds`` / ``window`` / ``moe_k`` describe a model whose layers
     differ in kind and route their feed-forward (``_make_arch``); its
     counters ``moe`` come in last and go out last, with the picks of the
-    prompt's own positions added."""
+    prompt's own positions added.
+
+    ``tp > 1``: the program runs INSIDE ``shard_map`` over the ``tp``
+    mesh axis (``_tp_jitted``). Every weight leaf and the KV pool arrive
+    as per-device shards: attention runs over the local head group, the
+    row-parallel projections reassemble replicated activations through
+    ppermute-pipelined collective-matmuls (``_layer_body``), and the
+    token is sampled from the ring-gathered full logits (``_head``)."""
     from ..text import generation as G
 
     Lb = ids.shape[1]
@@ -296,33 +240,29 @@ def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
         x = jnp.take(w["embed"], ids, axis=0)
         pos = jnp.arange(Lb)
         real = jnp.arange(Lb) < n_prompt
-
-        def layer_of(win):
-            def one(xc, lw):
-                return G._llama_prefill_layer(
-                    xc, lw, pos, n_heads=n_heads, n_kv=n_kv, eps=eps,
-                    theta=theta, window=win, moe_k=moe_k, valid=real)
-            return one
-
-        x, kvs = _scan_layers(_by_kind(layer_of, kinds, window),
-                              G._llama_stack(w), x)
-        if moe is not None:
-            moe = _count_picks(moe, kvs[2])
-        hlast = jax.lax.dynamic_index_in_dim(
-            G._rms(x, w["norm"], eps)[0], n_prompt - 1, 0, keepdims=False)
-        logits0 = hlast @ w["head"]
+        stack, at = G._llama_stack(w), (pos,)       # the rotary positions
     else:
         pos = jnp.arange(Lb)
         x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][pos][None]
-        stack = {k: w[k] for k in G._GPT_STACK_KEYS}
+        stack, at, real = {k: w[k] for k in G._GPT_STACK_KEYS}, (), None
 
-        def one(xc, lw):
-            return G._gpt_prefill_layer(xc, lw, n_heads=n_heads)
+    def layer_of(win):
+        body = _layer_body("prefill", arch, tp, n_heads=n_heads, n_kv=n_kv,
+                           eps=eps, theta=theta, window=win, moe_k=moe_k,
+                           valid=real)
+        return lambda xc, lw: body(xc, lw, *at)
 
-        x, kvs = jax.lax.scan(one, x, stack)
+    x, kvs = _scan_layers(_by_kind(layer_of, kinds, window), stack, x)
+    if moe is not None:
+        moe = _count_picks(moe, kvs[2])
+    if arch == "llama":
+        hlast = jax.lax.dynamic_index_in_dim(
+            G._rms(x, w["norm"], eps)[0], n_prompt - 1, 0, keepdims=False)
+    else:
         xlast = jax.lax.dynamic_index_in_dim(x[0], n_prompt - 1, 0,
                                              keepdims=False)
-        logits0 = G._ln(xlast, w["lnfw"], w["lnfb"]) @ w["head"]
+        hlast = G._ln(xlast, w["lnfw"], w["lnfb"])
+    logits0 = _head(hlast, w, tp)
 
     j = jnp.arange(Lb)
     writable = (j >= skip_write) & (j < n_prompt)
@@ -467,7 +407,7 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
                        temps, vmasks, moe=None, *, arch, n_heads, n_kv, eps,
                        theta, do_sample, top_k, top_p, block_size,
                        flash_decode=False, kinds=None, window=None,
-                       moe_k=0):
+                       moe_k=0, tp=1):
     """One fused paged decode step: every decode-active slot advances a
     token at its own position, writing K/V through its block table
     (inactive rows scatter into the trash block so a freed slot's stale
@@ -479,7 +419,13 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
     shape; the pool is a carry of the layer loop
     (``_scan_layers_over_pool``), written in place when donated.
     ``kinds`` / ``window`` / ``moe_k`` / ``moe`` as in the prefill
-    program; the routed layers compute and count the active rows alone."""
+    program; the routed layers compute and count the active rows alone.
+    ``tp > 1`` as in the prefill program: each device scatters its
+    kv-head shard into its pool shard (the LOCAL shard is the carry) and
+    attends over its local head group; the o-/down-projections and the
+    vocab head are overlapped collective-matmuls, so the decode HLO
+    holds ``collective_permute`` ops alone — nothing serializes after a
+    dot."""
     from ..text import generation as G
 
     S = tok.shape[0]
@@ -490,35 +436,27 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
     if arch == "llama":
         xt = jnp.take(w["embed"], tok, axis=0)[:, None]
         stack = G._llama_stack(w)
-
-        def layer_of(win):
-            def layer(xc, lw, kc_p, vc_p, blocks, rows):
-                return G._llama_decode_layer_paged(
-                    xc, lw, kc_p, vc_p, blocks, rows, cur_pos, cur_pos,
-                    n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
-                    block_size=block_size, flash_decode=flash_decode,
-                    window=win, moe_k=moe_k, valid=active)
-            return layer
-
-        layer = _by_kind(layer_of, kinds, window)
+        at = (cur_pos, cur_pos)         # the write line, the rotary one
     else:
         xt = (jnp.take(w["wte"], tok, axis=0)
               + jnp.take(w["wpe"], cur_pos, axis=0))[:, None]
-        stack = {k: w[k] for k in G._GPT_STACK_KEYS}
+        stack, at = {k: w[k] for k in G._GPT_STACK_KEYS}, (cur_pos,)
 
-        def layer(xc, lw, kc_p, vc_p, blocks, rows):
-            return G._gpt_decode_layer_paged(
-                xc, lw, kc_p, vc_p, blocks, rows, cur_pos,
-                n_heads=n_heads, block_size=block_size,
-                flash_decode=flash_decode)
+    def layer_of(win):
+        body = _layer_body("decode", arch, tp, n_heads=n_heads, n_kv=n_kv,
+                           eps=eps, theta=theta, block_size=block_size,
+                           flash_decode=flash_decode, window=win,
+                           moe_k=moe_k, valid=active)
+        return lambda xc, lw, kc_p, vc_p, blocks, rows: body(
+            xc, lw, kc_p, vc_p, blocks, rows, *at)
 
-    xt, kc, vc, *picks = _scan_layers_over_pool(layer, stack, xt, kc, vc,
-                                                tables, dest)
+    xt, kc, vc, *picks = _scan_layers_over_pool(
+        _by_kind(layer_of, kinds, window), stack, xt, kc, vc, tables, dest)
     if arch == "llama":
         hidden = G._rms(xt[:, 0], w["norm"], eps)
-        logits = hidden @ w["head"]
     else:
-        logits = G._ln(xt[:, 0], w["lnfw"], w["lnfb"]) @ w["head"]
+        hidden = G._ln(xt[:, 0], w["lnfw"], w["lnfb"])
+    logits = _head(hidden, w, tp)
     logits = jnp.where(vmasks > 0, logits, -jnp.inf)
 
     split = jax.vmap(jax.random.split)(keys)        # [S, 2, 2]
@@ -542,7 +480,7 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
                       n_prompt, slot, table_row, skip_write, is_final,
                       seed, skip, temp, vmask, moe=None, *, arch, n_heads,
                       n_kv, eps, theta, do_sample, top_k, top_p, block_size,
-                      kinds=None, window=None, moe_k=0):
+                      kinds=None, window=None, moe_k=0, tp=1):
     """One block-aligned prefill CHUNK of one slot, co-schedulable with
     the fused decode step: processes ``ids`` ([1, C], global positions
     ``chunk_start + j``) through every layer, scattering its K/V into
@@ -554,7 +492,8 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
     length. Sampling uses the admission-seeded PRNG chain with the
     supervisor-replay ``skip`` fast-forward, like the one-shot paths.
     The pool is a carry of the layer loop, as in the decode program;
-    ``kinds`` / ``window`` / ``moe_k`` / ``moe`` as in the prefill one."""
+    ``kinds`` / ``window`` / ``moe_k`` / ``moe`` / ``tp`` as in the
+    prefill one."""
     from ..text import generation as G
 
     C = ids.shape[1]
@@ -567,37 +506,32 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
     if arch == "llama":
         x = jnp.take(w["embed"], ids, axis=0)
         stack = G._llama_stack(w)
-
-        def layer_of(win):
-            def layer(xc, lw, kc_p, vc_p, blocks, rows):
-                return G._llama_chunk_layer(
-                    xc, lw, kc_p, vc_p, blocks, gpos, rows,
-                    n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
-                    block_size=block_size, window=win, moe_k=moe_k,
-                    valid=gpos < n_prompt)
-            return layer
-
-        layer = _by_kind(layer_of, kinds, window)
     else:
         x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][gpos][None]
         stack = {k: w[k] for k in G._GPT_STACK_KEYS}
 
+    def layer_of(win):
         def layer(xc, lw, kc_p, vc_p, blocks, rows):
-            return G._gpt_chunk_layer(
-                xc, lw, kc_p, vc_p, blocks, gpos, rows,
-                n_heads=n_heads, block_size=block_size)
+            body = _layer_body(
+                "chunk", arch, tp, n_heads=n_heads, n_kv=n_kv, eps=eps,
+                theta=theta, block_size=block_size, window=win,
+                moe_k=moe_k,
+                valid=gpos < n_prompt if arch == "llama" else None)
+            return body(xc, lw, kc_p, vc_p, blocks, gpos, rows)
+        return layer
 
-    x, kc, vc, *picks = _scan_layers_over_pool(layer, stack, x, kc, vc,
-                                               table_row, wdest)
+    x, kc, vc, *picks = _scan_layers_over_pool(
+        _by_kind(layer_of, kinds, window), stack, x, kc, vc, table_row,
+        wdest)
     li = jnp.clip(n_prompt - 1 - chunk_start, 0, C - 1)
     if arch == "llama":
         hlast = jax.lax.dynamic_index_in_dim(
             G._rms(x, w["norm"], eps)[0], li, 0, keepdims=False)
-        logits0 = hlast @ w["head"]
     else:
         xlast = jax.lax.dynamic_index_in_dim(x[0], li, 0,
                                              keepdims=False)
-        logits0 = G._ln(xlast, w["lnfw"], w["lnfb"]) @ w["head"]
+        hlast = G._ln(xlast, w["lnfw"], w["lnfb"])
+    logits0 = _head(hlast, w, tp)
 
     key = jax.random.PRNGKey(seed)
     key = jax.lax.fori_loop(0, skip,
@@ -619,213 +553,6 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
     keys = jnp.where(fin, keys.at[slot].set(key), keys)
     if moe is not None:
         return kc, vc, tok, cur_pos, keys, tok0, _count_picks(moe, picks[0])
-    return kc, vc, tok, cur_pos, keys, tok0
-
-
-def _tp_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
-                     seed, skip, temp, table_row, skip_write, vmask, *,
-                     arch, n_heads, n_kv, eps, theta, do_sample, top_k,
-                     top_p, block_size, tp):
-    """Tensor-parallel paged prefill (runs INSIDE shard_map over the
-    ``tp`` mesh axis): same causal forward and PRNG chain as
-    ``_paged_prefill_impl``, but every weight leaf / the KV pool arrive
-    as per-device shards — attention runs over the local head group and
-    the row-parallel projections reassemble replicated activations
-    through ppermute-pipelined collective-matmuls. The sampled token is
-    drawn from the ring-gathered FULL logits row, so the sampling math
-    (and therefore the token stream) is shared with the single-device
-    engine."""
-    from ..text import generation as G
-
-    Lb = ids.shape[1]
-    if arch == "llama":
-        x = jnp.take(w["embed"], ids, axis=0)
-        pos = jnp.arange(Lb)
-        stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
-
-        def one(xc, lw):
-            return G._llama_prefill_layer_tp(
-                xc, lw, pos, n_heads=n_heads, n_kv=n_kv, eps=eps,
-                theta=theta, tp=tp)
-
-        x, kvs = jax.lax.scan(one, x, stack)
-        hlast = jax.lax.dynamic_index_in_dim(
-            G._rms(x, w["norm"], eps)[0], n_prompt - 1, 0, keepdims=False)
-        logits0 = G.matmul_allgather(hlast[None], w["head"], G._TP_AXIS,
-                                     tp)[0]
-    else:
-        pos = jnp.arange(Lb)
-        x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][pos][None]
-        stack = {k: w[k] for k in G._GPT_STACK_KEYS}
-
-        def one(xc, lw):
-            return G._gpt_prefill_layer_tp(xc, lw, n_heads=n_heads, tp=tp)
-
-        x, kvs = jax.lax.scan(one, x, stack)
-        xlast = jax.lax.dynamic_index_in_dim(x[0], n_prompt - 1, 0,
-                                             keepdims=False)
-        logits0 = G.matmul_allgather(
-            G._ln(xlast, w["lnfw"], w["lnfb"])[None], w["head"],
-            G._TP_AXIS, tp)[0]
-
-    j = jnp.arange(Lb)
-    writable = (j >= skip_write) & (j < n_prompt)
-    dest = jnp.where(writable,
-                     table_row[j // block_size] * block_size
-                     + j % block_size,
-                     j % block_size)             # trash block rows
-    L, nb, bs = kc.shape[0], kc.shape[1], kc.shape[2]
-    kvh, hd = kc.shape[3], kc.shape[4]
-    kc = kc.reshape(L, nb * bs, kvh, hd).at[:, dest].set(
-        kvs[0][:, 0]).reshape(L, nb, bs, kvh, hd)
-    vc = vc.reshape(L, nb * bs, kvh, hd).at[:, dest].set(
-        kvs[1][:, 0]).reshape(L, nb, bs, kvh, hd)
-
-    key = jax.random.PRNGKey(seed)
-    key = jax.lax.fori_loop(0, skip,
-                            lambda _, k: jax.random.split(k)[0], key)
-    key, sk = jax.random.split(key)
-    logits0 = jnp.where(vmask > 0, logits0, -jnp.inf)
-    logits_f = G._filter_logits(logits0[None], temp, do_sample, top_k,
-                                top_p)
-    if do_sample:
-        tok0 = jax.random.categorical(sk, logits_f, axis=-1)[0]
-    else:
-        tok0 = jnp.argmax(logits_f, axis=-1)[0]
-    tok0 = tok0.astype(jnp.int32)
-    tok = tok.at[slot].set(tok0)
-    cur_pos = cur_pos.at[slot].set(n_prompt.astype(jnp.int32))
-    keys = keys.at[slot].set(key)
-    return kc, vc, tok, cur_pos, keys, tok0
-
-
-def _tp_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys, temps,
-                    vmasks, *, arch, n_heads, n_kv, eps, theta, do_sample,
-                    top_k, top_p, block_size, tp):
-    """Tensor-parallel fused paged decode step (inside shard_map): ONE
-    SPMD program for the life of the engine. Each device scatters its
-    kv-head shard into its pool shard and attends over its local head
-    group; the o-/down-projections and the vocab head are overlapped
-    collective-matmuls, so the decode HLO contains only
-    ``collective_permute`` ops — nothing serializes after a dot. The
-    LOCAL pool shard is a carry of the layer loop, as on one device."""
-    from ..text import generation as G
-
-    S = tok.shape[0]
-    rows = jnp.arange(S)
-    blk = tables[rows, cur_pos // block_size]
-    dest = jnp.where(active, blk * block_size + cur_pos % block_size,
-                     cur_pos % block_size)
-    if arch == "llama":
-        xt = jnp.take(w["embed"], tok, axis=0)[:, None]
-        stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
-
-        def layer(xc, lw, kc_p, vc_p, blocks, rows):
-            return G._llama_decode_layer_paged_tp(
-                xc, lw, kc_p, vc_p, blocks, rows, cur_pos, cur_pos,
-                n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
-                block_size=block_size, tp=tp)
-    else:
-        xt = (jnp.take(w["wte"], tok, axis=0)
-              + jnp.take(w["wpe"], cur_pos, axis=0))[:, None]
-        stack = {k: w[k] for k in G._GPT_STACK_KEYS}
-
-        def layer(xc, lw, kc_p, vc_p, blocks, rows):
-            return G._gpt_decode_layer_paged_tp(
-                xc, lw, kc_p, vc_p, blocks, rows, cur_pos,
-                n_heads=n_heads, block_size=block_size, tp=tp)
-
-    xt, kc, vc = _scan_layers_over_pool(layer, stack, xt, kc, vc, tables,
-                                        dest)
-    if arch == "llama":
-        hidden = G._rms(xt[:, 0], w["norm"], eps)
-    else:
-        hidden = G._ln(xt[:, 0], w["lnfw"], w["lnfb"])
-    logits = G.matmul_allgather(hidden, w["head"], G._TP_AXIS, tp)
-    logits = jnp.where(vmasks > 0, logits, -jnp.inf)
-
-    split = jax.vmap(jax.random.split)(keys)        # [S, 2, 2]
-    new_keys, sks = split[:, 0], split[:, 1]
-    logits_f = G._filter_logits(logits, temps, do_sample, top_k, top_p)
-    if do_sample:
-        nxt = jax.vmap(jax.random.categorical)(sks, logits_f)
-    else:
-        nxt = jnp.argmax(logits_f, axis=-1)
-    nxt = nxt.astype(jnp.int32)
-    nxt = jnp.where(active, nxt, tok)
-    new_keys = jnp.where(active[:, None], new_keys, keys)
-    cur2 = jnp.where(active, cur_pos + 1, cur_pos)
-    return nxt, kc, vc, cur2, new_keys
-
-
-def _tp_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
-                   n_prompt, slot, table_row, skip_write, is_final, seed,
-                   skip, temp, vmask, *, arch, n_heads, n_kv, eps, theta,
-                   do_sample, top_k, top_p, block_size, tp):
-    """Tensor-parallel chunked-prefill step (inside shard_map): the SAME
-    one-extra-lowering contract as ``_paged_chunk_impl`` — every chunk
-    of every long prompt shares this program, ``is_final`` gating the
-    sampling side effects as a runtime operand (pool shard: a carry)."""
-    from ..text import generation as G
-
-    C = ids.shape[1]
-    gpos = chunk_start + jnp.arange(C)
-    writable = (gpos >= skip_write) & (gpos < n_prompt)
-    wdest = jnp.where(writable,
-                      table_row[gpos // block_size] * block_size
-                      + gpos % block_size,
-                      gpos % block_size)
-    if arch == "llama":
-        x = jnp.take(w["embed"], ids, axis=0)
-        stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
-
-        def layer(xc, lw, kc_p, vc_p, blocks, rows):
-            return G._llama_chunk_layer_tp(
-                xc, lw, kc_p, vc_p, blocks, gpos, rows,
-                n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
-                block_size=block_size, tp=tp)
-    else:
-        x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][gpos][None]
-        stack = {k: w[k] for k in G._GPT_STACK_KEYS}
-
-        def layer(xc, lw, kc_p, vc_p, blocks, rows):
-            return G._gpt_chunk_layer_tp(
-                xc, lw, kc_p, vc_p, blocks, gpos, rows,
-                n_heads=n_heads, block_size=block_size, tp=tp)
-
-    x, kc, vc = _scan_layers_over_pool(layer, stack, x, kc, vc, table_row,
-                                       wdest)
-    li = jnp.clip(n_prompt - 1 - chunk_start, 0, C - 1)
-    if arch == "llama":
-        hlast = jax.lax.dynamic_index_in_dim(
-            G._rms(x, w["norm"], eps)[0], li, 0, keepdims=False)
-        logits0 = G.matmul_allgather(hlast[None], w["head"], G._TP_AXIS,
-                                     tp)[0]
-    else:
-        xlast = jax.lax.dynamic_index_in_dim(x[0], li, 0,
-                                             keepdims=False)
-        logits0 = G.matmul_allgather(
-            G._ln(xlast, w["lnfw"], w["lnfb"])[None], w["head"],
-            G._TP_AXIS, tp)[0]
-
-    key = jax.random.PRNGKey(seed)
-    key = jax.lax.fori_loop(0, skip,
-                            lambda _, k: jax.random.split(k)[0], key)
-    key, sk = jax.random.split(key)
-    logits0 = jnp.where(vmask > 0, logits0, -jnp.inf)
-    logits_f = G._filter_logits(logits0[None], temp, do_sample, top_k,
-                                top_p)
-    if do_sample:
-        tok0 = jax.random.categorical(sk, logits_f, axis=-1)[0]
-    else:
-        tok0 = jnp.argmax(logits_f, axis=-1)[0]
-    tok0 = tok0.astype(jnp.int32)
-    fin = is_final.astype(bool)
-    tok = jnp.where(fin, tok.at[slot].set(tok0), tok)
-    cur_pos = jnp.where(fin,
-                        cur_pos.at[slot].set(n_prompt.astype(jnp.int32)),
-                        cur_pos)
-    keys = jnp.where(fin, keys.at[slot].set(key), keys)
     return kc, vc, tok, cur_pos, keys, tok0
 
 
@@ -866,20 +593,14 @@ def _spec_verify_impl(w, kc, vc, keys, ids, start, slot, table_row,
     if arch == "llama":
         x = jnp.take(w["embed"], ids, axis=0)
         stack = {k: w[k] for k in G._LLAMA_STACK_KEYS}
-
-        def layer(xc, lw, kc_p, vc_p, blocks, rows):
-            return G._llama_verify_layer(
-                xc, lw, kc_p, vc_p, blocks, gpos, rows,
-                n_heads=n_heads, n_kv=n_kv, eps=eps, theta=theta,
-                block_size=block_size)
     else:
         x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][gpos][None]
         stack = {k: w[k] for k in G._GPT_STACK_KEYS}
+    body = _layer_body("verify", arch, 1, n_heads=n_heads, n_kv=n_kv,
+                       eps=eps, theta=theta, block_size=block_size)
 
-        def layer(xc, lw, kc_p, vc_p, blocks, rows):
-            return G._gpt_verify_layer(
-                xc, lw, kc_p, vc_p, blocks, gpos, rows,
-                n_heads=n_heads, block_size=block_size)
+    def layer(xc, lw, kc_p, vc_p, blocks, rows):
+        return body(xc, lw, kc_p, vc_p, blocks, gpos, rows)
 
     x, kc, vc = _scan_layers_over_pool(layer, stack, x, kc, vc, table_row,
                                        wdest)
@@ -905,13 +626,13 @@ def _spec_verify_impl(w, kc, vc, keys, ids, start, slot, table_row,
 
 _STATICS = ("arch", "n_heads", "n_kv", "eps", "theta", "do_sample",
             "top_k", "top_p")
-# kinds / window / moe_k: a model whose layers differ in kind and route
-# their feed-forward (``_make_arch``); absent from every other model's
-# statics, so those programs and their cache keys are what they were
 _PAGED_STATICS = _STATICS + ("block_size",)
-_KIND_STATICS = ("kinds", "window", "moe_k")
-_PAGED_DECODE_STATICS = _PAGED_STATICS + ("flash_decode",)
-_TP_STATICS = _PAGED_STATICS + ("tp",)
+# kinds / window / moe_k: a model whose layers differ in kind and route
+# their feed-forward (``_make_arch``); tp: a sharded engine's, baked into
+# its ``shard_map`` programs (``_tp_jitted``). A model or an engine without
+# them passes none, so its programs and their cache keys are what they were
+_PROGRAM_STATICS = _PAGED_STATICS + ("kinds", "window", "moe_k", "tp")
+_DECODE_STATICS = _PROGRAM_STATICS + ("flash_decode",)
 
 _CODE_TOKEN = None
 
@@ -943,61 +664,55 @@ def _serving_code_token():
 #: still hits this cache and re-traces nothing in-process.
 _TP_PROGRAMS: dict = {}
 
-_TP_IN_REST = {"prefill": 12, "decode": 7, "chunk": 14}
-_TP_IMPLS = {"prefill": _tp_prefill_impl, "decode": _tp_decode_impl,
-             "chunk": _tp_chunk_impl}
-
 
 def _tp_jitted(mesh, kind, arch, donate, statics_items):
-    """Build (or fetch) the jitted shard_map wrapper for one TP program
-    kind. Statics are BAKED via closure (shard_map has no static-kwarg
-    channel); they live in the cache key and in the engine's AOT key
-    parts instead."""
+    """Build (or fetch) the jitted ``shard_map`` of one paged program
+    (``kind``: prefill, decode or chunk) over the ``tp`` axis of
+    ``mesh``. Statics, ``tp`` among them, are BAKED via closure
+    (shard_map has no static-kwarg channel); they live in the cache key
+    and in the engine's AOT key parts instead."""
     key = (mesh, kind, arch, donate, statics_items)
     fn = _TP_PROGRAMS.get(key)
     if fn is not None:
         return fn
-    import functools
-
     from jax.sharding import PartitionSpec as P
 
     from ..distributed.mesh import shard_map
     from ..text import generation as G
 
+    impl, n_rest = {"prefill": (_paged_prefill_impl, 12),
+                    "decode": (_paged_decode_impl, 7),
+                    "chunk": (_paged_chunk_impl, 14)}[kind]
     wspec = G._llama_tp_specs() if arch == "llama" else G._gpt_tp_specs()
     kv = P(None, None, None, "tp", None)
     R = P()
-    in_specs = (wspec, kv, kv) + (R,) * _TP_IN_REST[kind]
+    # the weights, the two pool shards, then the program's other operands,
+    # all replicated
+    in_specs = (wspec, kv, kv) + (R,) * n_rest
     if kind == "decode":
         out_specs = (R, kv, kv, R, R)
     else:
         out_specs = (kv, kv, R, R, R, R)
-    body = functools.partial(_TP_IMPLS[kind], **dict(statics_items))
+    body = functools.partial(impl, **dict(statics_items))
     sm = shard_map(body, mesh=mesh, in_specs=in_specs,
                    out_specs=out_specs, check_vma=False)
     fn = jax.jit(sm, donate_argnums=(1, 2) if donate else ())
     _TP_PROGRAMS[key] = fn
     return fn
-_PREFILL = jax.jit(_prefill_impl, static_argnames=_STATICS)
-_PREFILL_DONATED = jax.jit(_prefill_impl, static_argnames=_STATICS,
-                           donate_argnums=(1, 2))
-_DECODE = jax.jit(_decode_impl, static_argnames=_STATICS)
-_DECODE_DONATED = jax.jit(_decode_impl, static_argnames=_STATICS,
-                          donate_argnums=(1, 2))
+
+
 _PAGED_PREFILL = jax.jit(_paged_prefill_impl,
-                         static_argnames=_PAGED_STATICS + _KIND_STATICS)
+                         static_argnames=_PROGRAM_STATICS)
 _PAGED_PREFILL_DONATED = jax.jit(
-    _paged_prefill_impl, static_argnames=_PAGED_STATICS + _KIND_STATICS,
+    _paged_prefill_impl, static_argnames=_PROGRAM_STATICS,
     donate_argnums=(1, 2))
-_PAGED_DECODE = jax.jit(
-    _paged_decode_impl, static_argnames=_PAGED_DECODE_STATICS + _KIND_STATICS)
+_PAGED_DECODE = jax.jit(_paged_decode_impl, static_argnames=_DECODE_STATICS)
 _PAGED_DECODE_DONATED = jax.jit(
-    _paged_decode_impl, static_argnames=_PAGED_DECODE_STATICS + _KIND_STATICS,
+    _paged_decode_impl, static_argnames=_DECODE_STATICS,
     donate_argnums=(1, 2))
-_PAGED_CHUNK = jax.jit(_paged_chunk_impl,
-                       static_argnames=_PAGED_STATICS + _KIND_STATICS)
+_PAGED_CHUNK = jax.jit(_paged_chunk_impl, static_argnames=_PROGRAM_STATICS)
 _PAGED_CHUNK_DONATED = jax.jit(
-    _paged_chunk_impl, static_argnames=_PAGED_STATICS + _KIND_STATICS,
+    _paged_chunk_impl, static_argnames=_PROGRAM_STATICS,
     donate_argnums=(1, 2))
 _SPEC_VERIFY = jax.jit(_spec_verify_impl, static_argnames=_PAGED_STATICS)
 _SPEC_VERIFY_DONATED = jax.jit(_spec_verify_impl,
@@ -1182,24 +897,22 @@ class Engine:
                  min_prompt_bucket=8, token_budget=None, max_queue=None,
                  base_seed=0, donate=None, compile_budget=None,
                  default_retry_after_s=DEFAULT_RETRY_AFTER_S,
-                 kv_layout="paged", block_size=16, n_blocks=None,
+                 block_size=16, n_blocks=None,
                  prefill_chunk=None, prefix_sharing=True, tp=1,
                  mesh=None, replica_id=None, flash_decode=False,
                  speculative=None):
         self._w, self._hp, geo = _make_arch(model)
         if "kinds" in self._hp:
             # a model of layer kinds with routed experts runs through the
-            # paged single-device gathered programs alone: what else it
-            # is asked for is refused by name, never served another way
+            # single-device gathered programs alone: what else it is
+            # asked for is refused by name, never served another way
             for asked, missing in (
                     (int(tp) > 1, "tp > 1: the tensor-parallel bodies have "
                      "no routed feed-forward and take no window"),
                     (speculative is not None, "speculative=...: the verify "
                      "body has no routed feed-forward and takes no window"),
                     (flash_decode, "flash_decode=True: the flash-decode "
-                     "kernel takes no window"),
-                    (kv_layout == "slot", "kv_layout='slot': the slot "
-                     "bodies take no window and no rotary table")):
+                     "kernel takes no window")):
                 if asked:
                     raise ValueError(
                         f"serving.Engine cannot serve "
@@ -1211,7 +924,7 @@ class Engine:
         self._mesh = None
         self._n_layers = geo["n_layers"]
         if self.tp > 1:
-            mesh = self._init_tp(mesh, geo, kv_layout)
+            mesh = self._init_tp(mesh)
         elif mesh is not None:
             raise ValueError("mesh= requires tp > 1")
         self.n_slots = int(n_slots)
@@ -1229,68 +942,50 @@ class Engine:
         # tp degree or KV geometry — share it and may exchange handles
         self.model_fingerprint = _model_fingerprint(
             model, self._hp, self._statics, eos_token_id, self._w)
-        if kv_layout not in ("paged", "slot"):
-            raise ValueError("kv_layout must be 'paged' or 'slot'")
         # the pallas flash-decode kernel replaces the gathered decode
-        # attention (paged, single-device only — the TP decode rings its
-        # own attention path). Interpret mode on CPU keeps the program
+        # attention (single-device only — the TP decode rings its own
+        # attention path). Interpret mode on CPU keeps the program
         # compilable everywhere; output is token-identical to the
         # gathered form, and the replay/adopt machinery is untouched.
         self.flash_decode = bool(flash_decode)
-        if self.flash_decode and kv_layout != "paged":
-            raise ValueError("flash_decode=True requires kv_layout="
-                             "'paged' (the block-table operands)")
         if self.flash_decode and self.tp > 1:
             raise ValueError("flash_decode is not supported with tp > 1 "
                              "yet (the TP decode shards attention over "
                              "the mesh)")
         # speculative decoding (draft-verify; see serving/speculative.py):
-        # the verify program is chunk-shaped against the paged pool, and
-        # the TP decode shards attention over the mesh — both gates below
+        # the TP decode shards attention over the mesh, the verify
+        # program does not
         self.spec = speculative
         if self.spec is not None:
             from .speculative import SpecConfig
             if not isinstance(self.spec, SpecConfig):
                 raise TypeError("speculative= takes a SpecConfig")
-            if kv_layout != "paged":
-                raise ValueError("speculative decoding requires "
-                                 "kv_layout='paged' (the verify program "
-                                 "writes through block tables)")
             if self.tp > 1:
                 raise ValueError("speculative decoding is not supported "
                                  "with tp > 1 yet")
-        self.kv_layout = kv_layout
-        self.prefix_sharing = bool(prefix_sharing) and kv_layout == "paged"
-        self._chunking = []        # in-progress chunked prefills (paged)
+        self.prefix_sharing = bool(prefix_sharing)
+        self._chunking = []        # in-progress chunked prefills
         self.chunk_used = False    # the +1 chunk lowering, once traced
-        if kv_layout == "paged":
-            self.block_size = int(block_size)
-            if prefill_chunk is not None:
-                prefill_chunk = int(prefill_chunk)
-                if prefill_chunk < self.block_size \
-                        or prefill_chunk % self.block_size:
-                    raise ValueError(
-                        "prefill_chunk must be a block-aligned multiple "
-                        f"of block_size={self.block_size}")
-            self.prefill_chunk = prefill_chunk
-            self.cache = PagedKVCache(geo["n_layers"], self.n_slots,
-                                      self.max_len, geo["kv_heads"],
-                                      geo["head_dim"], geo["dtype"],
-                                      block_size=self.block_size,
-                                      n_blocks=n_blocks)
-            self._paged_statics = dict(self._statics,
-                                       block_size=self.block_size)
-            # the flash_decode static only shapes the DECODE program;
-            # prefill/chunk keep their signatures (and AOT keys) stable
-            self._decode_statics = dict(self._paged_statics,
-                                        flash_decode=self.flash_decode)
-        else:
-            self.block_size = None
-            self.prefill_chunk = None
-            self._decode_statics = dict(self._statics)
-            self.cache = SlotKVCache(geo["n_layers"], self.n_slots,
-                                     self.max_len, geo["kv_heads"],
-                                     geo["head_dim"], geo["dtype"])
+        self.block_size = int(block_size)
+        if prefill_chunk is not None:
+            prefill_chunk = int(prefill_chunk)
+            if prefill_chunk < self.block_size \
+                    or prefill_chunk % self.block_size:
+                raise ValueError(
+                    "prefill_chunk must be a block-aligned multiple "
+                    f"of block_size={self.block_size}")
+        self.prefill_chunk = prefill_chunk
+        self.cache = PagedKVCache(geo["n_layers"], self.n_slots,
+                                  self.max_len, geo["kv_heads"],
+                                  geo["head_dim"], geo["dtype"],
+                                  block_size=self.block_size,
+                                  n_blocks=n_blocks)
+        self._paged_statics = dict(self._statics,
+                                   block_size=self.block_size)
+        # the flash_decode static only shapes the DECODE program;
+        # prefill/chunk keep their signatures (and AOT keys) stable
+        self._decode_statics = dict(self._paged_statics,
+                                    flash_decode=self.flash_decode)
         # threaded device state (numpy until the first jit call)
         self._tok = np.zeros(self.n_slots, np.int32)
         self._cur = np.zeros(self.n_slots, np.int32)
@@ -1362,16 +1057,14 @@ class Engine:
                                        items)
             self._decode = _tp_jitted(mesh, "decode", arch, donate, items)
             self._chunk = _tp_jitted(mesh, "chunk", arch, donate, items)
-        elif self.kv_layout == "paged":
+            # baked into the shard_map programs: no call passes a static
+            self._paged_statics = self._decode_statics = {}
+        else:
             self._prefill = (_PAGED_PREFILL_DONATED if donate
                              else _PAGED_PREFILL)
             self._decode = (_PAGED_DECODE_DONATED if donate
                             else _PAGED_DECODE)
             self._chunk = _PAGED_CHUNK_DONATED if donate else _PAGED_CHUNK
-        else:
-            self._prefill = _PREFILL_DONATED if donate else _PREFILL
-            self._decode = _DECODE_DONATED if donate else _DECODE
-            self._chunk = None
         # compile ledger: which prefill bucket lengths this engine has
         # actually traced (each is one XLA program; + 1 fused decode).
         # ``compile_budget`` is the declared cap the compile-budget lint
@@ -1404,7 +1097,7 @@ class Engine:
 
     # -- tensor parallelism -----------------------------------------------
 
-    def _init_tp(self, mesh, geo, kv_layout):
+    def _init_tp(self, mesh):
         """Validate the tp geometry and commit the stacked weights to
         the mesh: column-parallel qkv/gate-up, row-parallel o-/down-proj
         (GPT: the fused qkv columns pre-permuted to device-major order),
@@ -1416,10 +1109,6 @@ class Engine:
         from ..distributed import mesh as mesh_mod
         from ..text import generation as G
 
-        if kv_layout != "paged":
-            raise ValueError(
-                "tensor-parallel serving requires kv_layout='paged' "
-                "(the sharded pool + block-table operands)")
         tp = self.tp
         if mesh is None:
             mesh = mesh_mod.build_mesh(tp=tp)
@@ -1480,8 +1169,7 @@ class Engine:
     # -- AOT program routing ----------------------------------------------
 
     def _aot_key_parts(self, kind):
-        parts = ("serving", kind, self.kv_layout, self._donate,
-                 _serving_code_token())
+        parts = ("serving", kind, self._donate, _serving_code_token())
         if self.tp > 1:
             # statics are baked into the shard_map closure (not call-site
             # kwargs), so they pin program identity here instead
@@ -1493,8 +1181,6 @@ class Engine:
         The handle is resolved once per (kind, bucket) and cached; with
         no persistent cache configured this is a plain passthrough to
         the module-level jitted program (pre-AOT behavior)."""
-        if self.tp > 1:
-            statics = {}       # baked into the shard_map program
         h = self._aot.get(hkey)
         if h is None:
             from ..aot import get_service
@@ -1565,53 +1251,38 @@ class Engine:
         vrow = jax.ShapeDtypeStruct((self._vocab,), np.float32)
         moe = () if self.metrics.moe is None else (
             jax.tree.map(sds, self.metrics.moe),)
-        if self.kv_layout == "paged":
-            # TP programs bake their statics into the shard_map closure
-            stat = {} if self.tp > 1 else self._paged_statics
-            mb = self.cache.block_tables.shape[1]
-            trow = jax.ShapeDtypeStruct((mb,), np.int32)
-            tables = jax.ShapeDtypeStruct((S, mb), np.int32)
-            for Lb in buckets:
-                ids = jax.ShapeDtypeStruct((1, int(Lb)), np.int32)
-                specs.append((
-                    "prefill", ("prefill", int(Lb)), self._prefill,
-                    (w, kc, vc, tok, cur, keys, ids, i32, i32, u32, i32,
-                     f32, trow, i32, vrow) + moe,
-                    stat, f"prefill:L{Lb}"))
+        mb = self.cache.block_tables.shape[1]
+        trow = jax.ShapeDtypeStruct((mb,), np.int32)
+        tables = jax.ShapeDtypeStruct((S, mb), np.int32)
+        for Lb in buckets:
+            ids = jax.ShapeDtypeStruct((1, int(Lb)), np.int32)
             specs.append((
-                "decode", ("decode",), self._decode,
-                (w, kc, vc, tables, tok, cur, active, keys, temps,
-                 vmasks) + moe,
-                {} if self.tp > 1 else self._decode_statics, "decode"))
-            if self.spec is not None:
-                K1 = self.spec.k + 1
-                sids = jax.ShapeDtypeStruct((1, K1), np.int32)
-                specs.append((
-                    "verify", ("verify", K1), self._verify,
-                    (w, kc, vc, keys, sids, i32, i32, trow, i32, f32,
-                     vrow),
-                    self._paged_statics, "spec.verify"))
-                specs.extend(self._spec.probe_specs(buckets))
-            if self.prefill_chunk is not None:
-                ids = jax.ShapeDtypeStruct((1, self.prefill_chunk),
-                                           np.int32)
-                specs.append((
-                    "chunk", ("chunk",), self._chunk,
-                    (w, kc, vc, tok, cur, keys, ids, i32, i32, i32, trow,
-                     i32, i32, u32, i32, f32, vrow) + moe,
-                    stat, "chunk"))
-        else:
-            for Lb in buckets:
-                ids = jax.ShapeDtypeStruct((1, int(Lb)), np.int32)
-                specs.append((
-                    "prefill", ("prefill", int(Lb)), self._prefill,
-                    (w, kc, vc, tok, cur, keys, ids, i32, i32, u32, i32,
-                     f32, vrow),
-                    self._statics, f"prefill:L{Lb}"))
+                "prefill", ("prefill", int(Lb)), self._prefill,
+                (w, kc, vc, tok, cur, keys, ids, i32, i32, u32, i32,
+                 f32, trow, i32, vrow) + moe,
+                self._paged_statics, f"prefill:L{Lb}"))
+        specs.append((
+            "decode", ("decode",), self._decode,
+            (w, kc, vc, tables, tok, cur, active, keys, temps,
+             vmasks) + moe,
+            self._decode_statics, "decode"))
+        if self.spec is not None:
+            K1 = self.spec.k + 1
+            sids = jax.ShapeDtypeStruct((1, K1), np.int32)
             specs.append((
-                "decode", ("decode",), self._decode,
-                (w, kc, vc, tok, cur, active, keys, temps, vmasks),
-                self._decode_statics, "decode"))
+                "verify", ("verify", K1), self._verify,
+                (w, kc, vc, keys, sids, i32, i32, trow, i32, f32,
+                 vrow),
+                self._paged_statics, "spec.verify"))
+            specs.extend(self._spec.probe_specs(buckets))
+        if self.prefill_chunk is not None:
+            ids = jax.ShapeDtypeStruct((1, self.prefill_chunk),
+                                       np.int32)
+            specs.append((
+                "chunk", ("chunk",), self._chunk,
+                (w, kc, vc, tok, cur, keys, ids, i32, i32, i32, trow,
+                 i32, i32, u32, i32, f32, vrow) + moe,
+                self._paged_statics, "chunk"))
         return specs
 
     def precompile_aot(self, dest_dir, buckets=None):
@@ -1738,13 +1409,12 @@ class Engine:
             raise ValueError(
                 f"prompt ({ids.shape[0]}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds max_len={self.max_len}")
-        if self.kv_layout == "paged":
-            cap = (self.cache.pool.n_blocks - 1) * self.block_size
-            if ids.shape[0] + int(max_new_tokens) + 1 > cap:
-                raise ValueError(
-                    f"prompt ({ids.shape[0]}) + max_new_tokens "
-                    f"({max_new_tokens}) can never fit the KV pool "
-                    f"({cap} token lines) — raise n_blocks")
+        cap = (self.cache.pool.n_blocks - 1) * self.block_size
+        if ids.shape[0] + int(max_new_tokens) + 1 > cap:
+            raise ValueError(
+                f"prompt ({ids.shape[0]}) + max_new_tokens "
+                f"({max_new_tokens}) can never fit the KV pool "
+                f"({cap} token lines) — raise n_blocks")
         rid = self._next_id
         self._next_id += 1
         h = RequestHandle(
@@ -1795,12 +1465,8 @@ class Engine:
         # or max_new_tokens=1) frees its slot immediately — loop so the
         # queue keeps draining into freshly freed slots
         while True:
-            if self.kv_layout == "paged":
-                popped = self.scheduler.pop_admissible(
-                    self.cache.n_free,
-                    free_tokens=self.cache.free_tokens())
-            else:
-                popped = self.scheduler.pop_admissible(self.cache.n_free)
+            popped = self.scheduler.pop_admissible(
+                self.cache.n_free, free_tokens=self.cache.free_tokens())
             if not popped:
                 return
             for h in popped:
@@ -1829,44 +1495,6 @@ class Engine:
         # exhaustion re-enters through the same path.
         k = len(h.tokens)
         n_eff = h.n_prompt + k
-        if self.kv_layout == "paged":
-            return self._admit_one_paged(h, k, n_eff)
-        slot = self.cache.alloc(h.request_id)
-        h.slot = slot
-        self._by_slot[slot] = h
-        self._temps[slot] = h.temperature
-        self._vmask[slot] = (1.0 if h.logit_mask is None
-                             else h.logit_mask)
-        Lb = self._bucket(n_eff)
-        self.buckets_seen.add(Lb)
-        ids = np.zeros((1, Lb), np.int32)
-        ids[0, :n_eff] = self._full_ids(h)
-        called = time.perf_counter()
-        _tracing.span_event("serving.queue", h._queued_t, called,
-                            cat="serving", trace_id=h.trace_id,
-                            request_id=h.request_id)
-        with _compile_scope(f"prefill:L{Lb}"):
-            out = self._run_program(
-                "prefill", ("prefill", Lb), self._prefill,
-                (self._w, self.cache.kc, self.cache.vc, self._tok,
-                 self._cur, self._keys, ids, np.int32(n_eff),
-                 np.int32(slot), np.uint32(h.seed), np.int32(k),
-                 np.float32(h.temperature),
-                 self._vmask[slot].copy()), self._statics,
-                f"prefill:L{Lb}")
-        (self.cache.kc, self.cache.vc, self._tok, self._cur,
-         self._keys, tok0) = out
-        dispatched = time.perf_counter()
-        tok0 = int(tok0)
-        self._launched(f"prefill:L{Lb}", called, dispatched,
-                       time.perf_counter(), "serving.prefill", h,
-                       tokens=n_eff, bucket=Lb, replay_k=k)
-        self.metrics.prefills += 1
-        self.cache.cur_pos[slot] = n_eff
-        self._emit(h, tok0)
-        return True
-
-    def _admit_one_paged(self, h, k, n_eff):
         full = self._full_ids(h)
         slot = self.cache.alloc(h.request_id)
         # wire block-table coverage for [0, n_eff] (prompt + replay
@@ -2138,7 +1766,7 @@ class Engine:
         """One engine iteration: expire overdue requests, admit waiting
         ones into free slots, advance ONE chunk of any in-progress
         chunked prefill, then advance every decode-active slot one token
-        with the fused decode step (paged: gathering K/V through block
+        with the fused decode step (gathering K/V through block
         tables; preempting on pool exhaustion first). Returns the number
         of requests that were decoding this step."""
         if self._condemned:
@@ -2161,26 +1789,17 @@ class Engine:
         launches = self.metrics.launches_recorded
         self._expire()
         self._admit()
-        paged = self.kv_layout == "paged"
-        if paged and self._chunking:
+        if self._chunking:
             self._chunk_tick()
-        if paged:
-            active = self._decode_active()
-            self._ensure_decode_capacity(active)
-            active = self._decode_active()     # preemption may shrink it
-        else:
-            active = self.cache.active
+        active = self._decode_active()
+        self._ensure_decode_capacity(active)
+        active = self._decode_active()     # preemption may shrink it
         n_active = int(active.sum())
-        if paged:
-            self.metrics.sample(self.cache.occupancy,
-                                self.scheduler.queue_depth,
-                                active=self.cache.n_active,
-                                pool_free=self.cache.pool.n_free,
-                                pool_total=self.cache.pool.n_blocks - 1)
-        else:
-            self.metrics.sample(self.cache.occupancy,
-                                self.scheduler.queue_depth,
-                                active=self.cache.n_active)
+        self.metrics.sample(self.cache.occupancy,
+                            self.scheduler.queue_depth,
+                            active=self.cache.n_active,
+                            pool_free=self.cache.pool.n_free,
+                            pool_total=self.cache.pool.n_blocks - 1)
         if not n_active:
             return 0
         kind = ("decode" if self.metrics.launches_recorded == launches
@@ -2210,24 +1829,15 @@ class Engine:
         """One fused decode-step invocation over ``active`` rows: every
         active slot advances exactly one token. Returns when its call
         had returned and when its tokens were on the host."""
-        paged = self.kv_layout == "paged"
         called = time.perf_counter()
         with _compile_scope("decode"):
-            if paged:
-                out = self._run_program(
-                    "decode", ("decode",), self._decode,
-                    (self._w, self.cache.kc, self.cache.vc,
-                     self.cache.block_tables.copy(), self._tok,
-                     self._cur, active, self._keys, self._temps,
-                     self._vmask.copy()) + self._moe_in(),
-                    self._decode_statics, "decode")
-            else:
-                out = self._run_program(
-                    "decode", ("decode",), self._decode,
-                    (self._w, self.cache.kc, self.cache.vc,
-                     self._tok, self._cur, active, self._keys,
-                     self._temps, self._vmask.copy()),
-                    self._decode_statics, "decode")
+            out = self._run_program(
+                "decode", ("decode",), self._decode,
+                (self._w, self.cache.kc, self.cache.vc,
+                 self.cache.block_tables.copy(), self._tok,
+                 self._cur, active, self._keys, self._temps,
+                 self._vmask.copy()) + self._moe_in(),
+                self._decode_statics, "decode")
         nxt, self.cache.kc, self.cache.vc, self._cur, self._keys = \
             self._moe_out(out)
         self._tok = nxt
@@ -2403,7 +2013,7 @@ class Engine:
                              tokens=len(h.tokens))
         if h.slot is not None:         # queued-only timeouts held no slot
             self._by_slot[h.slot] = None
-            # paged: every block the slot holds is released here —
+            # every block the slot holds is released here —
             # shared-prefix refcounts drop and private blocks (including
             # the already-written chunks of a cancelled/timed-out
             # mid-prefill request) return to the pool
@@ -2435,18 +2045,16 @@ class Engine:
     def stats(self):
         out = {**self.metrics.snapshot(),
                "n_slots": self.n_slots, "max_len": self.max_len,
-               "kv_layout": self.kv_layout,
                "active": self.cache.n_active,
                "queue_depth": self.scheduler.queue_depth,
                "kv_cache_bytes": self.cache.nbytes(),
                "prefill_buckets": sorted(self.buckets_seen),
                "chunk_program": self.chunk_used,
-               "compile_budget": self.compile_budget}
-        if self.kv_layout == "paged":
-            out.update(self.cache.pool_stats())
-            out["prefill_chunk"] = self.prefill_chunk
-            out["prefix_sharing"] = self.prefix_sharing
-            out["flash_decode"] = self.flash_decode
+               "compile_budget": self.compile_budget,
+               **self.cache.pool_stats(),
+               "prefill_chunk": self.prefill_chunk,
+               "prefix_sharing": self.prefix_sharing,
+               "flash_decode": self.flash_decode}
         if self.spec is not None:
             ar = self.metrics.acceptance_rate()
             out["speculative"] = {

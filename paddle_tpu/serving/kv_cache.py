@@ -1,20 +1,15 @@
-"""KV caches for continuous batching: slot-based and paged.
+"""The paged KV cache of continuous batching.
 
-:class:`SlotKVCache` (the PR-4 layout) keeps one fixed
-``[n_layers, n_slots, max_len, kv_heads, head_dim]`` buffer pair — every
-slot reserves worst-case ``max_len`` lines, so MEMORY (not compute) caps
-concurrency.
-
-:class:`PagedKVCache` (the default since the paging PR) breaks that
-reservation: a fixed ``[n_layers, n_blocks, block_size, kv, hd]`` pool
-plus host-side per-slot block tables (numpy int32). Slots draw
-fixed-size blocks on demand, so a request only ever holds
-``ceil(len/block_size)`` blocks, and requests sharing a system prompt
-share the full blocks of that prefix through a refcounted radix index
-(:class:`RadixIndex`) — copy-on-write on the partial tail block (the
-sharer recomputes the tail into a private block; full blocks alias).
-Shapes stay fixed (the pool and the ``[n_slots, max_blocks]`` tables are
-static-shape jit operands), so the compiled-program count is unchanged.
+:class:`PagedKVCache` holds a fixed ``[n_layers, n_blocks, block_size,
+kv, hd]`` pool plus host-side per-slot block tables (numpy int32). Slots
+draw fixed-size blocks on demand, so a request only ever holds
+``ceil(len/block_size)`` blocks and not worst-case ``max_len`` lines,
+and requests sharing a system prompt share the full blocks of that
+prefix through a refcounted radix index (:class:`RadixIndex`) —
+copy-on-write on the partial tail block (the sharer recomputes the tail
+into a private block; full blocks alias). Shapes stay fixed (the pool
+and the ``[n_slots, max_blocks]`` tables are static-shape jit operands),
+so the compiled-program count does not grow with the traffic.
 
 Block 0 is a reserved TRASH block: it is never allocated, and in-program
 scatter writes that must not land anywhere real (bucket padding, shared
@@ -34,75 +29,6 @@ import heapq
 import numpy as np
 
 TRASH_BLOCK = 0   # reserved scatter target for masked writes, never allocated
-
-
-class SlotKVCache:
-    """Fixed-shape per-layer KV slabs plus a host-side slot allocator."""
-
-    def __init__(self, n_layers, n_slots, max_len, kv_heads, head_dim,
-                 dtype):
-        if n_slots < 1:
-            raise ValueError("n_slots must be >= 1")
-        if max_len < 2:
-            raise ValueError("max_len must be >= 2")
-        self.n_layers = int(n_layers)
-        self.n_slots = int(n_slots)
-        self.max_len = int(max_len)
-        self.kv_heads = int(kv_heads)
-        self.head_dim = int(head_dim)
-        self.dtype = np.dtype(dtype)
-        shape = (self.n_layers, self.n_slots, self.max_len, self.kv_heads,
-                 self.head_dim)
-        # plain numpy zeros: the first jit call device-puts them, so cache
-        # construction itself never compiles an XLA program (the serving
-        # compile budget is exactly n_prefill_buckets + 1)
-        self.kc = np.zeros(shape, self.dtype)
-        self.vc = np.zeros(shape, self.dtype)
-        # host mirrors of per-slot state (device copies live inside the
-        # engine's threaded arrays)
-        self.cur_pos = np.zeros(self.n_slots, np.int32)
-        self.active = np.zeros(self.n_slots, bool)
-        self._free = collections.deque(range(self.n_slots))
-        self._owner = [None] * self.n_slots   # request_id per slot
-
-    @property
-    def n_free(self):
-        return len(self._free)
-
-    @property
-    def n_active(self):
-        return int(self.active.sum())
-
-    @property
-    def occupancy(self):
-        return self.n_active / self.n_slots
-
-    def alloc(self, request_id=None):
-        """Claim the lowest free slot (FIFO over frees) or return None."""
-        if not self._free:
-            return None
-        slot = self._free.popleft()
-        self.active[slot] = True
-        self.cur_pos[slot] = 0
-        self._owner[slot] = request_id
-        return slot
-
-    def free(self, slot):
-        """Evict: slot becomes reusable; device lines are NOT cleared —
-        a later occupant overwrites each line before it becomes
-        attendable (causal bound), so stale KV is never read."""
-        if not self.active[slot]:
-            raise ValueError(f"slot {slot} is not active")
-        self.active[slot] = False
-        self._owner[slot] = None
-        self._free.append(slot)
-
-    def owner(self, slot):
-        return self._owner[slot]
-
-    def nbytes(self):
-        return 2 * self.n_layers * self.n_slots * self.max_len \
-            * self.kv_heads * self.head_dim * self.dtype.itemsize
 
 
 # ---------------------------------------------------------------------------
@@ -314,12 +240,10 @@ class RadixIndex:
 class PagedKVCache:
     """Paged KV pool + host-side slot/block bookkeeping.
 
-    Exposes the same slot-level surface as :class:`SlotKVCache`
-    (``alloc``/``free``/``active``/``cur_pos``/``n_free``/``occupancy``)
-    so the engine, supervisor and tests treat both layouts uniformly;
-    the paged extras are the block tables (a static-shape
-    ``[n_slots, max_blocks]`` int32 jit operand), the refcounted pool
-    and the radix prefix index.
+    A slot-level surface (``alloc``/``free``/``active``/``cur_pos``/
+    ``n_free``/``occupancy``) for the engine and the supervisor, and
+    under it the block tables (a static-shape ``[n_slots, max_blocks]``
+    int32 jit operand), the refcounted pool and the radix prefix index.
     """
 
     def __init__(self, n_layers, n_slots, max_len, kv_heads, head_dim,
@@ -339,9 +263,9 @@ class PagedKVCache:
         self.block_size = int(block_size)
         self.max_blocks = -(-self.max_len // self.block_size)
         if n_blocks is None:
-            # worst-case capacity parity with SlotKVCache (+ trash):
-            # paging can never run dry under slot-equivalent load;
-            # size it DOWN explicitly to bank the memory win
+            # worst case, every slot at max_len (+ trash): the pool can
+            # never run dry; size it DOWN explicitly to bank the memory
+            # win
             n_blocks = self.n_slots * self.max_blocks + 1
         self.pool = BlockPool(n_blocks)
         self.radix = RadixIndex(self.block_size)
@@ -361,7 +285,7 @@ class PagedKVCache:
         # pool telemetry for serving metrics
         self.low_watermark = self.pool.n_free
 
-    # -- slot surface (SlotKVCache-compatible) ----------------------------
+    # -- slot surface ------------------------------------------------------
 
     @property
     def n_free(self):

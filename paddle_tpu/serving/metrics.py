@@ -4,7 +4,7 @@ Per-request: TTFT (submit -> first token out of prefill), inter-token
 latencies, tokens/sec. Per-engine: slot occupancy and queue depth sampled
 every decode step, admission/eviction counters. Snapshots surface through
 ``paddle_tpu.profiler.serving_counters()`` (the same counter plumbing as
-the eager dispatch cache) and feed tools/bench_serving.py's JSON ledger.
+the eager dispatch cache).
 
 Per-engine too, always on: the ``perf_counter`` stamps the engine takes
 inside ``Engine.step()`` and around every program it launches, as

@@ -5,10 +5,10 @@ Latency-shaped traffic pays one fused target step per token; speculative
 decoding (Leviathan et al., arXiv 2211.17192) spends draft flops to
 collapse up to ``k`` tokens into ONE verify pass. Two draft modes:
 
-* ``SpecConfig(draft=model)`` — a small same-family model with its own
-  per-slot KV cache proposes ``k`` greedy tokens per round (k+1 fused
-  draft decode steps, so the draft KV never develops holes on a full
-  accept);
+* ``SpecConfig(draft=model)`` — a small same-family model with a paged
+  KV pool of its own (a fixed run of blocks a slot) proposes ``k``
+  greedy tokens per round (k+1 fused draft decode steps, so the draft
+  KV never develops holes on a full accept);
 * ``SpecConfig(draft="ngram")`` — a draft-FREE variant in the spirit of
   lookahead/prompt-lookup decoding (Fu et al., arXiv 2402.02057): a
   host-side n-gram index over each request's prompt + emitted tokens
@@ -145,15 +145,16 @@ class NgramProposer:
 
 
 class _ModelDraft:
-    """Same-family small-model draft with its own slot-layout KV cache
-    (one [layers, n_slots, max_len, kv, hd] slab pair, tracking the
-    target engine's slots one-for-one — no separate allocator). The
-    draft runs GREEDY: acceptance compares proposals against the
-    target's chain-sampled tokens, so draft sampling would only add
-    noise. Draft programs reuse the engine's module-level slot-layout
-    prefill/decode jits (with the draft's own weight shapes — they count
-    toward the compile budget as ``draft_buckets_seen`` + one draft
-    decode program)."""
+    """Same-family small-model draft with a paged pool of its own,
+    tracking the target engine's slots one-for-one: slot ``s`` owns the
+    fixed run of ``mb = ceil(max_len / block_size)`` blocks after the
+    trash block, ``1 + s*mb .. (s+1)*mb`` — an identity block table, no
+    allocator and no radix. The draft runs GREEDY: acceptance compares
+    proposals against the target's chain-sampled tokens, so draft
+    sampling would only add noise. Draft programs are the engine's
+    module-level paged prefill and decode jits (with the draft's own
+    weight shapes — they count toward the compile budget as
+    ``draft_buckets_seen`` + one draft decode program)."""
 
     def __init__(self, engine, model):
         from .engine import _make_arch
@@ -172,9 +173,14 @@ class _ModelDraft:
         self.engine = engine
         self._w = w
         # greedy statics: the draft's sampled path is never used
-        self._statics = dict(hp, do_sample=False, top_k=0, top_p=None)
-        S, T = engine.n_slots, engine.max_len
-        shape = (geo["n_layers"], S, T, geo["kv_heads"], geo["head_dim"])
+        self._statics = dict(hp, do_sample=False, top_k=0, top_p=None,
+                             block_size=engine.block_size)
+        self._decode_statics = dict(self._statics, flash_decode=False)
+        S, bs, mb = engine.n_slots, engine.block_size, engine.cache.max_blocks
+        self.tables = (1 + mb * np.arange(S)[:, None]
+                       + np.arange(mb)[None]).astype(np.int32)
+        shape = (geo["n_layers"], 1 + S * mb, bs, geo["kv_heads"],
+                 geo["head_dim"])
         self.kc = np.zeros(shape, geo["dtype"])
         self.vc = np.zeros(shape, geo["dtype"])
         self.tok = np.zeros(S, np.int32)
@@ -190,8 +196,8 @@ class _ModelDraft:
     def _programs(self):
         from . import engine as E
         if self.engine._donate:
-            return E._PREFILL_DONATED, E._DECODE_DONATED
-        return E._PREFILL, E._DECODE
+            return E._PAGED_PREFILL_DONATED, E._PAGED_DECODE_DONATED
+        return E._PAGED_PREFILL, E._PAGED_DECODE
 
     def on_admit(self, h, full):
         """Prefill the draft's KV for the slot's full token history
@@ -213,6 +219,7 @@ class _ModelDraft:
                 (self._w, self.kc, self.vc, self.tok, self.cur,
                  self.keys, ids, np.int32(n_eff), np.int32(slot),
                  np.uint32(0), np.int32(0), np.float32(1.0),
+                 self.tables[slot].copy(), np.int32(0),
                  eng._vmask[slot].copy()),
                 self._statics, f"spec.draft:L{Lb}")
         self.kc, self.vc, tok, self.cur, self.keys, _ = out
@@ -233,9 +240,12 @@ class _ModelDraft:
         eng = self.engine
         if not cand:
             return {}
-        active = np.zeros(eng.n_slots, bool)
-        for h, _ in cand:
-            active[h.slot] = True
+        # the line each row writes at the first step; a row sits out the
+        # steps that would write past ``max_len`` (its run of blocks ends
+        # there, and the engine takes no proposal that far: ``k_cap``)
+        pos = np.full(eng.n_slots, eng.max_len, np.int64)
+        slots = [h.slot for h, _ in cand]
+        pos[slots] = np.asarray(self.cur)[slots]
         _, decode = self._programs()
         outs = {h.slot: [] for h, _ in cand}
         k = eng.spec.k
@@ -244,9 +254,10 @@ class _ModelDraft:
                 called = time.perf_counter()
                 out = eng._run_program(
                     "draft_decode", ("draft_decode",), decode,
-                    (self._w, self.kc, self.vc, self.tok, self.cur,
-                     active, self.keys, self.temps, eng._vmask.copy()),
-                    self._statics, "spec.draft")
+                    (self._w, self.kc, self.vc, self.tables.copy(),
+                     self.tok, self.cur, pos + i < eng.max_len,
+                     self.keys, self.temps, eng._vmask.copy()),
+                    self._decode_statics, "spec.draft")
                 nxt, self.kc, self.vc, self.cur, self.keys = out
                 self.tok = nxt
                 dispatched = time.perf_counter()
@@ -297,6 +308,7 @@ class _ModelDraft:
         u32 = jax.ShapeDtypeStruct((), np.uint32)
         f32 = jax.ShapeDtypeStruct((), np.float32)
         vrow = jax.ShapeDtypeStruct((eng._vocab,), np.float32)
+        trow = sds(self.tables[0])
         prefill, decode = self._programs()
         specs = []
         for Lb in buckets:
@@ -304,12 +316,12 @@ class _ModelDraft:
             specs.append((
                 "draft_prefill", ("draft_prefill", int(Lb)), prefill,
                 (w, kc, vc, tok, cur, keys, ids, i32, i32, u32, i32, f32,
-                 vrow),
+                 trow, i32, vrow),
                 self._statics, f"spec.draft:L{Lb}"))
         specs.append((
             "draft_decode", ("draft_decode",), decode,
-            (w, kc, vc, tok, cur, act, keys, temps, vm),
-            self._statics, "spec.draft"))
+            (w, kc, vc, sds(self.tables), tok, cur, act, keys, temps, vm),
+            self._decode_statics, "spec.draft"))
         return specs
 
 

@@ -5,8 +5,9 @@ jit.to_static traces.
 
 Budget note: tier-1 runs close to its wall-clock cap, so the resnet18
 pair is built once per module and the heavyweight zoo variants
-(mobilenet end-to-end) are marked slow; tools/check_hlo_layout.py and
-tools/bench_conv.py carry the full-size evidence.
+(mobilenet end-to-end) are marked slow; tools/check_hlo_layout.py
+carries the full-size transpose counts (no conv cell in the benchmark:
+PERF.md has no timing of this path).
 """
 import numpy as np
 import pytest

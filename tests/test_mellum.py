@@ -222,7 +222,6 @@ def test_a_dense_engine_reports_no_moe():
     (dict(tp=2), "tp > 1"),
     (dict(speculative=SpecConfig(k=2)), "speculative"),
     (dict(flash_decode=True), "flash_decode"),
-    (dict(kv_layout="slot"), "kv_layout='slot'"),
 ])
 def test_engine_refuses_what_this_model_cannot_have(model, kwargs, names):
     with pytest.raises(ValueError, match="cannot serve MellumForCausalLM"

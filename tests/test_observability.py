@@ -491,8 +491,8 @@ def test_obs_dump_cli_smoke(tmp_path, capsys):
 # ---------------------------------------------------------------------------
 
 def test_disabled_overhead_smoke():
-    """Not a benchmark (tools/bench_eager.py vs its pre-PR ledger is
-    the real gate) — just the structural facts: disabled tracing takes
+    """Not a benchmark (PERF.md has what stamping costs a serving
+    step) — just the structural facts: disabled tracing takes
     the one-branch fast path, allocates nothing into the ring, and
     100k guarded checks stay well under a second on the 1-core CI."""
     import time as _time

@@ -14,7 +14,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.serving import (Engine, EngineOverloaded, FIFOScheduler,
-                                SlotKVCache, ledger)
+                                ledger)
 from paddle_tpu.text.models.llama import LLAMA_TINY, LlamaForCausalLM
 
 CFG = dataclasses.replace(LLAMA_TINY, dtype="float32", num_hidden_layers=2)
@@ -168,22 +168,6 @@ def test_streaming_callbacks_and_metrics_ledger(model):
     after = profiler.serving_counters()
     assert after["tokens_generated"] - before["tokens_generated"] == 4
     assert after["requests_completed"] - before["requests_completed"] == 1
-
-
-def test_slot_kv_cache_allocator():
-    c = SlotKVCache(n_layers=2, n_slots=2, max_len=8, kv_heads=2,
-                    head_dim=4, dtype=np.float32)
-    a = c.alloc("r0")
-    b = c.alloc("r1")
-    assert {a, b} == {0, 1} and c.alloc() is None
-    assert c.n_free == 0 and c.occupancy == 1.0
-    c.free(a)
-    with pytest.raises(ValueError):
-        c.free(a)                      # double free
-    assert c.alloc("r2") == a          # reuse
-    assert c.owner(a) == "r2" and c.owner(b) == "r1"
-    assert c.kc.shape == (2, 2, 8, 2, 4)
-    assert c.nbytes() == 2 * 2 * 2 * 8 * 2 * 4 * 4
 
 
 def test_submit_validation(model):

@@ -1,13 +1,13 @@
 """Paged, prefix-shared KV cache + chunked prefill (paddle_tpu.serving).
 
 The paging contract: block-table indirection must be invisible in the
-tokens — the paged engine (the default) stays token-identical to batch
-``generate()`` and the slot engine through sharing, chunking, pool
-preemption, cancellation and supervisor replay, while memory-per-request
-drops from worst-case ``max_len`` to ``ceil(len/block_size)`` blocks
-with full-block prefix dedup. Kept slim for the tier-1 budget: one tiny
-module-scope model, block_size=8 geometry shared across tests, the soak
-marked slow; the offered-load A/B ledger lives in tools/bench_serving.py.
+tokens — the engine stays token-identical to batch ``generate()``
+through sharing, chunking, pool preemption, cancellation and supervisor
+replay, while memory-per-request is ``ceil(len/block_size)`` blocks and
+not worst-case ``max_len`` lines, with full-block prefix dedup. Kept
+slim for the tier-1 budget: one tiny module-scope model, block_size=8
+geometry shared across tests, the soak marked slow; what the engine
+measures under load is in PERF.md (the serve cells).
 """
 import dataclasses
 import time
@@ -146,9 +146,9 @@ def test_scheduler_free_tokens_watermark_and_requeue():
 # engine: parity, sharing, chunking, preemption, churn
 # ---------------------------------------------------------------------------
 
-def test_paged_greedy_parity_staggered_and_slot_ab(model):
-    """Paged engine (default layout) token-identical to generate() AND
-    to the slot engine on the same staggered workload."""
+def test_paged_greedy_parity_staggered(model):
+    """The engine is token-identical to generate() on a staggered
+    workload."""
     prompts = _prompts([5, 9, 5, 9, 5], seed=1)
 
     def drive(eng):
@@ -162,11 +162,7 @@ def test_paged_greedy_parity_staggered_and_slot_ab(model):
         eng.drain()
         return [list(h.tokens) for h in hs]
 
-    paged = drive(Engine(model, **GEO))
-    slot = drive(Engine(model, n_slots=2, max_len=64, min_prompt_bucket=4,
-                        kv_layout="slot"))
-    assert paged == slot
-    for p, toks in zip(prompts, paged):
+    for p, toks in zip(prompts, drive(Engine(model, **GEO))):
         np.testing.assert_array_equal(np.asarray(toks, np.int32),
                                       _want(model, p, 4))
 
@@ -374,8 +370,6 @@ def test_paged_counters_in_profiler_plumbing(model, capsys):
 
 
 def test_paged_validation_errors(model):
-    with pytest.raises(ValueError):
-        Engine(model, kv_layout="banana")
     with pytest.raises(ValueError):
         Engine(model, **GEO, prefill_chunk=12)      # not block-aligned
     eng = Engine(model, n_slots=2, max_len=64, min_prompt_bucket=4,

@@ -237,8 +237,9 @@ def test_pool_as_carry_is_bitwise_the_xs_ys_loop(arch_name, kind):
 
 @pytest.mark.parametrize("kind", ("decode", "chunk"))
 def test_tp_programs_carry_the_pool_too(kind):
-    """The tensor-parallel copies, traced inside their ``shard_map`` over
-    two (virtual) devices: the local pool shard rides the same carry."""
+    """The same programs with ``tp=2``, traced inside their ``shard_map``
+    over two (virtual) devices: the local pool shard rides the same
+    carry."""
     from jax.sharding import Mesh
     tp = 2
     if len(jax.devices()) < tp:

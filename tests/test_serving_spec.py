@@ -85,9 +85,8 @@ def test_spec_validation(model):
         SpecConfig(ngram_min=0)
     with pytest.raises(TypeError):
         Engine(model, speculative=4, **GEO)
-    with pytest.raises(ValueError):
-        Engine(model, speculative=SpecConfig(), kv_layout="slot",
-               n_slots=2, max_len=64)
+    with pytest.raises(ValueError, match="tp > 1"):
+        Engine(model, speculative=SpecConfig(), tp=2, **GEO)
     eng = Engine(model, **GEO)
     with pytest.raises(ValueError):          # wrong mask shape
         eng.submit(np.asarray([1, 2, 3], np.int32),
@@ -151,6 +150,34 @@ def test_model_draft_token_identity_and_step_ratio(model):
     assert spec.draft_decode_used and spec.draft_buckets_seen
     st = spec.stats()["speculative"]
     assert st["draft"] == "model" and st["verify_used"]
+
+
+def test_model_draft_pool_stays_inside_its_slots_blocks(model):
+    """The draft's pool has no allocator: slot ``s`` owns the run of
+    blocks ``1 + s*mb .. (s+1)*mb``. Requests that run to ``max_len``
+    in slots 0 and 1 (the draft's k+1 steps would write past it) leave
+    slot 2's run and everything past a slot's own last line as zero."""
+    geo = dict(GEO, n_slots=3, max_len=32)
+    k, bs, mb = 4, geo["block_size"], 32 // geo["block_size"]
+    spec = Engine(model, speculative=SpecConfig(draft=model, k=k), **geo)
+    draft = spec._spec
+    assert draft.kc.shape[1] == 1 + 3 * mb
+    assert draft.tables.tolist() == [
+        list(range(1 + s * mb, 1 + (s + 1) * mb)) for s in range(3)]
+    rng = np.random.default_rng(9)
+    reqs = [(rng.integers(0, V, (n,)).astype(np.int32),
+             dict(max_new_tokens=32 - n)) for n in (6, 21)]
+    base = _drive(Engine(model, **geo), reqs, stagger=False)
+    assert _drive(spec, reqs, stagger=False) == base
+    assert spec.draft_decode_used
+    for pool in (np.asarray(draft.kc), np.asarray(draft.vc)):
+        lines = pool.reshape(pool.shape[0], -1, *pool.shape[3:])
+        written = lines.any(axis=(0, 2, 3))[bs:].reshape(3, mb * bs)
+        assert written[0].any() and written[1].any()
+        assert not written[2].any()
+        # slot 1's request wrote lines 0..31 of its own run and no line
+        # of slot 0's: both runs are whole where the sequences ended
+        assert written[0][:31].all() and written[1][:31].all()
 
 
 def test_zero_accept_worst_case(model):

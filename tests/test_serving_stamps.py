@@ -38,8 +38,6 @@ GEO = dict(n_slots=2, max_len=64, min_prompt_bucket=4, block_size=8)
 LAYOUTS = {
     "paged": GEO,
     "paged_chunked": dict(GEO, prefill_chunk=8),
-    "slot": dict(n_slots=2, max_len=64, min_prompt_bucket=4,
-                 kv_layout="slot"),
 }
 SLOW = 0.02
 
@@ -212,7 +210,7 @@ def test_a_slow_token_fetch_shows_in_itl_and_in_the_fetch_phase(model):
 
 
 @pytest.mark.parametrize("layout,kind,index", [
-    ("paged", "prefill", 5), ("slot", "prefill", 5),
+    ("paged", "prefill", 5),
     ("paged_chunked", "chunk", 5)])
 def test_a_slow_first_token_shows_in_the_prefill_span(model, layout, kind,
                                                       index):

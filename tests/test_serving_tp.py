@@ -23,6 +23,7 @@ import jax
 import paddle_tpu as paddle
 from paddle_tpu import analysis
 from paddle_tpu.serving import Engine
+from paddle_tpu.text.models.gpt import GPT_TINY, GPTForCausalLM
 from paddle_tpu.text.models.llama import LLAMA_TINY, LlamaForCausalLM
 
 CFG = dataclasses.replace(LLAMA_TINY, dtype="float32", num_hidden_layers=2)
@@ -42,6 +43,13 @@ def model():
     return m
 
 
+@pytest.fixture(scope="module")
+def models(model):
+    gpt = GPTForCausalLM(GPT_TINY)
+    gpt.eval()
+    return {"llama": model, "gpt": gpt}
+
+
 def _prompts(lens, seed=0):
     rng = np.random.default_rng(seed)
     return [rng.integers(0, CFG.vocab_size, (n,)).astype(np.int32)
@@ -53,13 +61,75 @@ def _tokens(handles):
 
 
 # ---------------------------------------------------------------------------
+# one program, traced with tp == 1 or inside shard_map
+# ---------------------------------------------------------------------------
+
+_COLLECTIVES = ("collective_permute", "collective-permute", "all_gather",
+                "all-gather", "all_reduce", "all-reduce")
+
+
+def _lowered(eng, kind):
+    """The text of one of the engine's programs, lowered from the
+    operands and statics the engine calls it with."""
+    for k, _, jitted, args, statics, _ in eng._aot_probe_specs(buckets=[8]):
+        if k == kind:
+            return jitted.lower(*args, **statics).as_text()
+    raise KeyError(kind)
+
+
+@pytest.mark.parametrize("kind", ("prefill", "decode", "chunk"))
+@pytest.mark.parametrize("arch", ("llama", "gpt"))
+def test_one_device_program_holds_no_collective(models, arch, kind):
+    """``tp`` is a static of the paged programs: an engine on one device
+    passes none, and what it traces holds no collective; the same
+    function inside ``shard_map`` with ``tp=2`` holds the rings."""
+    one = Engine(models[arch], prefill_chunk=8, **GEO)
+    assert "tp" not in one._decode_statics
+    text = _lowered(one, kind)
+    assert not [op for op in _COLLECTIVES if op in text]
+    two = Engine(models[arch], tp=2, prefill_chunk=8, **GEO)
+    assert dict(two._tp_statics_items)["tp"] == 2
+    assert "collective_permute" in _lowered(two, kind)
+
+
+@pytest.mark.parametrize("arch", ("llama", "gpt"))
+def test_tp2_prefill_writes_the_one_device_pool_lines(models, arch):
+    """The sharded prefill writes the pool the one-device way, as rows
+    of the pool flat over layers: gathered over the mesh, the lines a
+    prompt wrote are the one-device pool's, and no other line outside
+    the trash block was touched."""
+    prompt = _prompts((11,), seed=4)[0]          # bucket 16: 5 pad rows
+    pools = {}
+    for tp in (1, 2):
+        eng = Engine(models[arch], tp=tp, **GEO)
+        h = eng.submit(prompt, max_new_tokens=2)     # prefills inside
+        j = np.arange(len(prompt))
+        rows = (eng.cache.block_tables[h.slot][j // eng.block_size]
+                * eng.block_size + j % eng.block_size)
+        flat = (eng.cache.n_layers, -1, eng.cache.kv_heads,
+                eng.cache.head_dim)
+        pools[tp] = (rows, np.asarray(eng.cache.kc).reshape(flat),
+                     np.asarray(eng.cache.vc).reshape(flat))
+    rows, k1, v1 = pools[1]
+    rows2, k2, v2 = pools[2]
+    assert np.array_equal(rows, rows2)
+    untouched = np.setdiff1d(np.arange(GEO["block_size"], k1.shape[1]), rows)
+    for one, two in ((k1, k2), (v1, v2)):
+        assert one[:, rows].any(axis=(2, 3)).all()
+        # np.allclose's rtol as elsewhere in this file; the ring sums a
+        # row-parallel product in another order, so values near zero
+        # differ by a float32 rounding of the largest term, not of theirs
+        assert np.allclose(two[:, rows], one[:, rows], atol=1e-5)
+        assert not one[:, untouched].any() and not two[:, untouched].any()
+
+
+# ---------------------------------------------------------------------------
 # construction contract
 # ---------------------------------------------------------------------------
 
 def test_tp_validation(model):
-    with pytest.raises(ValueError, match="kv_layout"):
-        Engine(model, kv_layout="slot", tp=2, **{k: v
-               for k, v in GEO.items() if k != "block_size"})
+    with pytest.raises(ValueError, match="flash_decode"):
+        Engine(model, flash_decode=True, tp=2, **GEO)
     with pytest.raises(ValueError, match="does not divide"):
         Engine(model, tp=3, **GEO)        # 8 heads / 4 kv not divisible
     with pytest.raises(ValueError, match="mesh"):

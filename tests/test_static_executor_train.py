@@ -69,8 +69,7 @@ class TestCompiledTrainLoop:
         """(a) the classic fluid loop: minimize + repeated exe.run.
         The first fetched loss (pure forward, fresh params) must match
         the eager op-by-op replay bitwise; post-update losses may drift
-        by fusion ULPs only (tools/bench_static_executor.py --train
-        asserts full bitwise equality on its pinned config)."""
+        by fusion ULPs only."""
         xs, ys = _make_regression()
 
         def sgd(params):

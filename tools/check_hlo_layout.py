@@ -13,7 +13,7 @@ layout assignment is the compiler's business and is reported separately):
 
 Exits nonzero when a budget is exceeded, so the conv pipeline cannot
 silently regress to per-op transposes. Run with --json for a ledger
-line (tools/bench_conv.py embeds the same counts next to its timings).
+line.
 
 Usage: JAX_PLATFORMS=cpu python tools/check_hlo_layout.py [--json]
 """

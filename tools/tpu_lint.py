@@ -9,7 +9,7 @@ and gate on severity:
     JAX_PLATFORMS=cpu python tools/tpu_lint.py --self --fail-on=high
 
     # lint specific files/dirs
-    python tools/tpu_lint.py paddle_tpu/serving tools/bench_serving.py
+    python tools/tpu_lint.py paddle_tpu/serving tools/chaos_serve.py
 
     # audit compiled demo programs (findings are machine-readable)
     JAX_PLATFORMS=cpu python tools/tpu_lint.py --audit resnet18 \
