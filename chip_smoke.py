@@ -377,14 +377,11 @@ def _compare(model, prompts, got, want, label, *, exact=False):
                               zip(prompts, got, want)) if g != w]}
 
 
-def _engine_pass(model, prompts, gens, want, builds, label, *,
-                 shares_programs=False, **engine_kw):
+def _engine_pass(model, prompts, gens, want, builds, label, **engine_kw):
     """One engine, gone when this returns: build it, serve ``prompts``
     twice, compare with ``want``, count its programs — buckets + decode
-    (+ chunk), or the decode alone where ``shares_programs`` says an
-    earlier engine of the same geometry already built the module-level
-    prefill programs — and say what its lowered decode program holds.
-    Returns what it saw, and the tokens."""
+    (+ chunk) — and say what its lowered decode program holds. Returns
+    what it saw, and the tokens."""
     from paddle_tpu.analysis.engine_support import lower_decode_program
     from paddle_tpu.serving import Engine
 
@@ -401,8 +398,7 @@ def _engine_pass(model, prompts, gens, want, builds, label, *,
     rebuilt = builds.count
     st = engine.stats()
     decode_text = lower_decode_program(engine)
-    expected = (1 if shares_programs else
-                len(engine.buckets_seen) + 1 + int(engine.chunk_used))
+    expected = len(engine.buckets_seen) + 1 + int(engine.chunk_used)
     info = {"engine": label,
             "vs_generate": _compare(model, prompts, got, want, label,
                                     exact=engine_kw.get("do_sample", False)),
@@ -434,9 +430,10 @@ def _engine_pass(model, prompts, gens, want, builds, label, *,
 def run_server(cfg, *, max_len, prefill_chunk, prefix, tails, solo, long,
                max_new, seed, builds, platform, tp=1):
     """Engines over one model against ``model.generate()``: greedy with
-    paged KV, chunked prefill and prefix sharing; sampled; then greedy
-    again with flash_decode (tp == 1) or tensor-parallel (tp > 1), which
-    is also held against the first engine's tokens."""
+    paged KV, chunked prefill and prefix sharing (its decode program
+    holds the paged-attention kernel on a TPU and nowhere else);
+    sampled; and, with ``tp > 1``, greedy again tensor-parallel, which is
+    also held against the first engine's tokens."""
     import paddle_tpu as paddle
     from paddle_tpu.text.models.llama import LlamaForCausalLM
 
@@ -470,32 +467,27 @@ def run_server(cfg, *, max_len, prefill_chunk, prefix, tails, solo, long,
     info["engines"].append(e)
     check(e["chunk_program"], "the long prompt did not take chunked prefill")
     check(e["prefix_hit_tokens"] > 0, "no prompt token came from the radix")
-    check(not e["kernel_in_decode"] and not e["ring_in_decode"],
-          "the gathered one-device decode program holds a kernel or a ring")
+    check(e["kernel_in_decode"] == (platform == "tpu"),
+          f"tpu_custom_call in the one-device decode program: "
+          f"{e['kernel_in_decode']} on {platform}")
+    check(not e["ring_in_decode"],
+          "the one-device decode program holds a ring")
 
     info["engines"].append(_engine_pass(
         model, prompts[:2], sampled, want_sampled, builds, "sampled",
         **geometry, **sample_kw)[0])
 
-    if tp == 1:
-        label, kw = "flash_decode", dict(flash_decode=True,
-                                         shares_programs=True)
-    else:
-        label, kw = f"tp={tp}", dict(tp=tp)
-    e, got = _engine_pass(model, prompts, greedy, want_greedy, builds, label,
-                          **geometry, **kw)
-    # engine against engine, directly: two near-ties with generate() at
-    # different places are not yet one with each other
-    e["vs_one_device_engine"] = _compare(
-        model, prompts, got, got_greedy, f"{label} vs greedy engine")
-    if tp == 1:
-        check(e["kernel_in_decode"] == (platform == "tpu"),
-              f"tpu_custom_call in the flash_decode decode program: "
-              f"{e['kernel_in_decode']} on {platform}")
-    else:
+    if tp > 1:
+        label = f"tp={tp}"
+        e, got = _engine_pass(model, prompts, greedy, want_greedy, builds,
+                              label, **geometry, tp=tp)
+        # engine against engine, directly: two near-ties with generate()
+        # at different places are not yet one with each other
+        e["vs_one_device_engine"] = _compare(
+            model, prompts, got, got_greedy, f"{label} vs greedy engine")
         check(e["ring_in_decode"],
               "no collective_permute in the tensor-parallel decode program")
-    info["engines"].append(e)
+        info["engines"].append(e)
     info["total_s"] = round(time.perf_counter() - t0, 2)
     return info
 
@@ -523,8 +515,8 @@ def _kernel_cases(cfg, seqlen, max_len, seed):
     from paddle_tpu.nn.functional.attention import _xla_sdpa
     from paddle_tpu.nn.quant import quantize_int8
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
-    from paddle_tpu.ops.pallas.flash_decode import (flash_decode,
-                                                    flash_decode_reference)
+    from paddle_tpu.ops.pallas.paged_attention import (gathered,
+                                                       paged_attention)
     from paddle_tpu.ops.pallas.fused_ce import (fused_ce_loss,
                                                 fused_ce_reference)
     from paddle_tpu.ops.pallas.int8_matmul import (_quant_rows, int8_linear)
@@ -559,13 +551,14 @@ def _kernel_cases(cfg, seqlen, max_len, seed):
     # the engine's pool: n_slots * max_len / block_size blocks + trash
     slots, bs = 8, 16
     mb = max_len // bs
-    for n_kv in (cfg.num_key_value_heads, 8):
+    for n_kv, window in ((cfg.num_key_value_heads, 0), (8, 0), (4, 96)):
         pool = (slots * mb + 1, bs, n_kv, hd)
-        yield (f"flash_decode.n_kv{n_kv}", flash_decode,
-               flash_decode_reference,
+        yield (f"paged_attention.n_kv{n_kv}.window{window}",
+               paged_attention, gathered,
                (normal((slots, H, hd)), normal(pool), normal(pool),
                 jnp.asarray(rng.integers(1, pool[0], (slots, mb)), jnp.int32),
-                jnp.asarray(rng.integers(0, mb * bs, (slots,)), jnp.int32)))
+                jnp.asarray(rng.integers(0, mb * bs, (slots,)), jnp.int32),
+                jnp.int32(window)))
 
     ce = (normal((seqlen, hidden)), normal((hidden, vocab), 0.02),
           jnp.asarray(rng.integers(0, vocab, (seqlen,)), jnp.int32))

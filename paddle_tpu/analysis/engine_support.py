@@ -22,4 +22,4 @@ def lower_decode_program(engine) -> str:
             jnp.asarray(engine._cur), engine.cache.active.copy(),
             jnp.asarray(engine._keys), engine._temps.copy(),
             jnp.asarray(engine._vmask)) + engine._moe_in()
-    return engine._decode.lower(*args, **engine._decode_statics).as_text()
+    return engine._decode.lower(*args, **engine._paged_statics).as_text()

@@ -452,10 +452,10 @@ def _fp64_ast(sf):
 #: pallas_call): tile choices at these call sites belong to the tuner
 _TUNED_KERNEL_CALLS = {
     "flash_attention", "int8_matmul_rescale", "int8_linear",
-    "flash_decode", "ragged_group_matmul", "ragged_dot",
+    "ragged_group_matmul", "ragged_dot",
     "fused_ce_stats", "fused_ce_loss", "sharded_vocab_ce", "pallas_call",
 }
-_TILE_KWARG_RE = re.compile(r"^(block_[a-z0-9]+|kv_heads_per_step)$")
+_TILE_KWARG_RE = re.compile(r"^block_[a-z0-9]+$")
 
 
 def _is_int_literal(node):

@@ -8,10 +8,32 @@ strings ("tpu", "tpu:0", "cpu", "gpu:0") and maps them onto jax devices.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 
 _current_device: str = "tpu"
+
+
+def _canonical_source_paths():
+    """Lowered programs name source files relative to the directory that
+    holds this package, not by the checkout's absolute path.
+
+    jax keeps file names out of its compile-cache key, but a Pallas kernel
+    is lowered apart and embedded whole, locations and all, in its
+    program: with absolute paths in it, a program that holds a kernel (the
+    train step's ``flash_attention``, every decode program's
+    ``paged_attention``) is compiled again from every checkout that is not
+    at the path that wrote the cache entry. jax's own canonicalisation
+    removes the prefix; a caller who has set one keeps theirs."""
+    if jax.config.jax_hlo_source_file_canonicalization_regex is None:
+        root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          "^" + re.escape(root + os.sep))
+
+
+_canonical_source_paths()
 
 
 def _platform_of(device: str) -> str:
@@ -58,9 +80,11 @@ def use_compile_cache() -> str:
 
     ``JAX_COMPILATION_CACHE_DIR`` wins: jax reads it itself, so nothing is
     set in code. Otherwise the cache is ``<repo root>/.jax_cache`` as an
-    absolute path, whatever the working directory: the path is part of the
-    cache's key, so a directory that moves never hits. Returns the
-    directory in use."""
+    absolute path, whatever the working directory. The directory is no
+    part of an entry's key; what did keep a moved checkout from its
+    entries was the absolute source paths inside a Pallas kernel's
+    payload (``_canonical_source_paths``). Returns the directory in
+    use."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -149,17 +149,16 @@ class AdoptMismatch(RuntimeError):
 # ---------------------------------------------------------------------------
 
 def _layer_body(kind, arch, tp, *, n_heads, n_kv, eps, theta,
-                block_size=None, flash_decode=False, window=None, moe_k=0,
-                valid=None):
+                block_size=None, window=None, moe_k=0, valid=None):
     """Which per-layer body of ``text/generation.py`` serves a program:
     by the program's ``kind``, the ``arch`` and whether the program is
     sharded (``tp > 1``: the ``_tp`` bodies, run inside ``shard_map``),
     with the keywords that body takes bound; its operands stay the
     program's to pass. The gpt bodies know neither grouped heads nor a
-    rotary table. A window, routed experts (``moe_k`` and the rows that
-    count, ``valid``) and the flash-decode kernel exist in the
-    one-device bodies alone: ``Engine.__init__`` refuses each of them
-    to an engine with ``tp > 1``, and so does this."""
+    rotary table. A window and routed experts (``moe_k`` and the rows
+    that count, ``valid``) exist in the one-device bodies alone:
+    ``Engine.__init__`` refuses a model that has them to an engine with
+    ``tp > 1``, and so does this."""
     from ..text import generation as G
 
     name = {"prefill": "prefill_layer", "decode": "decode_layer_paged",
@@ -170,13 +169,11 @@ def _layer_body(kind, arch, tp, *, n_heads, n_kv, eps, theta,
     if kind != "prefill":
         kw["block_size"] = block_size
     if tp > 1:
-        if window is not None or moe_k or flash_decode:
-            raise ValueError("the tensor-parallel bodies take no window, "
-                             "no routed feed-forward and no flash kernel")
+        if window is not None or moe_k:
+            raise ValueError("the tensor-parallel bodies take no window "
+                             "and no routed feed-forward")
         return functools.partial(getattr(G, f"_{arch}_{name}_tp"), tp=tp,
                                  **kw)
-    if kind == "decode":
-        kw["flash_decode"] = flash_decode
     if arch == "llama" and kind != "verify":
         kw.update(window=window, moe_k=moe_k, valid=valid)
     return functools.partial(getattr(G, f"_{arch}_{name}"), **kw)
@@ -405,18 +402,17 @@ def _count_picks(moe, picks, decode=False):
 
 def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
                        temps, vmasks, moe=None, *, arch, n_heads, n_kv, eps,
-                       theta, do_sample, top_k, top_p, block_size,
-                       flash_decode=False, kinds=None, window=None,
-                       moe_k=0, tp=1):
+                       theta, do_sample, top_k, top_p, block_size, kinds=None,
+                       window=None, moe_k=0, tp=1):
     """One fused paged decode step: every decode-active slot advances a
     token at its own position, writing K/V through its block table
     (inactive rows scatter into the trash block so a freed slot's stale
-    table can never corrupt the pool) and attending over the gathered
-    per-slot view — or, with ``flash_decode``, through the
-    tuner-registered pallas flash-decode kernel (block-table-aware DMA +
-    online softmax, no gathered view). ONE program for the life of the
-    engine — the block table is a plain runtime operand of static
-    shape; the pool is a carry of the layer loop
+    table can never corrupt the pool) and attending over the blocks of
+    the pool its table names, read in place on a TPU
+    (``generation._paged_decode_attention``; a slot that does not decode
+    is given the position -1 and sees, and reads, nothing). ONE program
+    for the life of the engine — the block table is a plain runtime
+    operand of static shape; the pool is a carry of the layer loop
     (``_scan_layers_over_pool``), written in place when donated.
     ``kinds`` / ``window`` / ``moe_k`` / ``moe`` as in the prefill
     program; the routed layers compute and count the active rows alone.
@@ -433,20 +429,20 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
     blk = tables[rows, cur_pos // block_size]
     dest = jnp.where(active, blk * block_size + cur_pos % block_size,
                      cur_pos % block_size)
+    seen = jnp.where(active, cur_pos, -1)   # the last position a row sees
     if arch == "llama":
         xt = jnp.take(w["embed"], tok, axis=0)[:, None]
         stack = G._llama_stack(w)
-        at = (cur_pos, cur_pos)         # the write line, the rotary one
+        at = (seen, cur_pos)            # ... and its rotary position
     else:
         xt = (jnp.take(w["wte"], tok, axis=0)
               + jnp.take(w["wpe"], cur_pos, axis=0))[:, None]
-        stack, at = {k: w[k] for k in G._GPT_STACK_KEYS}, (cur_pos,)
+        stack, at = {k: w[k] for k in G._GPT_STACK_KEYS}, (seen,)
 
     def layer_of(win):
         body = _layer_body("decode", arch, tp, n_heads=n_heads, n_kv=n_kv,
                            eps=eps, theta=theta, block_size=block_size,
-                           flash_decode=flash_decode, window=win,
-                           moe_k=moe_k, valid=active)
+                           window=win, moe_k=moe_k, valid=active)
         return lambda xc, lw, kc_p, vc_p, blocks, rows: body(
             xc, lw, kc_p, vc_p, blocks, rows, *at)
 
@@ -632,7 +628,6 @@ _PAGED_STATICS = _STATICS + ("block_size",)
 # its ``shard_map`` programs (``_tp_jitted``). A model or an engine without
 # them passes none, so its programs and their cache keys are what they were
 _PROGRAM_STATICS = _PAGED_STATICS + ("kinds", "window", "moe_k", "tp")
-_DECODE_STATICS = _PROGRAM_STATICS + ("flash_decode",)
 
 _CODE_TOKEN = None
 
@@ -648,10 +643,10 @@ def _serving_code_token():
         from ..aot import keys as _akeys
         from ..distributed import collective_matmul as _cm
         from ..nn import routed_ffn as _rf
-        from ..ops.pallas import flash_decode as _fd
+        from ..ops.pallas import paged_attention as _pa
         from ..text import generation as G
         from . import speculative as _spec
-        _CODE_TOKEN = _akeys.code_token(G, _cm, _fd, _rf, _spec,
+        _CODE_TOKEN = _akeys.code_token(G, _cm, _pa, _rf, _spec,
                                         sys.modules[__name__])
     return _CODE_TOKEN
 
@@ -706,9 +701,9 @@ _PAGED_PREFILL = jax.jit(_paged_prefill_impl,
 _PAGED_PREFILL_DONATED = jax.jit(
     _paged_prefill_impl, static_argnames=_PROGRAM_STATICS,
     donate_argnums=(1, 2))
-_PAGED_DECODE = jax.jit(_paged_decode_impl, static_argnames=_DECODE_STATICS)
+_PAGED_DECODE = jax.jit(_paged_decode_impl, static_argnames=_PROGRAM_STATICS)
 _PAGED_DECODE_DONATED = jax.jit(
-    _paged_decode_impl, static_argnames=_DECODE_STATICS,
+    _paged_decode_impl, static_argnames=_PROGRAM_STATICS,
     donate_argnums=(1, 2))
 _PAGED_CHUNK = jax.jit(_paged_chunk_impl, static_argnames=_PROGRAM_STATICS)
 _PAGED_CHUNK_DONATED = jax.jit(
@@ -899,20 +894,17 @@ class Engine:
                  default_retry_after_s=DEFAULT_RETRY_AFTER_S,
                  block_size=16, n_blocks=None,
                  prefill_chunk=None, prefix_sharing=True, tp=1,
-                 mesh=None, replica_id=None, flash_decode=False,
-                 speculative=None):
+                 mesh=None, replica_id=None, speculative=None):
         self._w, self._hp, geo = _make_arch(model)
         if "kinds" in self._hp:
             # a model of layer kinds with routed experts runs through the
-            # single-device gathered programs alone: what else it is
-            # asked for is refused by name, never served another way
+            # single-device programs alone: what else it is asked for is
+            # refused by name, never served another way
             for asked, missing in (
                     (int(tp) > 1, "tp > 1: the tensor-parallel bodies have "
                      "no routed feed-forward and take no window"),
                     (speculative is not None, "speculative=...: the verify "
-                     "body has no routed feed-forward and takes no window"),
-                    (flash_decode, "flash_decode=True: the flash-decode "
-                     "kernel takes no window")):
+                     "body has no routed feed-forward and takes no window")):
                 if asked:
                     raise ValueError(
                         f"serving.Engine cannot serve "
@@ -942,16 +934,6 @@ class Engine:
         # tp degree or KV geometry — share it and may exchange handles
         self.model_fingerprint = _model_fingerprint(
             model, self._hp, self._statics, eos_token_id, self._w)
-        # the pallas flash-decode kernel replaces the gathered decode
-        # attention (single-device only — the TP decode rings its own
-        # attention path). Interpret mode on CPU keeps the program
-        # compilable everywhere; output is token-identical to the
-        # gathered form, and the replay/adopt machinery is untouched.
-        self.flash_decode = bool(flash_decode)
-        if self.flash_decode and self.tp > 1:
-            raise ValueError("flash_decode is not supported with tp > 1 "
-                             "yet (the TP decode shards attention over "
-                             "the mesh)")
         # speculative decoding (draft-verify; see serving/speculative.py):
         # the TP decode shards attention over the mesh, the verify
         # program does not
@@ -982,10 +964,6 @@ class Engine:
                                   n_blocks=n_blocks)
         self._paged_statics = dict(self._statics,
                                    block_size=self.block_size)
-        # the flash_decode static only shapes the DECODE program;
-        # prefill/chunk keep their signatures (and AOT keys) stable
-        self._decode_statics = dict(self._paged_statics,
-                                    flash_decode=self.flash_decode)
         # threaded device state (numpy until the first jit call)
         self._tok = np.zeros(self.n_slots, np.int32)
         self._cur = np.zeros(self.n_slots, np.int32)
@@ -1058,7 +1036,7 @@ class Engine:
             self._decode = _tp_jitted(mesh, "decode", arch, donate, items)
             self._chunk = _tp_jitted(mesh, "chunk", arch, donate, items)
             # baked into the shard_map programs: no call passes a static
-            self._paged_statics = self._decode_statics = {}
+            self._paged_statics = {}
         else:
             self._prefill = (_PAGED_PREFILL_DONATED if donate
                              else _PAGED_PREFILL)
@@ -1265,7 +1243,7 @@ class Engine:
             "decode", ("decode",), self._decode,
             (w, kc, vc, tables, tok, cur, active, keys, temps,
              vmasks) + moe,
-            self._decode_statics, "decode"))
+            self._paged_statics, "decode"))
         if self.spec is not None:
             K1 = self.spec.k + 1
             sids = jax.ShapeDtypeStruct((1, K1), np.int32)
@@ -1830,6 +1808,8 @@ class Engine:
         active slot advances exactly one token. Returns when its call
         had returned and when its tokens were on the host."""
         called = time.perf_counter()
+        self.metrics.mark_lines_seen(self.cache.cur_pos[active] + 1,
+                                     self._hp.get("window"))
         with _compile_scope("decode"):
             out = self._run_program(
                 "decode", ("decode",), self._decode,
@@ -1837,7 +1817,7 @@ class Engine:
                  self.cache.block_tables.copy(), self._tok,
                  self._cur, active, self._keys, self._temps,
                  self._vmask.copy()) + self._moe_in(),
-                self._decode_statics, "decode")
+                self._paged_statics, "decode")
         nxt, self.cache.kc, self.cache.vc, self._cur, self._keys = \
             self._moe_out(out)
         self._tok = nxt
@@ -2053,8 +2033,7 @@ class Engine:
                "compile_budget": self.compile_budget,
                **self.cache.pool_stats(),
                "prefill_chunk": self.prefill_chunk,
-               "prefix_sharing": self.prefix_sharing,
-               "flash_decode": self.flash_decode}
+               "prefix_sharing": self.prefix_sharing}
         if self.spec is not None:
             ar = self.metrics.acceptance_rate()
             out["speculative"] = {

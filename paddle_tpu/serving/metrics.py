@@ -180,6 +180,11 @@ class EngineMetrics:
         # counters the programs keep on the device and hand on from call
         # to call, set by the engine; moe_counters() fetches them
         self.moe = None
+        # what decode attention has to read (``mark_lines_seen``): the
+        # K and V lines the decode-active rows could see, summed over
+        # ``calls`` fused decode calls; ``in_window`` is the same with
+        # each row cut to the model's window (equal without one)
+        self.lines_seen = {"calls": 0, "lines": 0, "in_window": 0}
         self.kv_pool_bytes_per_device = None
         self.collectives_per_decode_step = None
         # decode-step wall times, histogram-backed: the ~64-observation
@@ -254,6 +259,15 @@ class EngineMetrics:
         for _ in range(n):
             self.itl_hist.observe(per)
 
+    def mark_lines_seen(self, seen, window=None):
+        """One fused decode call whose active rows see ``seen`` lines
+        each (a host array: the position a row writes, plus one)."""
+        t = self.lines_seen
+        t["calls"] += 1
+        t["lines"] += int(seen.sum())
+        t["in_window"] += int((seen.clip(max=window) if window
+                               else seen).sum())
+
     def acceptance_rate(self):
         """Fraction of proposed draft tokens the verify pass accepted;
         None before any speculative step."""
@@ -313,6 +327,7 @@ class EngineMetrics:
             "tokens_generated": self.tokens_generated,
             "prefills": self.prefills,
             "decode_steps": self.decode_steps,
+            "decode_lines_seen": dict(self.lines_seen),
             "avg_slot_occupancy": round(self.occupancy_sum / n, 4),
             "avg_queue_depth": round(self.queue_depth_sum / n, 4),
             "peak_queue_depth": self.peak_queue_depth,

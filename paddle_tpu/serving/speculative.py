@@ -175,7 +175,6 @@ class _ModelDraft:
         # greedy statics: the draft's sampled path is never used
         self._statics = dict(hp, do_sample=False, top_k=0, top_p=None,
                              block_size=engine.block_size)
-        self._decode_statics = dict(self._statics, flash_decode=False)
         S, bs, mb = engine.n_slots, engine.block_size, engine.cache.max_blocks
         self.tables = (1 + mb * np.arange(S)[:, None]
                        + np.arange(mb)[None]).astype(np.int32)
@@ -257,7 +256,7 @@ class _ModelDraft:
                     (self._w, self.kc, self.vc, self.tables.copy(),
                      self.tok, self.cur, pos + i < eng.max_len,
                      self.keys, self.temps, eng._vmask.copy()),
-                    self._decode_statics, "spec.draft")
+                    self._statics, "spec.draft")
                 nxt, self.kc, self.vc, self.cur, self.keys = out
                 self.tok = nxt
                 dispatched = time.perf_counter()
@@ -321,7 +320,7 @@ class _ModelDraft:
         specs.append((
             "draft_decode", ("draft_decode",), decode,
             (w, kc, vc, sds(self.tables), tok, cur, act, keys, temps, vm),
-            self._decode_statics, "spec.draft"))
+            self._statics, "spec.draft"))
         return specs
 
 

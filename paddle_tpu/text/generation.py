@@ -470,7 +470,7 @@ def _generate_jit(w, input_ids, prompt_len_mask, key, *, n_heads, n_kv, eps,
 
 
 # ---------------------------------------------------------------------------
-# paged-KV bodies (serving engine): decode attention gathers K/V through
+# paged-KV bodies (serving engine): decode attention reads K/V through
 # per-slot block tables; prefill/chunk writes are block-aligned scatters
 # into the shared pool (masked writes redirect to the reserved trash
 # block). Module-level like the slot bodies: one lowering per shape.
@@ -483,7 +483,10 @@ def _paged_view(pool_l, tables, block_size):
     (view index == logical position; unused table entries point at the
     trash block and sit beyond the causal bound).
 
-    Two forms, chosen by the pool's KV heads, each measured where the
+    The view of the chunk, verify and tensor-parallel bodies; the
+    one-device decode reads the pool in place
+    (:func:`_paged_decode_attention`). Two forms, chosen by the pool's KV
+    heads, each measured (in decode, while decode gathered) where the
     other is worse. By whole blocks: at 32 heads a decode step of 16
     slots x 2048 lines spends 25.99 ms on the device this way and 31.06
     gathered by lines (448 against 393 tokens/s end to end; my chip run,
@@ -503,48 +506,31 @@ def _paged_view(pool_l, tables, block_size):
 
 
 def _paged_decode_attention(q, kc_pool, vc_pool, tables, write_pos,
-                            block_size, flash, dt, window=None):
+                            window=None):
     """One-token paged attention: q [S, H, hd] over the pool through
-    block tables. ``flash=True`` runs the tuner-registered pallas
-    flash-decode kernel (block DMA straight off the table rows + online
-    softmax — no [S, T] gather materializes; interpret mode on CPU);
-    False keeps the gathered XLA form. Both share the causal contract
-    ``view position <= write_pos``; the flash output is token-identical,
-    not bitwise (online-softmax reduction order).
+    block tables, each row seeing the positions ``<= write_pos`` (none
+    where that is negative: a slot that does not decode) and, under a
+    ``window``, only the last ``window`` of them. On a TPU one kernel
+    reads the blocks that can hold such a key in place
+    (``ops/pallas/paged_attention.py``; the window goes in as data, so a
+    model of layer kinds compiles it once); elsewhere the same function
+    computes the gathered form. The chunk and verify bodies keep their
+    own view (``_paged_view``). Returns ``[S, H, hd]`` in q's type."""
+    from ..ops.pallas import paged_attention as kernel
 
-    ``window`` (gathered form only): a row sees its own position and the
-    ``window - 1`` before it, and only the blocks that can hold one of
-    those are gathered (``_window_blocks``: 65 of 512 at a window of
-    1024, blocks of 16 and 8192 lines), from the row's first such block
-    on; where that is no fewer than the whole table, the whole view is
-    masked instead."""
-    S, H, hd = q.shape
-    if flash:
-        from ..ops.pallas.flash_decode import flash_decode
-        return flash_decode(
-            q, kc_pool, vc_pool, tables, write_pos,
-            interpret=jax.default_backend() == "cpu").astype(dt)
-    first = 0
-    if window is not None:
-        tables, first = _window_tables(tables, write_pos, 1, window,
-                                       block_size)
-    kview = _paged_view(kc_pool, tables, block_size)   # [S, T, n_kv, hd]
-    vview = _paged_view(vc_pool, tables, block_size)
-    kpos = first + jnp.arange(kview.shape[1])[None, :]
-    valid = kpos <= write_pos[:, None]
-    if window is not None:
-        valid = valid & (write_pos[:, None] - kpos < window)
-    return _attend_rows(q, kview, vview, valid, dt)
+    return kernel.paged_attention(q, kc_pool, vc_pool, tables, write_pos,
+                                  window or 0)
 
 
 def _llama_decode_layer_paged(xt, lw, kc_pool, vc_pool, tables, dest,
                               write_pos, rope_pos, *, n_heads, n_kv, eps,
-                              theta, block_size, flash_decode=False,
-                              window=None, moe_k=0, valid=None):
+                              theta, block_size, window=None, moe_k=0,
+                              valid=None):
     """One Llama decoder layer advancing every slot one token against
     the paged pool: the new K/V scatters to flat pool index ``dest``
-    (trash-redirected for inactive rows), then attention gathers each
-    slot's view through its block-table row. kc_pool/vc_pool
+    (trash-redirected for inactive rows), then attention reads the pool
+    through each slot's block-table row
+    (:func:`_paged_decode_attention`). kc_pool/vc_pool
     [n_blocks, bs, n_kv, hd] (one layer); tables [S, mb]; dest [S];
     write_pos/rope_pos [S]. ``window``, the layer's own rotary table and
     the routed feed-forward as in :func:`_llama_prefill_layer`; ``valid``
@@ -564,8 +550,8 @@ def _llama_decode_layer_paged(xt, lw, kc_pool, vc_pool, tables, dest,
     vc_pool = vc_pool.reshape(nb * bs, n_kv, hd).at[dest].set(
         v[:, 0]).reshape(nb, bs, n_kv, hd)
     o = _paged_decode_attention(q[:, 0], kc_pool, vc_pool, tables,
-                                write_pos, block_size, flash_decode,
-                                dt, window).reshape(S, 1, n_heads * hd)
+                                write_pos, window).reshape(
+                                    S, 1, n_heads * hd)
     xt2 = xt + o @ lw["wo"]
     y, picks = _feed_forward(_rms(xt2, lw["ln2"], eps), lw, moe_k, valid)
     xt2 = xt2 + y
@@ -574,14 +560,12 @@ def _llama_decode_layer_paged(xt, lw, kc_pool, vc_pool, tables, dest,
 
 
 def _gpt_decode_layer_paged(xt, lw, kc_pool, vc_pool, tables, dest,
-                            write_pos, *, n_heads, block_size,
-                            flash_decode=False):
+                            write_pos, *, n_heads, block_size):
     """GPT block, paged decode (learned positions enter at the
     embedding; only the pool write/gather differs from the slot body)."""
     S = xt.shape[0]
     h = xt.shape[-1]
     hd = h // n_heads
-    dt = xt.dtype
     hN = _ln(xt, lw["ln1w"], lw["ln1b"])
     qkv = hN @ lw["wqkv"] + lw["bqkv"]
     q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -594,8 +578,7 @@ def _gpt_decode_layer_paged(xt, lw, kc_pool, vc_pool, tables, dest,
     vc_pool = vc_pool.reshape(nb * bs, n_heads, hd).at[dest].set(
         v[:, 0]).reshape(nb, bs, n_heads, hd)
     o = _paged_decode_attention(q[:, 0], kc_pool, vc_pool, tables,
-                                write_pos, block_size, flash_decode,
-                                dt).reshape(S, 1, h)
+                                write_pos).reshape(S, 1, h)
     xt2 = xt + o @ lw["wproj"] + lw["bproj"]
     h2 = _ln(xt2, lw["ln2w"], lw["ln2b"])
     xt2 = xt2 + jax.nn.gelu(h2 @ lw["wfc1"] + lw["bfc1"],

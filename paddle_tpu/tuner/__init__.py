@@ -20,7 +20,7 @@ Entry points::
     from paddle_tpu import tuner
     tuner.tune("ragged_matmul", args=(x, w, counts))   # search + persist
     tuner.get_config("fused_ce", shapes=..., dtype=...)  # resolve winner
-    tuner.call("flash_decode", q, kc, vc, tables, wp)  # tuned + AOT-routed
+    tuner.call("int8_matmul", xq, xs, wq, ws)          # tuned + AOT-routed
 
 Kernel call sites resolve configs through :func:`get_config`; literal
 tile sizes at call sites outside this registry are flagged by the

@@ -179,71 +179,6 @@ register(KernelSpec(
 
 
 # ---------------------------------------------------------------------------
-# paged flash-decode (ISSUE 14 kernel a)
-# ---------------------------------------------------------------------------
-
-def _fd_space(shapes, dtype):
-    from ..ops.pallas.flash_decode import legal_kv_heads_per_step
-    return [{"kv_heads_per_step": g}
-            for g in legal_kv_heads_per_step(shapes[1][2])]
-
-
-def _fd_build(config, interpret):
-    from ..ops.pallas.flash_decode import flash_decode
-
-    def fn(q, kc, vc, tables, write_pos):
-        return flash_decode(q, kc, vc, tables, write_pos,
-                            kv_heads_per_step=config["kv_heads_per_step"],
-                            interpret=interpret)
-    return fn
-
-
-def _fd_reference(q, kc, vc, tables, write_pos):
-    from ..ops.pallas.flash_decode import flash_decode_reference
-    return flash_decode_reference(q, kc, vc, tables, write_pos)
-
-
-def _fd_features(shapes, dtype, config):
-    (S, H, hd), (nb, bs, n_kv, _) = shapes[0], shapes[1]
-    mb = shapes[2][1] if len(shapes) > 2 else nb
-    g = config["kv_heads_per_step"]
-    G = g * (H // n_kv)
-    it = _itemsize(dtype)
-    vmem = (G * hd + 2 * bs * g * hd) * it \
-        + (G * (2 * _LANES + hd)) * _F32
-    return {"tiles": [(G, _sub(dtype)), (hd, _LANES),
-                      (bs * g, _sub(dtype))],
-            "vmem_bytes": vmem,
-            "steps": S * (n_kv // g) * mb}
-
-
-def _fd_demo(rng):
-    S, H, n_kv, hd, nb, bs, mb = 2, 4, 2, 32, 6, 8, 3
-    q = jnp.asarray(rng.standard_normal((S, H, hd)), jnp.float32)
-    kc = jnp.asarray(rng.standard_normal((nb, bs, n_kv, hd)), jnp.float32)
-    vc = jnp.asarray(rng.standard_normal((nb, bs, n_kv, hd)), jnp.float32)
-    tables = jnp.asarray(rng.integers(0, nb, (S, mb)), jnp.int32)
-    wp = jnp.asarray(rng.integers(0, mb * bs, (S,)), jnp.int32)
-    return ((q, kc, vc, tables, wp),
-            ((S, H, hd), (nb, bs, n_kv, hd), (S, mb)), "float32")
-
-
-register(KernelSpec(
-    name="flash_decode",
-    space=_fd_space,
-    build=_fd_build,
-    reference=_fd_reference,
-    features=_fd_features,
-    default=lambda shapes, dtype: dict(_fd_space(shapes, dtype)[0]),
-    demo=_fd_demo,
-    shapes_of=lambda args: ((tuple(args[0].shape), tuple(args[1].shape),
-                             tuple(args[3].shape)), str(args[0].dtype)),
-    tol=2e-5,
-    doc="paged single-token decode attention (block-table gather + "
-        "online softmax)"))
-
-
-# ---------------------------------------------------------------------------
 # ragged grouped matmul (ISSUE 14 kernel b)
 # ---------------------------------------------------------------------------
 

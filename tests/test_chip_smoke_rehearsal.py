@@ -51,11 +51,11 @@ def test_server_phase(builds):
     cfg, serve = chip_smoke.cut(CFG, SIZES["serve"])
     info = chip_smoke.run_server(cfg, **serve, seed=0, builds=builds,
                                  platform="cpu")
-    assert [e["engine"] for e in info["engines"]] == [
-        "greedy", "sampled", "flash_decode"]
+    assert [e["engine"] for e in info["engines"]] == ["greedy", "sampled"]
     # float32 on the CPU: identity is exact, no near-tie to explain
     assert all(e["vs_generate"]["equal"] for e in info["engines"])
-    assert info["engines"][-1]["vs_one_device_engine"]["equal"]
+    # the platform decides: no kernel in a decode program off the TPU
+    assert not info["engines"][0]["kernel_in_decode"]
 
 
 def test_four_device_phase(builds):

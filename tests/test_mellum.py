@@ -136,6 +136,46 @@ def test_engine_prefill_chunk_and_decode_match_the_reference(served,
     assert _worst_gap(weights, _config_dict(), sample, handles) < 0.05
 
 
+def test_engine_decodes_through_the_kernel_as_through_the_gathered_form(
+        model, weights, monkeypatch):
+    """On a TPU the decode program of a model of layer kinds holds the
+    paged-attention kernel in every layer, its window an operand: here
+    through the interpreter, the tokens are the gathered form's and as
+    close to the reference, past the window and the YaRN table's
+    original length."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    from paddle_tpu.serving import engine as E
+
+    sample = [(_ids(n, seed=20 + n), new) for n, new in SAMPLE]
+
+    def serve():
+        eng = Engine(model, n_slots=3, max_len=64, block_size=4,
+                     prefill_chunk=16, prefix_sharing=False)
+        handles = [eng.submit(p, max_new_tokens=new) for p, new in sample]
+        eng.drain()
+        return handles
+
+    plain = serve()
+    windows, kernel = [], pa.paged_attention
+
+    def interpreted(q, kc, vc, tables, write_pos, window):
+        windows.append(window)
+        return kernel(q, kc, vc, tables, write_pos, window, interpret=True)
+
+    E._PAGED_DECODE_DONATED.clear_cache()
+    E._PAGED_DECODE.clear_cache()
+    monkeypatch.setattr(pa, "paged_attention", interpreted)
+    try:
+        handles = serve()
+    finally:
+        E._PAGED_DECODE_DONATED.clear_cache()
+        E._PAGED_DECODE.clear_cache()
+    assert windows == [CFG.sliding_window if kind == SLIDING else 0
+                       for kind in CFG.layer_types]
+    assert [h.tokens for h in handles] == [h.tokens for h in plain]
+    assert _worst_gap(weights, _config_dict(), sample, handles) < 0.05
+
+
 def _no_window(config, reference):
     return dict(config, sliding_window=CFG.max_position_embeddings)
 
@@ -221,7 +261,6 @@ def test_a_dense_engine_reports_no_moe():
 @pytest.mark.parametrize("kwargs,names", [
     (dict(tp=2), "tp > 1"),
     (dict(speculative=SpecConfig(k=2)), "speculative"),
-    (dict(flash_decode=True), "flash_decode"),
 ])
 def test_engine_refuses_what_this_model_cannot_have(model, kwargs, names):
     with pytest.raises(ValueError, match="cannot serve MellumForCausalLM"

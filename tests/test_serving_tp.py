@@ -84,7 +84,7 @@ def test_one_device_program_holds_no_collective(models, arch, kind):
     passes none, and what it traces holds no collective; the same
     function inside ``shard_map`` with ``tp=2`` holds the rings."""
     one = Engine(models[arch], prefill_chunk=8, **GEO)
-    assert "tp" not in one._decode_statics
+    assert "tp" not in one._paged_statics
     text = _lowered(one, kind)
     assert not [op for op in _COLLECTIVES if op in text]
     two = Engine(models[arch], tp=2, prefill_chunk=8, **GEO)
@@ -128,8 +128,8 @@ def test_tp2_prefill_writes_the_one_device_pool_lines(models, arch):
 # ---------------------------------------------------------------------------
 
 def test_tp_validation(model):
-    with pytest.raises(ValueError, match="flash_decode"):
-        Engine(model, flash_decode=True, tp=2, **GEO)
+    with pytest.raises(TypeError, match="flash_decode"):
+        Engine(model, flash_decode=True, **GEO)   # an arm that is gone
     with pytest.raises(ValueError, match="does not divide"):
         Engine(model, tp=3, **GEO)        # 8 heads / 4 kv not divisible
     with pytest.raises(ValueError, match="mesh"):

@@ -72,12 +72,14 @@ def _flash_attention(grad):
     return (jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd), [qkv] * 3
 
 
-def _flash_decode(n_kv):
-    from paddle_tpu.ops.pallas.flash_decode import flash_decode
-    pool = ((_BLOCKS, _BS, n_kv, _HD), jnp.bfloat16)
-    return flash_decode, [((_SLOTS, _H, _HD), jnp.bfloat16), pool, pool,
-                          ((_SLOTS, (_BLOCKS - 1) // _SLOTS), jnp.int32),
-                          ((_SLOTS,), jnp.int32)]
+def _paged_attention(blocks, n_kv, table):
+    """The decode kernel at a serving cell's shapes: 16 slots, the pool
+    flat over the layers, the window an operand."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    pool = ((blocks, _BS, n_kv, _HD), jnp.bfloat16)
+    return paged_attention, [((16, _H, _HD), jnp.bfloat16), pool, pool,
+                             ((16, table), jnp.int32), ((16,), jnp.int32),
+                             ((), jnp.int32)]
 
 
 def _fused_ce(grad):
@@ -110,8 +112,11 @@ def _stochastic(name):
 _CASES = {
     "flash_attention-fwd": functools.partial(_flash_attention, False),
     "flash_attention-grad": functools.partial(_flash_attention, True),
-    "flash_decode-n_kv32": functools.partial(_flash_decode, 32),
-    "flash_decode-n_kv8": functools.partial(_flash_decode, 8),
+    # deepseek-llm-7b at depth 6, 16 x 2048; mellum2 at depth 8, 16 x 8192
+    "paged_attention-32_kv_heads": functools.partial(
+        _paged_attention, 6 * 2049, 32, 128),
+    "paged_attention-4_kv_heads": functools.partial(
+        _paged_attention, 8 * 8193, 4, 512),
     "fused_ce_loss-fwd": functools.partial(_fused_ce, False),
     "fused_ce_loss-grad": functools.partial(_fused_ce, True),
     "int8_linear": _int8_linear,
@@ -129,6 +134,9 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
             for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if case.startswith("paged_attention"):
+        # the pool is read where it lies: nothing of its size is planned
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 def _over_jit(mesh):
@@ -295,6 +303,10 @@ def test_paged_program_keeps_the_pool_in_place(kind, one_chip,
         if n in (pool_elements, pool_elements // _LAYERS):
             moved.append((op, shape))
     assert not moved
+    if kind == "decode":
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        assert not re.search(r"= bf16\[16,\d+,32,128\]\S* gather\(", text)
 
 
 # the routed serving cell's engine: 64 experts of 2304 x 896, 32 query and
@@ -340,7 +352,7 @@ def _routed_program(kind):
         return (E._PAGED_DECODE_DONATED,
                 [w, pool, pool, ((S, _R_TABLE), i32), slots, slots,
                  ((S,), jnp.bool_), keys, ((S,), f32), ((S, V), f32), moe],
-                dict(statics, flash_decode=False))
+                statics)
     if kind == "chunk":
         return (E._PAGED_CHUNK_DONATED,
                 [w, pool, pool, slots, slots, keys, ((1, _R_CHUNK), i32),
@@ -358,9 +370,10 @@ def test_routed_program_compiles_and_copies_neither_pool_nor_banks(
     """At the published widths: a layer's expert banks reach their
     matmuls as the arrays they are (a slice of a stacked bank was copied
     first: 3 GB a decode step at depth 4), the pool with its 4 KV
-    heads is written in place and never relaid whole before a gather
-    (gathered by blocks it was: 8 copies of 537 MB a step), a window
-    layer's view is the window's blocks, not ``max_len``, and the chunk's
+    heads is written in place and never relaid whole before a gather or
+    the decode kernel (gathered by blocks it was: 8 copies of 537 MB a
+    step), the decode program holds the paged-attention kernel once a
+    layer and no gathered view, and the chunk's
     full layer walks its 8192 keys in tiles (one pass over float32
     ``[4, 8, 512, 8192]`` scores took 47 ms on the chip). Every expert is
     applied to every row: no grouped-matmul kernel is in any of the
@@ -389,8 +402,75 @@ def test_routed_program_compiles_and_copies_neither_pool_nor_banks(
             moved.append((op, shape))
     assert not moved
     if kind == "decode":
-        # of four layers three gather 65 blocks a slot, one the whole table
-        views = re.findall(r"= bf16\[16,(\d+),4,128\]\S* gather\(", text)
-        assert sorted(set(views)) == ["1040", "8192"]
-        assert views.count("8192") == 2 * (_R_LAYERS // 4)
-        assert views.count("1040") == 6 * (_R_LAYERS // 4)
+        # window layers and full ones run the same kernel, one a layer
+        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                              text)) == _R_LAYERS
+        assert not re.search(r"= bf16\[16,\d+,4,128\]\S* gather\(", text)
+
+
+_LOWER = """
+import json, os, sys
+root = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, root)
+import jax
+import jax.numpy as jnp
+from paddle_tpu.serving import engine as E
+
+
+def build(x):
+    if isinstance(x, dict):
+        return {k: build(v) for k, v in x.items()}
+    if len(x) == 2 and isinstance(x[1], str):
+        return jax.ShapeDtypeStruct(tuple(x[0]), jnp.dtype(x[1]))
+    return [build(v) for v in x]
+
+
+spec = json.load(open(os.path.join(root, "decode.json")))
+statics = {k: tuple(v) if isinstance(v, list) else v
+           for k, v in spec["statics"].items()}
+lowered = E._PAGED_DECODE_DONATED.trace(
+    *build(spec["args"]), **statics).lower(lowering_platforms=("tpu",))
+open(os.path.join(root, "decode.txt"), "w").write(lowered.as_text())
+"""
+
+
+def test_decode_program_text_is_the_same_from_any_checkout(tmp_path):
+    """jax's compile-cache key holds a program's text without locations,
+    but a Pallas kernel's payload is embedded whole, locations included:
+    with absolute paths in it, the routed cell's decode program (the
+    kernel once a layer) would be compiled again by every checkout that
+    is not at the path that wrote the entry. Lowered for a TPU (no chip,
+    no TPU library: two child processes) from two copies of the package
+    at different depths, the texts are equal byte for byte."""
+    import json
+    import os
+    import shutil
+    import subprocess
+    import sys
+
+    import paddle_tpu
+
+    _, shapes, statics = _routed_program("decode")
+    named = jax.tree.map(
+        lambda sd: [list(sd[0]), jnp.dtype(sd[1]).name], shapes,
+        is_leaf=lambda x: isinstance(x, tuple) and len(x) == 2
+        and isinstance(x[1], type(jnp.bfloat16)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    roots, children = [tmp_path / "a", tmp_path / "b" / "deeper"], []
+    for root in roots:
+        shutil.copytree(os.path.dirname(paddle_tpu.__file__),
+                        root / "paddle_tpu",
+                        ignore=shutil.ignore_patterns("__pycache__"))
+        (root / "decode.json").write_text(json.dumps(
+            {"args": named, "statics": statics}))
+        (root / "lower.py").write_text(_LOWER)
+        children.append(subprocess.Popen(
+            [sys.executable, str(root / "lower.py")], cwd=root, env=env,
+            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True))
+    for child in children:
+        out, _ = child.communicate(timeout=600)
+        assert child.returncode == 0, out[-2000:]
+    first, second = ((root / "decode.txt").read_text() for root in roots)
+    assert first.count("tpu_custom_call") >= _R_LAYERS
+    assert str(tmp_path) not in first
+    assert first == second
