@@ -3,9 +3,12 @@
 ``nn/moe.py`` bounds each expert by a capacity and drops what overflows
 (the GShard form its training path wants: static ``[E, C]`` shapes).
 Serving a published top-k model may drop nothing, so this is the other
-form: softmax over the experts in float32, the ``k`` largest,
-renormalised, then every expert applied to every row and each row's
-result the weighted sum over its picks (weight zero elsewhere). The
+form: scores over the experts in float32 (a softmax, or a sigmoid with a
+selection bias and a scale), the ``k`` largest, renormalised, then every
+expert held here applied to every row and each row's result the weighted
+sum over its picks (weight zero elsewhere). The banks may be a share of
+the layer's experts (``routed_ffn``'s ``first``): the router still scores
+all of them. The
 shapes depend on the rows and the banks alone: no factor pads anything,
 and a load that sends every token to one expert is computed like any
 other.
@@ -36,35 +39,62 @@ import jax.numpy as jnp
 __all__ = ["route", "routed_ffn"]
 
 
-def route(m, wr, k):
+def route(m, wr, k, scoring="softmax", bias=None, scale=1.0):
     """``(experts [T, k] int32, weights [T, k] float32)`` of rows ``m``
-    ``[T, h]`` under the router ``wr`` ``[h, E]``: the ``k`` largest of a
-    float32 softmax over all ``E``, renormalised to sum to 1."""
-    g = jax.nn.softmax(jnp.dot(m, wr, preferred_element_type=jnp.float32),
-                       axis=-1)
-    vals, experts = jax.lax.top_k(g, k)
-    return experts.astype(jnp.int32), vals / jnp.sum(vals, -1, keepdims=True)
+    ``[T, h]`` under the router ``wr`` ``[h, E]``: float32 scores over all
+    ``E`` (``scoring``: ``softmax``, or ``sigmoid``, each expert scored by
+    itself), the ``k`` largest, their scores renormalised to sum to 1 and
+    multiplied by ``scale``. ``bias`` ``[E]`` is added to the scores for
+    the selection alone: it picks, it never weighs."""
+    z = jnp.dot(m, wr, preferred_element_type=jnp.float32)
+    if scoring == "softmax":
+        g = jax.nn.softmax(z, axis=-1)
+    elif scoring == "sigmoid":
+        g = jax.nn.sigmoid(z)
+    else:
+        raise ValueError(f"scoring {scoring!r} is not implemented")
+    if bias is None:
+        # kept as it was written, not folded into the form below: equal
+        # values, but another lowered text, and with it other compile-cache
+        # keys for every program of a model routed this way
+        vals, experts = jax.lax.top_k(g, k)
+        weights = vals / jnp.sum(vals, -1, keepdims=True)
+    else:
+        _, experts = jax.lax.top_k(g + bias.astype(jnp.float32), k)
+        vals = jnp.take_along_axis(g, experts, axis=-1)
+        weights = vals / (jnp.sum(vals, -1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        weights = weights * scale
+    return experts.astype(jnp.int32), weights
 
 
-def routed_ffn(m, wr, wg, wu, wd, k, valid=None):
-    """``sum_e c_e * (silu(m Wg_e) * (m Wu_e)) Wd_e`` over each row's ``k``
-    routed experts. ``m`` ``[T, h]``; ``wr`` ``[h, E]``; the banks ``wg``,
-    ``wu`` ``[E, h, f]`` and ``wd`` ``[E, f, h]``. ``valid`` ``[T]`` bool
-    marks the rows that are tokens (a decode step's inactive slots and a
-    bucket's padding are not): the others touch no expert's count and
-    come back as zero rows. Every pick is computed, whatever the load of
-    its expert.
+def routed_ffn(m, wr, wg, wu, wd, k, valid=None, first=0, **router):
+    """``sum_e c_e * (silu(m Wg_e) * (m Wu_e)) Wd_e`` over those of each
+    row's ``k`` routed experts that are held here. ``m`` ``[T, h]``;
+    ``wr`` ``[h, E]`` scores all ``E`` experts (``router``: the further
+    arguments of :func:`route`); the banks ``wg``, ``wu`` ``[held, h, f]``
+    and ``wd`` ``[held, f, h]`` are those of the experts ``first`` to
+    ``first + held - 1``, the share a chip holds of a layer divided over
+    several (``held == E``, ``first == 0``: all of them). A pick keeps the
+    weight it has among all ``k`` of its row; what a row's picks on
+    absent experts would add is left out, so the shares of all the chips
+    add up to the whole layer. ``valid`` ``[T]`` bool marks the rows that
+    are tokens (a decode step's inactive slots and a bucket's padding are
+    not): the others touch no expert's count and come back as zero rows.
+    Every pick on a held expert is computed, whatever its expert's load.
 
-    Returns ``(y [T, h], picks [E] int32)``, ``picks`` the rows that
-    picked each expert."""
-    experts, weights = route(m, wr, k)
-    T, E = m.shape[0], wg.shape[0]
+    Returns ``(y [T, h], picks [held] int32)``, ``picks`` the rows that
+    picked each held expert."""
+    experts, weights = route(m, wr, k, **router)
+    T, E, held = m.shape[0], wr.shape[-1], wg.shape[0]
     c = jnp.zeros((T, E), jnp.float32).at[
         jnp.arange(T)[:, None], experts].set(weights)
+    if held != E:
+        c = jax.lax.slice_in_dim(c, first, first + held, axis=1)
     if valid is not None:
         c = jnp.where(valid[:, None], c, 0.0)
     act = jax.nn.silu(jnp.einsum("th,ehf->etf", m, wg)) \
         * jnp.einsum("th,ehf->etf", m, wu)
-    out = jnp.einsum("etf,efh->eth", act, wd)                    # [E, T, h]
+    out = jnp.einsum("etf,efh->eth", act, wd)                 # [held, T, h]
     y = jnp.einsum("te,eth->th", c, out.astype(jnp.float32))
     return y.astype(m.dtype), jnp.sum(c > 0, axis=0, dtype=jnp.int32)

@@ -30,6 +30,11 @@ output. The kernel is bound by its copies, so the second pass is free.
 
 A window is data (an int32 operand, 0 for none): a model of layer kinds
 compiles one kernel for both kinds wherever the shapes agree.
+
+A latent cache has one pool and no V: a line is the latent beside the
+shared rotary key, its values the first ``value_dim`` numbers of the same
+line. The walk is the same with one buffer, each line read once and used
+twice, and the scores times the model's own ``scale``.
 """
 from __future__ import annotations
 
@@ -65,8 +70,13 @@ def _tiles(pool_shape, dtype):
     return (bs * n_kv) % sublanes == 0 and hd % 128 == 0
 
 
-def _kernel(tables, pos, win, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
-            *, nbk, n_kv, bs, mb):
+def _kernel(tables, pos, win, q_ref, *refs, nbk, n_kv, bs, mb, scale, vd):
+    if vd is None:
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sems = refs
+        pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
+    else:                    # one pool: a line's values are its first vd
+        k_hbm, o_ref, kbuf, sems = refs
+        pools = ((k_hbm, kbuf, 0),)
     s = pl.program_id(0)
     H, hd = q_ref.shape
     rows, group = bs * n_kv, H // n_kv
@@ -76,8 +86,8 @@ def _kernel(tables, pos, win, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
     def _clean():
         # lines of a buffer that no copy has reached yet are multiplied by
         # a zero weight below: they have to be numbers
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
+        for _, buf, _ in pools:
+            buf[...] = jnp.zeros_like(buf)
 
     wp = pos[s]
     w = win[0]
@@ -94,7 +104,7 @@ def _kernel(tables, pos, win, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
         def one(i, _):
             blk = tables[s, b0 + i]
             dst = pl.ds(pl.multiple_of(i * rows, rows), rows)
-            for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+            for hbm, buf, which in pools:
                 cp = pltpu.make_async_copy(hbm.at[blk], buf.at[slot, dst],
                                            sems.at[which, slot])
                 cp.start() if go else cp.wait()
@@ -107,7 +117,8 @@ def _kernel(tables, pos, win, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
         copies(0, 0, True)
 
     q = q_ref[...]
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.float32(hd))
     col = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
     line, head = col // n_kv, col % n_kv
     mine = head == jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // group
@@ -121,7 +132,8 @@ def _kernel(tables, pos, win, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
             copies(c + 1, 1 - slot, True)
 
         copies(c, slot, False)
-        k, v = kbuf[slot], vbuf[slot]
+        k = kbuf[slot]
+        v = vbuf[slot] if vd is None else k[:, :vd]
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
         at = (first + c * nbk) * bs + line
@@ -143,43 +155,48 @@ def _kernel(tables, pos, win, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
 
     start = (jnp.full((H, 1), _MASKED, jnp.float32),
              jnp.zeros((H, 1), jnp.float32),
-             jnp.zeros((H, hd), jnp.float32))
+             jnp.zeros((H, hd if vd is None else vd), jnp.float32))
     _, total, acc = jax.lax.fori_loop(0, n_chunks, chunk, start)
     o_ref[...] = (acc / jnp.where(total > 0.0, total, 1.0)).astype(
         o_ref.dtype)
 
 
-def _walk(q, kc_pool, vc_pool, tables, write_pos, window, *,
-          interpret=False):
+def _walk(q, kc_pool, vc_pool, tables, write_pos, window, *, scale=None,
+          value_dim=None, interpret=False):
     S, H, hd = q.shape
     nb, bs, n_kv, _ = kc_pool.shape
     mb = tables.shape[1]
     rows = bs * n_kv
     nbk = _blocks_a_step(rows, hd, kc_pool.dtype.itemsize, mb)
-    kernel = functools.partial(_kernel, nbk=nbk, n_kv=n_kv, bs=bs, mb=mb)
-    row = pl.BlockSpec((None, H, hd), lambda s, *_: (s, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3, grid=(S,),
-        in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=row,
-        scratch_shapes=[pltpu.VMEM((2, nbk * rows, hd), kc_pool.dtype),
-                        pltpu.VMEM((2, nbk * rows, hd), vc_pool.dtype),
-                        pltpu.SemaphoreType.DMA((2, 2))])
+    kernel = functools.partial(_kernel, nbk=nbk, n_kv=n_kv, bs=bs, mb=mb,
+                               scale=scale, vd=value_dim)
     # a block's lines and heads are one run of bytes in the pool as the
     # scatters write it: [nb, bs, kv, hd] read as [nb, bs * kv, hd]
+    pools = [p.reshape(nb, rows, hd) for p in (kc_pool, vc_pool)
+             if p is not None]
+    row = pl.BlockSpec((None, H, hd), lambda s, *_: (s, 0, 0))
+    out = row if value_dim is None else pl.BlockSpec(
+        (None, H, value_dim), lambda s, *_: (s, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(S,),
+        in_specs=[row] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=out,
+        scratch_shapes=[pltpu.VMEM((2, nbk * rows, hd), p.dtype)
+                        for p in pools]
+        + [pltpu.SemaphoreType.DMA((2, 2))])
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, H, value_dim or hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        name="paged_attention", interpret=interpret,
+        name="paged_attention" if value_dim is None
+        else "paged_latent_attention", interpret=interpret,
     )(tables.astype(jnp.int32), write_pos.astype(jnp.int32),
-      jnp.asarray(window, jnp.int32).reshape(1), q,
-      kc_pool.reshape(nb, rows, hd), vc_pool.reshape(nb, rows, hd))
+      jnp.asarray(window, jnp.int32).reshape(1), q, *pools)
 
 
-def gathered(q, kc_pool, vc_pool, tables, write_pos, window=0):
+def gathered(q, kc_pool, vc_pool, tables, write_pos, window=0, *,
+             scale=None, value_dim=None):
     """The plain form, and the kernel's parity oracle: every slot's view
     gathered through its whole table row (``generation._paged_view``),
     masked by position and scored as every other one-token attention of
@@ -191,22 +208,39 @@ def gathered(q, kc_pool, vc_pool, tables, write_pos, window=0):
 
     bs = kc_pool.shape[1]
     kview = _paged_view(kc_pool, tables, bs)
-    vview = _paged_view(vc_pool, tables, bs)
+    vview = kview[..., :value_dim] if vc_pool is None \
+        else _paged_view(vc_pool, tables, bs)
     at = jnp.arange(kview.shape[1])[None, :]
     window = jnp.asarray(window, jnp.int32)
     ok = (at <= write_pos[:, None]) & (
         (window <= 0) | (write_pos[:, None] - at < window))
-    return _attend_rows(q, kview, vview, ok, q.dtype)
+    return _attend_rows(q, kview, vview, ok, q.dtype, scale)
+
+
+@functools.lru_cache(maxsize=None)
+def _form(f, has_v, scale, value_dim):
+    """``f`` (:func:`_walk` or :func:`gathered`) over the operands that are
+    arrays: a latent cache has no V pool. One function object a form, so
+    that jax traces a branch once for all the layers that call it alike."""
+    def call(q, kc_pool, *rest):
+        *v, tables, write_pos, window = rest
+        return f(q, kc_pool, v[0] if has_v else None, tables, write_pos,
+                 window, scale=scale, value_dim=value_dim)
+    return call
 
 
 def paged_attention(q, kc_pool, vc_pool, tables, write_pos, window=0, *,
-                    interpret=False):
+                    scale=None, value_dim=None, interpret=False):
     """One-token attention over a paged pool: ``q`` ``[S, H, hd]``
     against ``kc_pool`` / ``vc_pool`` ``[n_blocks, block_size, n_kv, hd]``
     through the slots' block tables ``[S, max_blocks]``. Slot ``s`` sees
     the positions ``<= write_pos[s]`` (none where that is negative) and,
     where ``window`` (an int32 scalar, traced or not) is positive, only
     the last ``window`` of them. Returns ``[S, H, hd]`` in ``q``'s type.
+    The scores are over ``sqrt(hd)``, or times ``scale`` where the model
+    states its own. A latent cache passes ``vc_pool`` None and
+    ``value_dim``: a line's values are its first ``value_dim`` numbers,
+    and ``[S, H, value_dim]`` comes back.
 
     The platform decides what runs: on a TPU the kernel, wherever a
     block of the pool fills whole tiles; anywhere else :func:`gathered`.
@@ -215,9 +249,17 @@ def paged_attention(q, kc_pool, vc_pool, tables, write_pos, window=0, *,
     if q.shape[1] % kc_pool.shape[2]:
         raise ValueError(f"{q.shape[1]} query heads over "
                          f"{kc_pool.shape[2]} KV heads")
+    if (vc_pool is None) != (value_dim is not None):
+        raise ValueError("value_dim is given for a pool without V, and "
+                         "for no other")
     args = (q, kc_pool, vc_pool, tables, write_pos, window)
+    how = {"scale": scale, "value_dim": value_dim}
     if interpret:
-        return _walk(*args, interpret=True)
-    if not _tiles(kc_pool.shape, kc_pool.dtype):
-        return gathered(*args)
-    return jax.lax.platform_dependent(*args, tpu=_walk, default=gathered)
+        return _walk(*args, interpret=True, **how)
+    if not _tiles(kc_pool.shape, kc_pool.dtype) or (value_dim or 0) % 128:
+        return gathered(*args, **how)
+
+    form = functools.partial(_form, has_v=vc_pool is not None, **how)
+    return jax.lax.platform_dependent(
+        *(a for a in args if a is not None),
+        tpu=form(_walk), default=form(gathered))

@@ -149,7 +149,8 @@ class AdoptMismatch(RuntimeError):
 # ---------------------------------------------------------------------------
 
 def _layer_body(kind, arch, tp, *, n_heads, n_kv, eps, theta,
-                block_size=None, window=None, moe_k=0, valid=None):
+                block_size=None, window=None, moe_k=0, valid=None,
+                attn_scale=None, router=()):
     """Which per-layer body of ``text/generation.py`` serves a program:
     by the program's ``kind``, the ``arch`` and whether the program is
     sharded (``tp > 1``: the ``_tp`` bodies, run inside ``shard_map``),
@@ -158,11 +159,22 @@ def _layer_body(kind, arch, tp, *, n_heads, n_kv, eps, theta,
     rotary table. A window and routed experts (``moe_k`` and the rows
     that count, ``valid``) exist in the one-device bodies alone:
     ``Engine.__init__`` refuses a model that has them to an engine with
-    ``tp > 1``, and so does this."""
+    ``tp > 1``, and so does this. The latent bodies (``attn_scale``, the
+    model's softmax scale, and ``router``, its further routing arguments)
+    exist for one device and for no verify program."""
     from ..text import generation as G
 
     name = {"prefill": "prefill_layer", "decode": "decode_layer_paged",
             "chunk": "chunk_layer", "verify": "verify_layer"}[kind]
+    if arch == "latent":
+        if tp > 1 or kind == "verify":
+            raise ValueError("the latent bodies are neither tensor-parallel "
+                             "nor a verify program's")
+        kw = dict(n_heads=n_heads, eps=eps, attn_scale=attn_scale,
+                  moe_k=moe_k, valid=valid, router=router)
+        if kind != "prefill":
+            kw["block_size"] = block_size
+        return functools.partial(getattr(G, f"_latent_{name}"), **kw)
     kw = {"n_heads": n_heads}
     if arch == "llama":
         kw.update(n_kv=n_kv, eps=eps, theta=theta)
@@ -199,7 +211,8 @@ def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
                         seed, skip, temp, table_row, skip_write, vmask,
                         moe=None, *, arch, n_heads, n_kv, eps, theta,
                         do_sample, top_k, top_p, block_size, kinds=None,
-                        window=None, moe_k=0, tp=1):
+                        window=None, moe_k=0, tp=1, attn_scale=None,
+                        router=()):
     """Prefill one request (ids [1, Lb], right-padded to its bucket):
     the same full causal forward as ``generate()``'s (so the first
     sampled token is bit-identical to it), sample the first token and
@@ -222,7 +235,9 @@ def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
     ``kinds`` / ``window`` / ``moe_k`` describe a model whose layers
     differ in kind and route their feed-forward (``_make_arch``); its
     counters ``moe`` come in last and go out last, with the picks of the
-    prompt's own positions added.
+    prompt's own positions added. ``attn_scale`` / ``router`` are a
+    latent model's (``arch`` ``latent``: the llama program around the
+    latent bodies, and ``vc`` None, for its cache has one pool).
 
     ``tp > 1``: the program runs INSIDE ``shard_map`` over the ``tp``
     mesh axis (``_tp_jitted``). Every weight leaf and the KV pool arrive
@@ -233,11 +248,11 @@ def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
     from ..text import generation as G
 
     Lb = ids.shape[1]
-    if arch == "llama":
+    if arch != "gpt":
         x = jnp.take(w["embed"], ids, axis=0)
         pos = jnp.arange(Lb)
         real = jnp.arange(Lb) < n_prompt
-        stack, at = G._llama_stack(w), (pos,)       # the rotary positions
+        stack, at = _stack_of(arch, w), (pos,)      # the rotary positions
     else:
         pos = jnp.arange(Lb)
         x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][pos][None]
@@ -246,13 +261,13 @@ def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
     def layer_of(win):
         body = _layer_body("prefill", arch, tp, n_heads=n_heads, n_kv=n_kv,
                            eps=eps, theta=theta, window=win, moe_k=moe_k,
-                           valid=real)
+                           valid=real, attn_scale=attn_scale, router=router)
         return lambda xc, lw: body(xc, lw, *at)
 
     x, kvs = _scan_layers(_by_kind(layer_of, kinds, window), stack, x)
     if moe is not None:
-        moe = _count_picks(moe, kvs[2])
-    if arch == "llama":
+        moe = _count_picks(moe, kvs[-1], real, moe_k)
+    if arch != "gpt":
         hlast = jax.lax.dynamic_index_in_dim(
             G._rms(x, w["norm"], eps)[0], n_prompt - 1, 0, keepdims=False)
     else:
@@ -278,10 +293,17 @@ def _paged_prefill_impl(w, kc, vc, tok, cur_pos, keys, ids, n_prompt, slot,
     # chip run, PR 27), so there is one form
     rows = (dest[None, :] + (nb * bs) * jnp.arange(L)[:, None]).reshape(
         L * Lb)
-    kc = kc.reshape(L * nb * bs, kvh, hd).at[rows].set(
-        kvs[0][:, 0].reshape(L * Lb, kvh, hd)).reshape(L, nb, bs, kvh, hd)
-    vc = vc.reshape(L * nb * bs, kvh, hd).at[rows].set(
-        kvs[1][:, 0].reshape(L * Lb, kvh, hd)).reshape(L, nb, bs, kvh, hd)
+
+    def written(pool, lines):
+        if lines.shape[-1] < hd:       # a line padded to whole lanes
+            lines = jnp.pad(lines, [(0, 0)] * (lines.ndim - 1)
+                            + [(0, hd - lines.shape[-1])])
+        return pool.reshape(L * nb * bs, kvh, hd).at[rows].set(
+            lines[:, 0].reshape(L * Lb, kvh, hd)).reshape(L, nb, bs, kvh, hd)
+
+    kc = written(kc, kvs[0])
+    if vc is not None:
+        vc = written(vc, kvs[1])
 
     key = jax.random.PRNGKey(seed)
     key = jax.lax.fori_loop(0, skip,
@@ -314,10 +336,29 @@ def _by_kind(make, kinds, window):
                  for k in kinds)
 
 
+def _stack_of(arch, w):
+    """The per-layer leaves of a stacked weight tree."""
+    from ..text import generation as G
+
+    return G._latent_stack(w) if arch == "latent" else G._llama_stack(w)
+
+
 def _layer_of(stack, i):
     """Layer ``i``'s leaves of a weight stack whose leaves are ``[L, ...]``
-    arrays or, an expert bank's, a tuple of the layers' own arrays."""
-    return {k: a[i] for k, a in stack.items()}
+    arrays or a tuple of the layers' own arrays (an expert bank's; every
+    leaf of a latent model's, with None where layer ``i`` has no such
+    leaf: those are left out)."""
+    return {k: a[i] for k, a in stack.items()
+            if not isinstance(a, (tuple, list)) or a[i] is not None}
+
+
+def _stacked(ys):
+    """What the layers of an unrolled loop returned, each a flat tuple,
+    stacked ``[L, ...]`` value by value; a value that only some layers
+    return (a routed layer's picks in a model with dense layers too) is
+    stacked over those."""
+    return tuple(jnp.stack([y[i] for y in ys if len(y) > i])
+                 for i in range(max(map(len, ys))))
 
 
 def _scan_layers(layer, stack, x):
@@ -331,7 +372,7 @@ def _scan_layers(layer, stack, x):
     for i, body in enumerate(layer):
         x, y = body(x, _layer_of(stack, i))
         ys.append(y)
-    return x, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+    return x, _stacked(ys)
 
 
 def _scan_layers_over_pool(layer, stack, x, kc, vc, block_ids, row_ids):
@@ -363,19 +404,22 @@ def _scan_layers_over_pool(layer, stack, x, kc, vc, block_ids, row_ids):
 
     Returns ``(x, kc, vc)``, the pools shaped as they came, and after
     them, stacked ``[L, ...]``, whatever a body returns beyond its three
-    (a routed layer's picks)."""
+    (a routed layer's picks). ``vc`` is None where the cache has one pool
+    (a latent model's, whose loop is unrolled), and comes back None."""
     L, nb, bs = kc.shape[:3]
     assert L * nb * bs < 2 ** 31, "pool rows over all layers overflow int32"
     flat = (L * nb,) + kc.shape[2:]
     if isinstance(layer, tuple):
-        k2, v2, more = kc.reshape(flat), vc.reshape(flat), []
+        k2, more = kc.reshape(flat), []
+        v2 = None if vc is None else vc.reshape(flat)
         for i, body in enumerate(layer):
             x, k2, v2, *rest = body(x, _layer_of(stack, i), k2, v2,
                                     block_ids + i * nb,
                                     row_ids + i * (nb * bs))
             more.append(tuple(rest))
-        return (x, k2.reshape(kc.shape), v2.reshape(vc.shape)) + tuple(
-            jax.tree.map(lambda *a: jnp.stack(a), *more))
+        return (x, k2.reshape(kc.shape),
+                None if vc is None else v2.reshape(vc.shape)) \
+            + _stacked(more)
 
     def one(cx, lw_i):
         lw, i = lw_i
@@ -389,10 +433,15 @@ def _scan_layers_over_pool(layer, stack, x, kc, vc, block_ids, row_ids):
     return cx["x"], cx["kc"].reshape(kc.shape), cx["vc"].reshape(vc.shape)
 
 
-def _count_picks(moe, picks, decode=False):
+def _count_picks(moe, picks, valid, k, decode=False):
     """The routed layers' counters (``serving/metrics.py``) after one
-    program call whose rows made ``picks`` ``[L, E]``."""
+    program call whose rows made ``picks`` ``[L, held]``. An engine that
+    holds a share of the experts also counts every pick its ``valid`` rows
+    made, ``k`` a row a layer, on experts held here or not."""
     out = dict(moe, expert_tokens=moe["expert_tokens"] + picks)
+    if "picks" in moe:
+        out["picks"] = moe["picks"] + jnp.sum(
+            valid, dtype=jnp.int32) * (k * picks.shape[0])
     if decode:
         out["experts_hit"] = moe["experts_hit"] + jnp.sum(
             picks > 0, axis=1, dtype=jnp.int32)
@@ -403,7 +452,8 @@ def _count_picks(moe, picks, decode=False):
 def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
                        temps, vmasks, moe=None, *, arch, n_heads, n_kv, eps,
                        theta, do_sample, top_k, top_p, block_size, kinds=None,
-                       window=None, moe_k=0, tp=1):
+                       window=None, moe_k=0, tp=1, attn_scale=None,
+                       router=()):
     """One fused paged decode step: every decode-active slot advances a
     token at its own position, writing K/V through its block table
     (inactive rows scatter into the trash block so a freed slot's stale
@@ -414,8 +464,9 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
     for the life of the engine — the block table is a plain runtime
     operand of static shape; the pool is a carry of the layer loop
     (``_scan_layers_over_pool``), written in place when donated.
-    ``kinds`` / ``window`` / ``moe_k`` / ``moe`` as in the prefill
-    program; the routed layers compute and count the active rows alone.
+    ``kinds`` / ``window`` / ``moe_k`` / ``moe`` / ``attn_scale`` /
+    ``router`` as in the prefill program; the routed layers compute and
+    count the active rows alone.
     ``tp > 1`` as in the prefill program: each device scatters its
     kv-head shard into its pool shard (the LOCAL shard is the carry) and
     attends over its local head group; the o-/down-projections and the
@@ -430,9 +481,9 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
     dest = jnp.where(active, blk * block_size + cur_pos % block_size,
                      cur_pos % block_size)
     seen = jnp.where(active, cur_pos, -1)   # the last position a row sees
-    if arch == "llama":
+    if arch != "gpt":
         xt = jnp.take(w["embed"], tok, axis=0)[:, None]
-        stack = G._llama_stack(w)
+        stack = _stack_of(arch, w)
         at = (seen, cur_pos)            # ... and its rotary position
     else:
         xt = (jnp.take(w["wte"], tok, axis=0)
@@ -442,13 +493,14 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
     def layer_of(win):
         body = _layer_body("decode", arch, tp, n_heads=n_heads, n_kv=n_kv,
                            eps=eps, theta=theta, block_size=block_size,
-                           window=win, moe_k=moe_k, valid=active)
+                           window=win, moe_k=moe_k, valid=active,
+                           attn_scale=attn_scale, router=router)
         return lambda xc, lw, kc_p, vc_p, blocks, rows: body(
             xc, lw, kc_p, vc_p, blocks, rows, *at)
 
     xt, kc, vc, *picks = _scan_layers_over_pool(
         _by_kind(layer_of, kinds, window), stack, xt, kc, vc, tables, dest)
-    if arch == "llama":
+    if arch != "gpt":
         hidden = G._rms(xt[:, 0], w["norm"], eps)
     else:
         hidden = G._ln(xt[:, 0], w["lnfw"], w["lnfb"])
@@ -467,8 +519,8 @@ def _paged_decode_impl(w, kc, vc, tables, tok, cur_pos, active, keys,
     new_keys = jnp.where(active[:, None], new_keys, keys)
     cur2 = jnp.where(active, cur_pos + 1, cur_pos)
     if moe is not None:
-        return nxt, kc, vc, cur2, new_keys, _count_picks(moe, picks[0],
-                                                         decode=True)
+        return nxt, kc, vc, cur2, new_keys, _count_picks(
+            moe, picks[0], active, moe_k, decode=True)
     return nxt, kc, vc, cur2, new_keys
 
 
@@ -476,7 +528,8 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
                       n_prompt, slot, table_row, skip_write, is_final,
                       seed, skip, temp, vmask, moe=None, *, arch, n_heads,
                       n_kv, eps, theta, do_sample, top_k, top_p, block_size,
-                      kinds=None, window=None, moe_k=0, tp=1):
+                      kinds=None, window=None, moe_k=0, tp=1,
+                      attn_scale=None, router=()):
     """One block-aligned prefill CHUNK of one slot, co-schedulable with
     the fused decode step: processes ``ids`` ([1, C], global positions
     ``chunk_start + j``) through every layer, scattering its K/V into
@@ -488,8 +541,8 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
     length. Sampling uses the admission-seeded PRNG chain with the
     supervisor-replay ``skip`` fast-forward, like the one-shot paths.
     The pool is a carry of the layer loop, as in the decode program;
-    ``kinds`` / ``window`` / ``moe_k`` / ``moe`` / ``tp`` as in the
-    prefill one."""
+    ``kinds`` / ``window`` / ``moe_k`` / ``moe`` / ``tp`` /
+    ``attn_scale`` / ``router`` as in the prefill one."""
     from ..text import generation as G
 
     C = ids.shape[1]
@@ -499,9 +552,9 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
                       table_row[gpos // block_size] * block_size
                       + gpos % block_size,
                       gpos % block_size)
-    if arch == "llama":
+    if arch != "gpt":
         x = jnp.take(w["embed"], ids, axis=0)
-        stack = G._llama_stack(w)
+        stack = _stack_of(arch, w)
     else:
         x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][gpos][None]
         stack = {k: w[k] for k in G._GPT_STACK_KEYS}
@@ -511,8 +564,8 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
             body = _layer_body(
                 "chunk", arch, tp, n_heads=n_heads, n_kv=n_kv, eps=eps,
                 theta=theta, block_size=block_size, window=win,
-                moe_k=moe_k,
-                valid=gpos < n_prompt if arch == "llama" else None)
+                moe_k=moe_k, attn_scale=attn_scale, router=router,
+                valid=gpos < n_prompt if arch != "gpt" else None)
             return body(xc, lw, kc_p, vc_p, blocks, gpos, rows)
         return layer
 
@@ -520,7 +573,7 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
         _by_kind(layer_of, kinds, window), stack, x, kc, vc, table_row,
         wdest)
     li = jnp.clip(n_prompt - 1 - chunk_start, 0, C - 1)
-    if arch == "llama":
+    if arch != "gpt":
         hlast = jax.lax.dynamic_index_in_dim(
             G._rms(x, w["norm"], eps)[0], li, 0, keepdims=False)
     else:
@@ -548,7 +601,8 @@ def _paged_chunk_impl(w, kc, vc, tok, cur_pos, keys, ids, chunk_start,
                         cur_pos)
     keys = jnp.where(fin, keys.at[slot].set(key), keys)
     if moe is not None:
-        return kc, vc, tok, cur_pos, keys, tok0, _count_picks(moe, picks[0])
+        return kc, vc, tok, cur_pos, keys, tok0, _count_picks(
+            moe, picks[0], gpos < n_prompt, moe_k)
     return kc, vc, tok, cur_pos, keys, tok0
 
 
@@ -627,7 +681,9 @@ _PAGED_STATICS = _STATICS + ("block_size",)
 # their feed-forward (``_make_arch``); tp: a sharded engine's, baked into
 # its ``shard_map`` programs (``_tp_jitted``). A model or an engine without
 # them passes none, so its programs and their cache keys are what they were
-_PROGRAM_STATICS = _PAGED_STATICS + ("kinds", "window", "moe_k", "tp")
+# attn_scale / router: a latent model's softmax scale and routing arguments
+_PROGRAM_STATICS = _PAGED_STATICS + ("kinds", "window", "moe_k", "tp",
+                                     "attn_scale", "router")
 
 _CODE_TOKEN = None
 
@@ -742,6 +798,20 @@ def _make_arch(model):
                   window=c.sliding_window, moe_k=c.num_experts_per_tok)
         kvh = c.num_key_value_heads
         dtype = w["embed"].dtype
+    elif name == "KimiK2ForCausalLM":
+        # the llama program around the latent bodies: one "KV head" whose
+        # line is the latent beside the shared rotary key, padded to whole
+        # lanes (576 -> 640: the chip lays a narrower line out so anyway),
+        # and no V pool; dense and routed layers as kinds; the softmax
+        # scale and the routing arguments as statics (n_kv and theta are
+        # never read)
+        w = model.stacked_weights()
+        hp = dict(arch="latent", n_heads=c.num_attention_heads, n_kv=1,
+                  eps=c.rms_norm_eps, theta=0.0, kinds=c.layer_kinds(),
+                  moe_k=c.num_experts_per_tok, attn_scale=c.rope()[2],
+                  router=c.router())
+        kvh, hd = 1, -(-(c.kv_lora_rank + c.qk_rope_head_dim) // 128) * 128
+        dtype = w["embed"].dtype
     elif name == "GPTForCausalLM":
         w = G._gpt_stacked_weights(model)
         hp = dict(arch="gpt", n_heads=c.num_attention_heads,
@@ -751,9 +821,16 @@ def _make_arch(model):
     else:
         raise TypeError(
             f"serving.Engine supports LlamaForCausalLM / GPTForCausalLM / "
-            f"MellumForCausalLM, got {name}")
-    geo = dict(n_layers=c.num_hidden_layers, kv_heads=kvh, head_dim=hd,
-               dtype=dtype, max_pos=c.max_position_embeddings)
+            f"MellumForCausalLM / KimiK2ForCausalLM, got {name}")
+    # what one position keeps in one layer's pool, and whether a V pool of
+    # the same lines stands beside it
+    geo = dict(n_layers=c.num_hidden_layers, line=(kvh, hd),
+               values=hp["arch"] != "latent", dtype=dtype,
+               max_pos=c.max_position_embeddings)
+    if hp["arch"] == "latent":
+        # the bytes of a line as published: what a roofline counts
+        geo["line_bytes"] = (c.kv_lora_rank + c.qk_rope_head_dim) \
+            * jnp.dtype(dtype).itemsize
     return w, hp, geo
 
 
@@ -900,11 +977,14 @@ class Engine:
             # a model of layer kinds with routed experts runs through the
             # single-device programs alone: what else it is asked for is
             # refused by name, never served another way
+            lacks = ("no latent attention, no routed share and no shared "
+                     "expert" if self._hp["arch"] == "latent"
+                     else "no routed feed-forward and takes no window")
             for asked, missing in (
                     (int(tp) > 1, "tp > 1: the tensor-parallel bodies have "
-                     "no routed feed-forward and take no window"),
+                     + lacks.replace("takes", "take")),
                     (speculative is not None, "speculative=...: the verify "
-                     "body has no routed feed-forward and takes no window")):
+                     "body has " + lacks)):
                 if asked:
                     raise ValueError(
                         f"serving.Engine cannot serve "
@@ -958,10 +1038,9 @@ class Engine:
                     f"of block_size={self.block_size}")
         self.prefill_chunk = prefill_chunk
         self.cache = PagedKVCache(geo["n_layers"], self.n_slots,
-                                  self.max_len, geo["kv_heads"],
-                                  geo["head_dim"], geo["dtype"],
+                                  self.max_len, geo["line"], geo["dtype"],
                                   block_size=self.block_size,
-                                  n_blocks=n_blocks)
+                                  n_blocks=n_blocks, values=geo["values"])
         self._paged_statics = dict(self._statics,
                                    block_size=self.block_size)
         # threaded device state (numpy until the first jit call)
@@ -1004,11 +1083,23 @@ class Engine:
             # the routed layers' counters live on the device, threaded
             # through the programs beside the pool (last argument in,
             # last value out); only a snapshot fetches them
-            layers, experts = self._w["wr"].shape[0], self._w["wr"].shape[-1]
+            # (a layer's counters are of the experts held here; an engine
+            # that holds a share also counts the picks made in all)
+            wr, held = self._w["wr"], self._w["wg"][-1].shape[0]
+            if isinstance(wr, tuple):      # the layers' own, None if dense
+                routers = [r for r in wr if r is not None]
+                layers, scored = len(routers), routers[0].shape[-1]
+            else:
+                layers, scored = wr.shape[0], wr.shape[-1]
             self.metrics.moe = {
-                "expert_tokens": jnp.zeros((layers, experts), jnp.int32),
+                "expert_tokens": jnp.zeros((layers, held), jnp.int32),
                 "experts_hit": jnp.zeros((layers,), jnp.int32),
                 "decode_calls": jnp.zeros((), jnp.int32)}
+            if held != scored:
+                self.metrics.moe["picks"] = jnp.zeros((), jnp.int32)
+        if "line_bytes" in geo:
+            self.metrics.latent = {"line_bytes": int(geo["line_bytes"]),
+                                   "decode_calls": 0, "lines": 0}
         self._steps = 0           # step() calls so far: the next index
         self._step = None         # index of the step() now running
         # tracer on: spans of the running step's launches, held until
@@ -1213,7 +1304,7 @@ class Engine:
             rep = NamedSharding(self._mesh, P())
         else:
             w = jax.tree_util.tree_map(sds, self._w)
-            kc, vc = sds(self.cache.kc), sds(self.cache.vc)
+            kc, vc = jax.tree.map(sds, (self.cache.kc, self.cache.vc))
         tok = jax.ShapeDtypeStruct((S,), np.int32, sharding=rep)
         cur = jax.ShapeDtypeStruct((S,), np.int32, sharding=rep)
         keys = jax.ShapeDtypeStruct((S, 2), np.uint32, sharding=rep)

@@ -1,7 +1,9 @@
 """The paged KV cache of continuous batching.
 
 :class:`PagedKVCache` holds a fixed ``[n_layers, n_blocks, block_size,
-kv, hd]`` pool plus host-side per-slot block tables (numpy int32). Slots
+*line]`` pool (two, K and V, of ``line = (kv, hd)``; one where a line
+holds all that is kept of a position: a latent and its shared rotary key)
+plus host-side per-slot block tables (numpy int32). Slots
 draw fixed-size blocks on demand, so a request only ever holds
 ``ceil(len/block_size)`` blocks and not worst-case ``max_len`` lines,
 and requests sharing a system prompt share the full blocks of that
@@ -244,10 +246,16 @@ class PagedKVCache:
     ``n_free``/``occupancy``) for the engine and the supervisor, and
     under it the block tables (a static-shape ``[n_slots, max_blocks]``
     int32 jit operand), the refcounted pool and the radix prefix index.
+
+    ``line`` is the shape of what one position keeps in one layer's pool,
+    ``(kv_heads, head_dim)``; ``values`` says whether a second pool of
+    the same shape holds V beside K. A latent cache has ``values=False``:
+    ``vc`` is None and one line is all there is of a position. Blocks,
+    tables and the index count lines, whatever their shape.
     """
 
-    def __init__(self, n_layers, n_slots, max_len, kv_heads, head_dim,
-                 dtype, block_size=16, n_blocks=None):
+    def __init__(self, n_layers, n_slots, max_len, line, dtype,
+                 block_size=16, n_blocks=None, values=True):
         if n_slots < 1:
             raise ValueError("n_slots must be >= 1")
         if max_len < 2:
@@ -257,8 +265,9 @@ class PagedKVCache:
         self.n_layers = int(n_layers)
         self.n_slots = int(n_slots)
         self.max_len = int(max_len)
-        self.kv_heads = int(kv_heads)
-        self.head_dim = int(head_dim)
+        self.line = tuple(int(n) for n in line)
+        self.kv_heads, self.head_dim = self.line
+        self.values = bool(values)
         self.dtype = np.dtype(dtype)
         self.block_size = int(block_size)
         self.max_blocks = -(-self.max_len // self.block_size)
@@ -269,11 +278,11 @@ class PagedKVCache:
             n_blocks = self.n_slots * self.max_blocks + 1
         self.pool = BlockPool(n_blocks)
         self.radix = RadixIndex(self.block_size)
-        shape = (self.n_layers, self.pool.n_blocks, self.block_size,
-                 self.kv_heads, self.head_dim)
+        shape = (self.n_layers, self.pool.n_blocks, self.block_size) \
+            + self.line
         # plain numpy zeros: first jit call device-puts them (no compile)
         self.kc = np.zeros(shape, self.dtype)
-        self.vc = np.zeros(shape, self.dtype)
+        self.vc = np.zeros(shape, self.dtype) if self.values else None
         self.block_tables = np.zeros((self.n_slots, self.max_blocks),
                                      np.int32)      # 0 = trash/unused
         self.cur_pos = np.zeros(self.n_slots, np.int32)
@@ -330,8 +339,10 @@ class PagedKVCache:
         return self._owner[slot]
 
     def nbytes(self):
-        return 2 * self.n_layers * self.pool.n_blocks * self.block_size \
-            * self.kv_heads * self.head_dim * self.dtype.itemsize
+        """What the pools really hold, padding of a line included."""
+        return (1 + self.values) * self.n_layers * self.pool.n_blocks \
+            * self.block_size * self.kv_heads * self.head_dim \
+            * self.dtype.itemsize
 
     # -- paged admission ---------------------------------------------------
 

@@ -185,6 +185,11 @@ class EngineMetrics:
         # ``calls`` fused decode calls; ``in_window`` is the same with
         # each row cut to the model's window (equal without one)
         self.lines_seen = {"calls": 0, "lines": 0, "in_window": 0}
+        # a latent cache's (None elsewhere): the bytes of a line as
+        # published and the latent lines the decode-active rows could
+        # see, kept as ``lines_seen`` is. Such a cache has no K and V
+        # lines, so ``lines_seen`` stays at zero there
+        self.latent = None
         self.kv_pool_bytes_per_device = None
         self.collectives_per_decode_step = None
         # decode-step wall times, histogram-backed: the ~64-observation
@@ -262,6 +267,10 @@ class EngineMetrics:
     def mark_lines_seen(self, seen, window=None):
         """One fused decode call whose active rows see ``seen`` lines
         each (a host array: the position a row writes, plus one)."""
+        if self.latent is not None:
+            self.latent["decode_calls"] += 1
+            self.latent["lines"] += int(seen.sum())
+            return
         t = self.lines_seen
         t["calls"] += 1
         t["lines"] += int(seen.sum())
@@ -294,15 +303,23 @@ class EngineMetrics:
         ``[layer][expert]``: the picks an expert has computed, over every
         prefill, chunk and decode call, padding and idle slots left out;
         ``experts_hit`` ``[layer]``: the distinct experts a decode call
-        touched, summed over ``decode_calls`` calls. None where the
-        model routes nothing."""
+        touched, summed over ``decode_calls`` calls. An engine that
+        holds a share of each layer's experts counts the held ones
+        (``expert_tokens`` ``[routed layer][held]``), and beside them
+        ``picks``, every pick its rows made on any expert, and
+        ``picks_held``, those that landed here. None where the model
+        routes nothing."""
         if self.moe is None:
             return None
         import numpy as np
-        return {"expert_tokens": np.asarray(
-                    self.moe["expert_tokens"]).tolist(),
-                "experts_hit": np.asarray(self.moe["experts_hit"]).tolist(),
-                "decode_calls": int(self.moe["decode_calls"])}
+        out = {"expert_tokens": np.asarray(
+                   self.moe["expert_tokens"]).tolist(),
+               "experts_hit": np.asarray(self.moe["experts_hit"]).tolist(),
+               "decode_calls": int(self.moe["decode_calls"])}
+        if "picks" in self.moe:
+            out["picks"] = int(self.moe["picks"])
+            out["picks_held"] = int(np.sum(out["expert_tokens"]))
+        return out
 
     def snapshot(self):
         n = max(self.samples, 1)
@@ -359,6 +376,7 @@ class EngineMetrics:
             "collectives_per_decode_step":
                 self.collectives_per_decode_step,
             **({} if self.moe is None else {"moe": self.moe_counters()}),
+            **({} if self.latent is None else {"latent": dict(self.latent)}),
         }
 
 

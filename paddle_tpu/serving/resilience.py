@@ -321,7 +321,8 @@ class EngineSupervisor:
             if not where:
                 return
             kc = np.asarray(cache.kc)[:, where]
-            vc = np.asarray(cache.vc)[:, where]
+            # a latent cache has one pool: its lines are all there is
+            vc = kc if cache.vc is None else np.asarray(cache.vc)[:, where]
         else:
             where = np.nonzero(cache.active)[0]
             if len(where) == 0:

@@ -178,8 +178,7 @@ class _ModelDraft:
         S, bs, mb = engine.n_slots, engine.block_size, engine.cache.max_blocks
         self.tables = (1 + mb * np.arange(S)[:, None]
                        + np.arange(mb)[None]).astype(np.int32)
-        shape = (geo["n_layers"], 1 + S * mb, bs, geo["kv_heads"],
-                 geo["head_dim"])
+        shape = (geo["n_layers"], 1 + S * mb, bs) + geo["line"]
         self.kc = np.zeros(shape, geo["dtype"])
         self.vc = np.zeros(shape, geo["dtype"])
         self.tok = np.zeros(S, np.int32)

@@ -110,39 +110,52 @@ def _rotate(q, k, pos, lw, theta, dt, per_row=False):
 _ATTEND_TILE = 2048
 
 
-def _attend(qh, kh, vh, allowed, dt):
-    """Masked softmax attention of ``qh`` ``[B, H, Q, hd]`` over ``kh``,
-    ``vh`` ``[B, n_kv, T, hd]`` under ``allowed`` (bool, broadcast to
-    ``[B, H, Q, T]``) -> ``[B, H, Q, hd]``. Each KV head serves ``H /
-    n_kv`` query heads, and the group contracts against its one head: no
-    copy of K or V per query head is built. A view of more than two
-    ``_ATTEND_TILE`` keys is walked tile by tile (:func:`_attend_tiled`)."""
+def _score_scale(hd, scale):
+    """What float32 scores are scaled by, as a function of them: over
+    ``sqrt(hd)`` where a model states no ``scale`` (the plain attention's),
+    else times it."""
+    if scale is None:
+        by = jnp.sqrt(jnp.float32(hd))
+        return lambda s: s / by
+    by = jnp.float32(scale)
+    return lambda s: s * by
+
+
+def _attend(qh, kh, vh, allowed, dt, scale=None):
+    """Masked softmax attention of ``qh`` ``[B, H, Q, hd]`` over ``kh``
+    ``[B, n_kv, T, hd]`` and ``vh`` ``[B, n_kv, T, vd]`` under ``allowed``
+    (bool, broadcast to ``[B, H, Q, T]``) -> ``[B, H, Q, vd]``. Each KV
+    head serves ``H / n_kv`` query heads, and the group contracts against
+    its one head: no copy of K or V per query head is built. The scores
+    are divided by ``sqrt(hd)``, or multiplied by ``scale`` where a model
+    states its own. A view of more than two ``_ATTEND_TILE`` keys is
+    walked tile by tile (:func:`_attend_tiled`)."""
     B, H, Q, hd = qh.shape
     n_kv, T = kh.shape[1], kh.shape[2]
     if T > 2 * _ATTEND_TILE:
-        return _attend_tiled(qh, kh, vh, allowed, dt)
-    scale = jnp.sqrt(jnp.float32(hd))
+        return _attend_tiled(qh, kh, vh, allowed, dt, scale)
+    scaled = _score_scale(hd, scale)
     if n_kv == H:
-        s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
-                       preferred_element_type=jnp.float32) / scale
+        s = scaled(jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
+                              preferred_element_type=jnp.float32))
         p = jax.nn.softmax(jnp.where(allowed, s, -1e30), axis=-1).astype(dt)
         return jnp.einsum("bhqk,bhkd->bhqd", p, vh)
     g = H // n_kv
-    s = jnp.einsum("bngqd,bnkd->bngqk", qh.reshape(B, n_kv, g, Q, hd), kh,
-                   preferred_element_type=jnp.float32).reshape(
-                       B, H, Q, -1) / scale
+    s = scaled(jnp.einsum(
+        "bngqd,bnkd->bngqk", qh.reshape(B, n_kv, g, Q, hd), kh,
+        preferred_element_type=jnp.float32).reshape(B, H, Q, -1))
     p = jax.nn.softmax(jnp.where(allowed, s, -1e30), axis=-1).astype(dt)
     return jnp.einsum("bngqk,bnkd->bngqd", p.reshape(B, n_kv, g, Q, -1),
-                      vh).reshape(B, H, Q, hd)
+                      vh).reshape(B, H, Q, vh.shape[-1])
 
 
-def _attend_tiled(qh, kh, vh, allowed, dt):
+def _attend_tiled(qh, kh, vh, allowed, dt, scale=None):
     """:func:`_attend` over ``T / _ATTEND_TILE`` tiles of keys, one after
     the other, carrying each row's running maximum, its sum of exponentials
     and its weighted values in float32 (the online softmax): the same sum,
     with scores never wider than a tile."""
     B, H, Q, hd = qh.shape
-    n_kv, T = kh.shape[1], kh.shape[2]
+    n_kv, T, vd = kh.shape[1], kh.shape[2], vh.shape[-1]
     ok = jnp.broadcast_to(allowed, allowed.shape[:-2] + (Q, T))
     pad = -T % _ATTEND_TILE
     if pad:                  # whole tiles: keys that no row may see
@@ -151,20 +164,21 @@ def _attend_tiled(qh, kh, vh, allowed, dt):
         ok = jnp.pad(ok, [(0, 0)] * (ok.ndim - 1) + [(0, pad)])
         T += pad
     g, nt = H // n_kv, T // _ATTEND_TILE
-    scale = jnp.sqrt(jnp.float32(hd))
+    scaled = _score_scale(hd, scale)
     qg = qh.reshape(B, n_kv, g, Q, hd)
     ok = jnp.moveaxis(ok.reshape(ok.shape[:-1] + (nt, _ATTEND_TILE)), -2, 0)
     if ok.ndim == 5:                      # [nt, B|1, H|1, Q, tile]
         ok = ok[:, :, :, None]            # over the group, beside n_kv
 
     def tiles(a):                         # [B, n, T, hd] -> [nt, B, n, t, hd]
-        return jnp.moveaxis(a.reshape(B, n_kv, nt, _ATTEND_TILE, hd), 2, 0)
+        return jnp.moveaxis(
+            a.reshape(B, n_kv, nt, _ATTEND_TILE, a.shape[-1]), 2, 0)
 
     def one(carry, tile):
         top, total, acc = carry
         k_t, v_t, ok_t = tile
-        s = jnp.einsum("bngqd,bnkd->bngqk", qg, k_t,
-                       preferred_element_type=jnp.float32) / scale
+        s = scaled(jnp.einsum("bngqd,bnkd->bngqk", qg, k_t,
+                              preferred_element_type=jnp.float32))
         s = jnp.where(ok_t, s, -1e30)
         top2 = jnp.maximum(top, jnp.max(s, axis=-1))
         # a key that may not be seen weighs nothing, also in a tile that
@@ -178,34 +192,35 @@ def _attend_tiled(qh, kh, vh, allowed, dt):
 
     start = (jnp.full((B, n_kv, g, Q), -1e30, jnp.float32),
              jnp.zeros((B, n_kv, g, Q), jnp.float32),
-             jnp.zeros((B, n_kv, g, Q, hd), jnp.float32))
+             jnp.zeros((B, n_kv, g, Q, vd), jnp.float32))
     (_, total, acc), _ = jax.lax.scan(one, start,
                                       (tiles(kh), tiles(vh), ok))
     return (acc / jnp.maximum(total, 1e-30)[..., None]).astype(dt).reshape(
-        B, H, Q, hd)
+        B, H, Q, vd)
 
 
-def _attend_rows(q, kview, vview, valid, dt):
+def _attend_rows(q, kview, vview, valid, dt, scale=None):
     """One query token a row: ``q`` ``[S, H, hd]`` over the row's view
-    ``kview``, ``vview`` ``[S, T, n_kv, hd]`` under ``valid`` ``[S, T]``
-    -> ``[S, H, hd]``; grouped as :func:`_attend`."""
+    ``kview`` ``[S, T, n_kv, hd]``, ``vview`` ``[S, T, n_kv, vd]`` under
+    ``valid`` ``[S, T]`` -> ``[S, H, vd]``; grouped and scaled as
+    :func:`_attend`."""
     S, H, hd = q.shape
     n_kv = kview.shape[2]
-    scale = jnp.sqrt(jnp.float32(hd))
+    scaled = _score_scale(hd, scale)
     if n_kv == H:
-        s = jnp.einsum("bhd,bthd->bht", q, kview,
-                       preferred_element_type=jnp.float32) / scale
+        s = scaled(jnp.einsum("bhd,bthd->bht", q, kview,
+                              preferred_element_type=jnp.float32))
         s = jnp.where(valid[:, None, :], s, -1e30)
         p = jax.nn.softmax(s, axis=-1).astype(dt)
         return jnp.einsum("bht,bthd->bhd", p, vview)
     g = H // n_kv
-    s = jnp.einsum("bngd,btnd->bngt", q.reshape(S, n_kv, g, hd), kview,
-                   preferred_element_type=jnp.float32).reshape(
-                       S, H, -1) / scale
+    s = scaled(jnp.einsum(
+        "bngd,btnd->bngt", q.reshape(S, n_kv, g, hd), kview,
+        preferred_element_type=jnp.float32).reshape(S, H, -1))
     s = jnp.where(valid[:, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(dt)
     return jnp.einsum("bngt,btnd->bngd", p.reshape(S, n_kv, g, -1),
-                      vview).reshape(S, H, hd)
+                      vview).reshape(S, H, vview.shape[-1])
 
 
 def _window_blocks(window, rows, block_size):
@@ -232,19 +247,30 @@ def _window_tables(tables, first_pos, rows, window, block_size):
         (first * block_size)[:, None]
 
 
-def _feed_forward(h2, lw, moe_k, valid):
+def _feed_forward(h2, lw, moe_k, valid, router=()):
     """The layer's second half on normed rows ``h2``: the dense SwiGLU of
     ``wg``/``wu``/``wd``, or, where the layer carries a router ``wr``
-    and expert banks, the routed one (``nn/routed_ffn.py``: every pick
-    computed, none dropped; ``valid`` marks the rows that are tokens).
-    Returns ``(y, picks)``, ``picks`` ``[E]`` rows an expert or None."""
+    and expert banks, the routed one (``nn/routed_ffn.py``: every pick on
+    an expert held here computed, none dropped; ``valid`` marks the rows
+    that are tokens; ``router``, pairs, are the model's further arguments
+    of ``routed_ffn``, and ``lw["rb"]`` its selection bias), beside it
+    the shared expert ``sg``/``su``/``sd`` where the layer has one.
+    Returns ``(y, picks)``, ``picks`` ``[held]`` rows an expert or None."""
+    def swiglu(wg, wu, wd):
+        return (jax.nn.silu(h2 @ wg) * (h2 @ wu)) @ wd
+
     if "wr" not in lw:
-        return (jax.nn.silu(h2 @ lw["wg"]) * (h2 @ lw["wu"])) @ lw["wd"], \
-            None
+        return swiglu(lw["wg"], lw["wu"], lw["wd"]), None
     from ..nn.routed_ffn import routed_ffn
+    router = dict(router)
+    if "rb" in lw:
+        router["bias"] = lw["rb"]
     y, picks = routed_ffn(h2.reshape(-1, h2.shape[-1]), lw["wr"], lw["wg"],
-                          lw["wu"], lw["wd"], moe_k, valid)
-    return y.reshape(h2.shape), picks
+                          lw["wu"], lw["wd"], moe_k, valid, **router)
+    y = y.reshape(h2.shape)
+    if "sg" in lw:
+        y = y + swiglu(lw["sg"], lw["su"], lw["sd"])
+    return y, picks
 
 
 def _nucleus_filter(logits, top_p):
@@ -690,6 +716,201 @@ def _gpt_verify_layer(x, lw, kc_pool, vc_pool, table_row, gpos, wdest, *,
     :func:`_llama_verify_layer`): shares the chunk-layer math."""
     return _gpt_chunk_layer(x, lw, kc_pool, vc_pool, table_row, gpos,
                             wdest, n_heads=n_heads, block_size=block_size)
+
+
+# ---------------------------------------------------------------------------
+# latent-attention bodies (MLA): low-rank q and kv projections with their
+# norms, a decoupled rotary part that all heads share, and a cache whose
+# line is the normed latent ``c`` beside the rotated ``k_pe``: one "KV
+# head", no V. Prefill and the chunk expand the latents to per-head K and
+# V (the plain form); decode absorbs ``kv_b`` into the query and the output
+# and reads the lines as they lie. A layer's feed-forward is dense, or a
+# routed share beside a shared expert (:func:`_feed_forward`).
+# ---------------------------------------------------------------------------
+
+_LATENT_KEYS = ("ln1", "wqa", "qln", "wqb", "wkva", "kvln", "wkvb", "wo",
+                "ln2", "wg", "wu", "wd", "wr", "rb", "sg", "su", "sd",
+                "rope_inv", "rope_scale")
+
+#: Lines of the cached prefix that the chunk body expands and scores at a
+#: time. It walks only the tiles that hold a key one of its rows may see
+#: (a ``fori_loop`` to the tile of its last position), so a chunk at
+#: position 5 k of a 16 k table expands 3 tiles, not 8.
+_LATENT_TILE = 2048
+
+
+def _latent_stack(w):
+    """The per-layer leaves of a latent model's weight tree: tuples of the
+    layers' own arrays, ``None`` where a layer has no such leaf."""
+    return {k: w[k] for k in _LATENT_KEYS if k in w}
+
+
+def _latent_project(h1, lw, pos, *, n_heads, eps, per_row=False):
+    """The attention half's projections of normed rows ``h1`` ``[B, L,
+    h]``: ``(q_nope [B, L, H, dn], q_pe [B, L, H, dr], c [B, L, r], k_pe
+    [B, L, 1, dr])``, q through its low rank and norm, ``c`` the normed
+    latent, the rotary parts rotated at ``pos`` (``[L]``, or ``[B]`` with
+    ``per_row``) by the layer's own table. ``(c, k_pe)`` is the position's
+    cache line; ``k_pe`` is one head that all ``H`` share."""
+    B, L = h1.shape[:2]
+    r = lw["kvln"].shape[0]
+    q = (_rms(h1 @ lw["wqa"], lw["qln"], eps) @ lw["wqb"]).reshape(
+        B, L, n_heads, -1)
+    kva = h1 @ lw["wkva"]
+    dr = kva.shape[-1] - r
+    c = _rms(kva[..., :r], lw["kvln"], eps)
+    q_pe, k_pe = _rotate(q[..., -dr:], kva[..., None, r:], pos, lw, 0.0,
+                         h1.dtype, per_row=per_row)
+    return q[..., :-dr], q_pe, c, k_pe
+
+
+def _latent_line(c, k_pe, width):
+    """The cache lines ``[..., 1, width]`` of ``c`` ``[..., r]`` and
+    ``k_pe`` ``[..., 1, dr]``: ``c``, then ``k_pe``, then zeros up to the
+    pool's width (whole lanes)."""
+    pad = width - c.shape[-1] - k_pe.shape[-1]
+    return jnp.concatenate(
+        [c[..., None, :], k_pe,
+         jnp.zeros(k_pe.shape[:-1] + (pad,), c.dtype)], axis=-1)
+
+
+def _kv_b(lw, n_heads):
+    """``kv_b_proj`` ``[r, H, dn + dv]``: head ``h``'s ``Wk_h`` and
+    ``Wv_h`` side by side."""
+    return lw["wkvb"].reshape(lw["wkvb"].shape[0], n_heads, -1)
+
+
+def _latent_prefill_layer(x, lw, pos, *, n_heads, eps, attn_scale, moe_k=0,
+                          valid=None, router=()):
+    """One latent-attention layer over a full ``[B, L]`` prompt, the plain
+    form: the latents expanded to per-head K (score width ``dn + dr``)
+    and V (``dv``), causal. Returns ``(x, (lines,))``, the lines ``[B, L,
+    1, r + dr]`` for the cache, and the picks after them where the layer
+    routes."""
+    B, L, _ = x.shape
+    dt = x.dtype
+    q_nope, q_pe, c, k_pe = _latent_project(
+        _rms(x, lw["ln1"], eps), lw, pos, n_heads=n_heads, eps=eps)
+    dn = q_nope.shape[-1]
+    kv = jnp.einsum("blr,rhd->blhd", c, _kv_b(lw, n_heads))
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_pe, q_pe.shape)], axis=-1)
+    o = _attend(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                jnp.swapaxes(kv[..., dn:], 1, 2),
+                jnp.tril(jnp.ones((L, L), bool)), dt, attn_scale)
+    x = x + jnp.swapaxes(o, 1, 2).reshape(B, L, -1) @ lw["wo"]
+    y, picks = _feed_forward(_rms(x, lw["ln2"], eps), lw, moe_k, valid,
+                             router)
+    line = _latent_line(c, k_pe, c.shape[-1] + k_pe.shape[-1])
+    return x + y, ((line,) if picks is None else (line, picks))
+
+
+def _latent_decode_layer_paged(xt, lw, pool, no_v, tables, dest, write_pos,
+                               rope_pos, *, n_heads, eps, block_size,
+                               attn_scale, moe_k=0, valid=None, router=()):
+    """One latent-attention layer advancing every slot one token, the
+    absorbed form: the new line scatters to flat pool index ``dest``, the
+    query carries ``(q_nope Wk_h^T, q_pe)`` against the lines as they lie
+    (one KV head under ``H`` query heads, the values the first ``r``
+    numbers of the same line: ``ops/pallas/paged_attention.py`` reads the
+    live blocks in place) and ``Wv_h`` multiplies the weighted latents
+    after. ``pool`` ``[n_blocks, bs, 1, width]`` is the one pool there is
+    (``no_v``, None, is the V pool that a latent cache has not)."""
+    from ..ops.pallas import paged_attention as kernel
+
+    S = xt.shape[0]
+    q_nope, q_pe, c, k_pe = _latent_project(
+        _rms(xt, lw["ln1"], eps), lw, rope_pos, n_heads=n_heads, eps=eps,
+        per_row=True)
+    nb, bs, _, width = pool.shape
+    r, dn = c.shape[-1], q_nope.shape[-1]
+    pool = pool.reshape(nb * bs, 1, width).at[dest].set(
+        _latent_line(c, k_pe, width)[:, 0]).reshape(nb, bs, 1, width)
+    wkv = _kv_b(lw, n_heads)
+    q = _latent_line(jnp.einsum("shd,rhd->shr", q_nope[:, 0], wkv[..., :dn]),
+                     q_pe[:, 0, :, None], width)[:, :, 0]
+    o = kernel.paged_attention(q, pool, None, tables, write_pos,
+                               scale=attn_scale, value_dim=r)
+    o = jnp.einsum("shr,rhd->shd", o, wkv[..., dn:]).reshape(S, 1, -1)
+    xt2 = xt + o @ lw["wo"]
+    y, picks = _feed_forward(_rms(xt2, lw["ln2"], eps), lw, moe_k, valid,
+                             router)
+    xt2 = xt2 + y
+    return (xt2, pool, no_v) if picks is None else (xt2, pool, no_v, picks)
+
+
+def _latent_chunk_attention(q_nope, q_pe, pool, table_row, gpos, wkv, scale,
+                            dt):
+    """The chunk's rows against the slot's cached lines, the plain form a
+    tile at a time: ``q_nope`` ``[C, H, dn]`` and ``q_pe`` ``[C, H, dr]``
+    at positions ``gpos``; each tile of ``_LATENT_TILE`` lines is gathered
+    through ``table_row``, expanded by ``wkv`` ``[r, H, dn + dv]`` and
+    folded into a float32 online softmax under ``line <= gpos``. Tiles
+    beyond the chunk's last position are not walked. -> ``[C, H, dv]``."""
+    C, H, dn = q_nope.shape
+    nb, bs, _, width = pool.shape
+    r, dr = wkv.shape[0], q_pe.shape[-1]
+    dv = wkv.shape[-1] - dn
+    blocks = max(min(_LATENT_TILE // bs, table_row.shape[0]), 1)
+    tile = blocks * bs
+    table = jnp.pad(table_row, (0, -table_row.shape[0] % blocks))
+    flat = pool.reshape(nb * bs, width)
+
+    def one(t, carry):
+        top, total, acc = carry
+        rows = (jax.lax.dynamic_slice_in_dim(table, t * blocks, blocks)[
+            :, None] * bs + jnp.arange(bs)).reshape(tile)
+        lines = flat[rows]
+        kv = jnp.einsum("kr,rhd->khd", lines[:, :r], wkv)
+        s = (jnp.einsum("qhd,khd->hqk", q_nope, kv[..., :dn],
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("qhd,kd->hqk", q_pe, lines[:, r:r + dr],
+                          preferred_element_type=jnp.float32)) \
+            * jnp.float32(scale)
+        ok = (t * tile + jnp.arange(tile))[None, :] <= gpos[:, None]
+        # the mask is applied where the scores are read, twice, not to a
+        # copy of them: a twentieth off the softmax fusion (0.104 -> 0.098
+        # s of a traced window, my chip runs, PR 32)
+        top2 = jnp.maximum(top, jnp.max(jnp.where(ok, s, -1e30), axis=-1))
+        p = jnp.where(ok, jnp.exp(s - top2[..., None]), 0.0)
+        shrink = jnp.exp(top - top2)
+        acc = acc * shrink[..., None] + jnp.einsum(
+            "hqk,khd->hqd", p.astype(dt), kv[..., dn:],
+            preferred_element_type=jnp.float32)
+        return top2, total * shrink + jnp.sum(p, axis=-1), acc
+
+    start = (jnp.full((H, C), -1e30, jnp.float32),
+             jnp.zeros((H, C), jnp.float32),
+             jnp.zeros((H, C, dv), jnp.float32))
+    _, total, acc = jax.lax.fori_loop(0, jnp.max(gpos) // tile + 1, one,
+                                      start)
+    return jnp.swapaxes(acc / jnp.maximum(total, 1e-30)[..., None], 0,
+                        1).astype(dt)
+
+
+def _latent_chunk_layer(x, lw, pool, no_v, table_row, gpos, wdest, *, n_heads,
+                        eps, block_size, attn_scale, moe_k=0, valid=None,
+                        router=()):
+    """One latent-attention layer over one block-aligned prefill chunk of
+    a single slot: x ``[1, C, h]`` at global positions ``gpos``; the
+    chunk's lines scatter to flat pool indices ``wdest``, then its rows
+    attend to the slot's cached lines, earlier chunks' and its own
+    (:func:`_latent_chunk_attention`). Operands and returns as
+    :func:`_latent_decode_layer_paged`."""
+    B, C, _h = x.shape
+    q_nope, q_pe, c, k_pe = _latent_project(
+        _rms(x, lw["ln1"], eps), lw, gpos, n_heads=n_heads, eps=eps)
+    nb, bs, _, width = pool.shape
+    pool = pool.reshape(nb * bs, 1, width).at[wdest].set(
+        _latent_line(c, k_pe, width)[0]).reshape(nb, bs, 1, width)
+    o = _latent_chunk_attention(q_nope[0], q_pe[0], pool, table_row, gpos,
+                                _kv_b(lw, n_heads), attn_scale, x.dtype)
+    x = x + o.reshape(B, C, -1) @ lw["wo"]
+    y, picks = _feed_forward(_rms(x, lw["ln2"], eps), lw, moe_k, valid,
+                             router)
+    x = x + y
+    return (x, pool, no_v) if picks is None else (x, pool, no_v, picks)
 
 
 # ---------------------------------------------------------------------------

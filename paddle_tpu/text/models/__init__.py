@@ -6,6 +6,9 @@ from .ernie_moe import (  # noqa: F401
     ERNIE_MOE_TINY, ErnieMoEConfig, ErnieMoEForPretraining, ErnieMoEModel,
 )
 from .gpt import GPT_TINY, GPTConfig, GPTForCausalLM, GPTModel  # noqa: F401
+from .kimi_k2 import (  # noqa: F401
+    KIMI_K2_TINY, KimiK2Config, KimiK2ForCausalLM,
+)
 from .llama import (  # noqa: F401
     LLAMA2_7B, LLAMA2_13B, LLAMA_TINY, LlamaConfig, LlamaForCausalLM,
     LlamaModel,

@@ -92,8 +92,8 @@ def test_radix_match_insert_evict():
 
 
 def test_paged_cache_admit_and_free_invariants():
-    c = PagedKVCache(n_layers=2, n_slots=2, max_len=32, kv_heads=2,
-                     head_dim=4, dtype=np.float32, block_size=8)
+    c = PagedKVCache(n_layers=2, n_slots=2, max_len=32, line=(2, 4),
+                     dtype=np.float32, block_size=8)
     assert c.max_blocks == 4 and c.pool.n_blocks == 9
     s = c.alloc("r0")
     toks = np.arange(11, dtype=np.int32)

@@ -408,6 +408,104 @@ def test_routed_program_compiles_and_copies_neither_pool_nor_banks(
         assert not re.search(r"= bf16\[16,\d+,4,128\]\S* gather\(", text)
 
 
+# the latent serving cell's engine: hidden 7168, 64 heads over a latent of
+# 512 beside a shared rotary key of 64 (a line padded to 640), q through a
+# rank of 1536; one dense layer of 18432 then four routed ones holding 12
+# of 384 experts of 2048 beside a shared expert; a 20480-row slice of the
+# head; 32 slots x 16384, block 16, chunk 512
+_K_LAYERS, _K_HIDDEN, _K_HEADS, _K_HELD, _K_SCORED, _K_VOCAB = 5, 7168, 64, \
+    12, 384, 20480
+_K_BLOCKS, _K_TABLE, _K_SLOTS, _K_LINE = 32769, 1024, 32, 640
+_K_POOL = (_K_LAYERS, _K_BLOCKS, _BS, 1, _K_LINE)
+
+
+def _latent_program(kind):
+    """A paged program of the engine for a latent model, as the engine
+    jits it: every per-layer leaf a tuple of the layers' own arrays (None
+    where a layer has no such leaf), one pool and None for the V pool, the
+    counters of a share last."""
+    from paddle_tpu.serving import engine as E
+
+    L, S, V, h = _K_LAYERS, _K_SLOTS, _K_VOCAB, _K_HIDDEN
+    bf, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+    every = {"ln1": (h,), "wqa": (h, 1536), "qln": (1536,),
+             "wqb": (1536, _K_HEADS * 192), "wkva": (h, 576), "kvln": (512,),
+             "wkvb": (512, _K_HEADS * 256), "wo": (_K_HEADS * 128, h),
+             "ln2": (h,)}
+    w = {k: [(s, bf)] * L for k, s in every.items()}
+    w.update(rope_inv=[((32,), f32)] * L, rope_scale=[((), f32)] * L)
+    dense = {"wg": (h, 18432), "wu": (h, 18432), "wd": (18432, h)}
+    routed = {"wg": (_K_HELD, h, 2048), "wu": (_K_HELD, h, 2048),
+              "wd": (_K_HELD, 2048, h), "wr": (h, _K_SCORED),
+              "rb": (_K_SCORED,), "sg": (h, 2048), "su": (h, 2048),
+              "sd": (2048, h)}
+    for k, s in routed.items():
+        w[k] = [(dense[k], bf) if k in dense else None] + [(s, bf)] * (L - 1)
+    w.update(embed=((V, h), bf), norm=((h,), bf), head=((h, V), bf))
+    pool, scalar = (_K_POOL, bf), ((), i32)
+    slots, keys = ((S,), i32), ((S, 2), jnp.uint32)
+    moe = {"expert_tokens": ((L - 1, _K_HELD), i32),
+           "experts_hit": ((L - 1,), i32), "decode_calls": ((), i32),
+           "picks": ((), i32)}
+    statics = dict(arch="latent", n_heads=_K_HEADS, n_kv=1, eps=1e-5,
+                   theta=0.0, do_sample=False, top_k=0, top_p=None,
+                   block_size=_BS, kinds=("dense",) + ("routed",) * (L - 1),
+                   moe_k=8, attn_scale=0.14467962580268923,
+                   router=(("first", 0), ("scale", 2.827),
+                           ("scoring", "sigmoid")))
+    row, temp, vmask = ((_K_TABLE,), i32), ((), f32), ((V,), f32)
+    if kind == "decode":
+        return (E._PAGED_DECODE_DONATED,
+                [w, pool, None, ((S, _K_TABLE), i32), slots, slots,
+                 ((S,), jnp.bool_), keys, ((S,), f32), ((S, V), f32), moe],
+                statics)
+    if kind == "chunk":
+        return (E._PAGED_CHUNK_DONATED,
+                [w, pool, None, slots, slots, keys, ((1, 512), i32),
+                 scalar, scalar, scalar, row, scalar, scalar,
+                 ((), jnp.uint32), scalar, temp, vmask, moe], statics)
+    return (E._PAGED_PREFILL_DONATED,
+            [w, pool, None, slots, slots, keys, ((1, 512), i32), scalar,
+             scalar, ((), jnp.uint32), scalar, temp, row, scalar, vmask, moe],
+            statics)
+
+
+@pytest.mark.parametrize("kind", ("decode", "chunk", "prefill"))
+def test_latent_program_compiles_and_moves_neither_pool_nor_banks(
+        kind, one_chip, no_compile_cache):
+    """At the published widths and the cell's sizes: the one pool (3.36
+    GB) is donated and written in place, never copied or relaid whole, no
+    layer's held banks are copied, the three programs fit beside 10.4 GB
+    of weights and pool, the decode program holds the latent kernel once
+    a layer and no ``[slots, max_len, ...]`` view of the lines, and the
+    chunk expands no more than a tile of the cached prefix at a time."""
+    fn, shapes, statics = _latent_program(kind)
+    args = jax.tree.map(
+        lambda sd: jax.ShapeDtypeStruct(*sd, sharding=one_chip), shapes,
+        is_leaf=lambda x: isinstance(x, tuple) and len(x) == 2
+        and isinstance(x[1], type(jnp.bfloat16)))
+    compiled = fn.lower(*args, **statics).compile()
+    text = compiled.as_text()
+    pool_elements = int(np.prod(_K_POOL))
+    bank_elements = _K_HELD * _K_HIDDEN * 2048
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_elements       # donated
+    assert mem.temp_size_in_bytes < 2.5e9
+    moved = []
+    for shape, op in re.findall(
+            r"= \w+\[([\d,]+)\]\S* (copy|slice|dynamic-slice|"
+            r"dynamic-update-slice)\(", text):
+        n = int(np.prod([int(d) for d in shape.split(",")]))
+        if n in (pool_elements, pool_elements // _K_LAYERS, bank_elements):
+            moved.append((op, shape))
+    assert not moved
+    if kind == "decode":
+        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                              text)) == _K_LAYERS
+        assert "paged_latent_attention" in text
+        assert not re.search(r"\[32,16384,", text)
+
+
 _LOWER = """
 import json, os, sys
 root = os.path.dirname(os.path.abspath(__file__))
