@@ -514,6 +514,8 @@ def _kernel_cases(cfg, seqlen, max_len, seed):
 
     from paddle_tpu.nn.functional.attention import _xla_sdpa
     from paddle_tpu.nn.quant import quantize_int8
+    from paddle_tpu.ops.pallas.chunk_attention import (
+        chunk_attention, plain as chunk_plain)
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.paged_attention import (gathered,
                                                        paged_attention)
@@ -559,6 +561,25 @@ def _kernel_cases(cfg, seqlen, max_len, seed):
                 jnp.asarray(rng.integers(1, pool[0], (slots, mb)), jnp.int32),
                 jnp.asarray(rng.integers(0, mb * bs, (slots,)), jnp.int32),
                 jnp.int32(window)))
+
+    # a latent chunk body's tile (kimi-k2.6: 64 heads x 512 rows, 192
+    # against 128, 2048 cached lines of 512 + 64 padded to 640), folded
+    # into a state that has seen lines already
+    def chunk_kernel(q, q_shared, lines, w, gpos, first, *carry):
+        return chunk_attention(q, q_shared, lines, w, gpos, first, carry,
+                               scale=0.1447)
+
+    def chunk_reference(*args):
+        return chunk_plain(*args, scale=0.1447)
+
+    f32 = jnp.float32
+    yield ("chunk_attention", chunk_kernel, chunk_reference,
+           (normal((64, 512, 128)), normal((64, 512, 64)),
+            normal((2048, 640)), normal((512, 64, 256), 512 ** -0.5),
+            5000 + jnp.arange(512, dtype=jnp.int32), jnp.int32(4096),
+            normal((64, 512), dtype=f32),
+            1.0 + jnp.abs(normal((64, 512), dtype=f32)),
+            normal((64, 512, 128), dtype=f32)))
 
     ce = (normal((seqlen, hidden)), normal((hidden, vocab), 0.02),
           jnp.asarray(rng.integers(0, vocab, (seqlen,)), jnp.int32))

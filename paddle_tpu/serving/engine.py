@@ -1098,8 +1098,8 @@ class Engine:
             if held != scored:
                 self.metrics.moe["picks"] = jnp.zeros((), jnp.int32)
         if "line_bytes" in geo:
-            self.metrics.latent = {"line_bytes": int(geo["line_bytes"]),
-                                   "decode_calls": 0, "lines": 0}
+            self.metrics.latent = EngineMetrics.latent_counters(
+                int(geo["line_bytes"]))
         self._steps = 0           # step() calls so far: the next index
         self._step = None         # index of the step() now running
         # tracer on: spans of the running step's launches, held until
@@ -1680,7 +1680,7 @@ class Engine:
             radix_tokens=max(0, min(cs.n_shared, end) - start),
             start=start, final=is_final)
         self.chunk_used = True
-        self.metrics.chunk_steps += 1
+        self.metrics.mark_chunk(end)
         cs.next = end
         if is_final:
             self._chunking.pop(0)

@@ -185,10 +185,9 @@ class EngineMetrics:
         # ``calls`` fused decode calls; ``in_window`` is the same with
         # each row cut to the model's window (equal without one)
         self.lines_seen = {"calls": 0, "lines": 0, "in_window": 0}
-        # a latent cache's (None elsewhere): the bytes of a line as
-        # published and the latent lines the decode-active rows could
-        # see, kept as ``lines_seen`` is. Such a cache has no K and V
-        # lines, so ``lines_seen`` stays at zero there
+        # a latent cache's (None elsewhere; ``latent_counters``). Such a
+        # cache has no K and V lines, so ``lines_seen`` stays at zero
+        # there
         self.latent = None
         self.kv_pool_bytes_per_device = None
         self.collectives_per_decode_step = None
@@ -263,6 +262,24 @@ class EngineMetrics:
         per = duration_s / n
         for _ in range(n):
             self.itl_hist.observe(per)
+
+    @staticmethod
+    def latent_counters(line_bytes):
+        """What a latent cache counts: the bytes of a line as published;
+        the latent lines the decode-active rows could see, kept as
+        ``lines_seen`` is, over ``decode_calls`` fused decode calls; and
+        the lines the last row of a prefill chunk could see, over
+        ``chunk_calls`` calls of the chunk program."""
+        return {"line_bytes": line_bytes, "decode_calls": 0, "lines": 0,
+                "chunk_calls": 0, "chunk_lines": 0}
+
+    def mark_chunk(self, end):
+        """One call of the chunk program, over the positions before
+        ``end``: the last row sees ``end`` lines."""
+        self.chunk_steps += 1
+        if self.latent is not None:
+            self.latent["chunk_calls"] += 1
+            self.latent["chunk_lines"] += int(end)
 
     def mark_lines_seen(self, seen, window=None):
         """One fused decode call whose active rows see ``seen`` lines

@@ -846,45 +846,29 @@ def _latent_chunk_attention(q_nope, q_pe, pool, table_row, gpos, wkv, scale,
     tile at a time: ``q_nope`` ``[C, H, dn]`` and ``q_pe`` ``[C, H, dr]``
     at positions ``gpos``; each tile of ``_LATENT_TILE`` lines is gathered
     through ``table_row``, expanded by ``wkv`` ``[r, H, dn + dv]`` and
-    folded into a float32 online softmax under ``line <= gpos``. Tiles
-    beyond the chunk's last position are not walked. -> ``[C, H, dv]``."""
+    folded into a float32 online softmax under ``line <= gpos``
+    (``ops/pallas/chunk_attention.py``: on a TPU one kernel a tile, the
+    scores never an array). Tiles beyond the chunk's last position are not
+    walked. -> ``[C, H, dv]``."""
+    from ..ops.pallas import chunk_attention as kernel
+
     C, H, dn = q_nope.shape
     nb, bs, _, width = pool.shape
-    r, dr = wkv.shape[0], q_pe.shape[-1]
     dv = wkv.shape[-1] - dn
     blocks = max(min(_LATENT_TILE // bs, table_row.shape[0]), 1)
     tile = blocks * bs
     table = jnp.pad(table_row, (0, -table_row.shape[0] % blocks))
     flat = pool.reshape(nb * bs, width)
+    q, q_pe = jnp.swapaxes(q_nope, 0, 1), jnp.swapaxes(q_pe, 0, 1)
 
     def one(t, carry):
-        top, total, acc = carry
         rows = (jax.lax.dynamic_slice_in_dim(table, t * blocks, blocks)[
             :, None] * bs + jnp.arange(bs)).reshape(tile)
-        lines = flat[rows]
-        kv = jnp.einsum("kr,rhd->khd", lines[:, :r], wkv)
-        s = (jnp.einsum("qhd,khd->hqk", q_nope, kv[..., :dn],
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("qhd,kd->hqk", q_pe, lines[:, r:r + dr],
-                          preferred_element_type=jnp.float32)) \
-            * jnp.float32(scale)
-        ok = (t * tile + jnp.arange(tile))[None, :] <= gpos[:, None]
-        # the mask is applied where the scores are read, twice, not to a
-        # copy of them: a twentieth off the softmax fusion (0.104 -> 0.098
-        # s of a traced window, my chip runs, PR 32)
-        top2 = jnp.maximum(top, jnp.max(jnp.where(ok, s, -1e30), axis=-1))
-        p = jnp.where(ok, jnp.exp(s - top2[..., None]), 0.0)
-        shrink = jnp.exp(top - top2)
-        acc = acc * shrink[..., None] + jnp.einsum(
-            "hqk,khd->hqd", p.astype(dt), kv[..., dn:],
-            preferred_element_type=jnp.float32)
-        return top2, total * shrink + jnp.sum(p, axis=-1), acc
+        return kernel.chunk_attention(q, q_pe, flat[rows], wkv, gpos,
+                                      t * tile, carry, scale=scale)
 
-    start = (jnp.full((H, C), -1e30, jnp.float32),
-             jnp.zeros((H, C), jnp.float32),
-             jnp.zeros((H, C, dv), jnp.float32))
     _, total, acc = jax.lax.fori_loop(0, jnp.max(gpos) // tile + 1, one,
-                                      start)
+                                      kernel.start(H, C, dv))
     return jnp.swapaxes(acc / jnp.maximum(total, 1e-30)[..., None], 0,
                         1).astype(dt)
 

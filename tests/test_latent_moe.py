@@ -354,6 +354,10 @@ def test_the_cache_holds_one_pool_of_lines_and_counts_them(served):
     # every decode call's active rows see their context and the new line
     assert latent["lines"] == sum(
         len(p) + i + 1 for p, n in sample for i in range(n - 1))
+    # every call of the chunk program: the lines up to its last position
+    # (prompts of 20 and 45 in chunks of 16, nothing shared)
+    assert latent["chunk_calls"] == st["chunk_steps"] == 2 + 3
+    assert latent["chunk_lines"] == (16 + 20) + (16 + 32 + 45)
 
 
 def test_the_counters_are_the_references_picks(served):
@@ -438,6 +442,61 @@ def test_engine_decodes_through_the_kernel_as_through_the_gathered_form(
     assert [h.tokens for h in handles] == [h.tokens for h in plain]
     assert _worst_gap(weights, dataclasses.asdict(cfg), sample,
                       handles) < 0.05
+
+
+def test_engine_chunks_through_the_kernel_as_through_the_plain_form(
+        built, monkeypatch):
+    """On a TPU the latent chunk program folds each tile of the cached
+    prefix through the chunk kernel; here through the interpreter, tiles
+    of 16 lines, the tokens are the plain form's, a prompt whose first 32
+    tokens come from the radix index among them, and as close to the
+    reference."""
+    from paddle_tpu.ops.pallas import chunk_attention as ca
+    from paddle_tpu.serving import engine as E
+
+    cfg, model, weights = built
+    system = _ids(32, seed=90)
+    sample = [(np.concatenate([system, _ids(n, seed=91 + n)]), new)
+              for n, new in ((7, 5), (18, 5), (29, 5))]
+
+    def forget():
+        for f in (E._PAGED_CHUNK, E._PAGED_CHUNK_DONATED):
+            f.clear_cache()
+
+    def serve():
+        forget()
+        eng = Engine(model, n_slots=2, max_len=96, block_size=4,
+                     prefill_chunk=16)
+        first = eng.submit(sample[0][0], max_new_tokens=sample[0][1])
+        eng.drain()                  # the producer's prefix is committed
+        rest = [eng.submit(p, max_new_tokens=n) for p, n in sample[1:]]
+        eng.drain()
+        return eng.stats(), [first] + rest
+
+    monkeypatch.setattr(G, "_LATENT_TILE", 16)
+    traced, fold = [], ca.chunk_attention
+
+    def interpreted(q, q_shared, lines, *args, **how):
+        traced.append((q.shape, q_shared.shape, lines.shape))
+        return fold(q, q_shared, lines, *args, interpret=True, **how)
+
+    try:
+        plain_stats, plain = serve()
+        monkeypatch.setattr(ca, "chunk_attention", interpreted)
+        stats, handles = serve()
+    finally:
+        forget()
+    # one trace a layer: 4 heads x 16 rows, 16 + 8 numbers against a tile
+    # of 16 lines padded to whole lanes
+    assert traced == [((4, 16, 16), (4, 16, 8), (16, 128))] * 3
+    assert stats["prefix_hit_tokens"] == plain_stats["prefix_hit_tokens"] \
+        >= 2 * 32
+    assert [h.tokens for h in handles] == [h.tokens for h in plain]
+    assert _worst_gap(weights, dataclasses.asdict(cfg), sample,
+                      handles) < 0.05
+    # the chunk program's calls and the lines their last rows could see
+    assert stats["latent"]["chunk_calls"] == stats["chunk_steps"] > 0
+    assert stats["latent"] == plain_stats["latent"]
 
 
 @pytest.mark.parametrize("fault", ["no_rotary_score", "no_mscale",

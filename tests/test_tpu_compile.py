@@ -82,6 +82,24 @@ def _paged_attention(blocks, n_kv, table):
                              ((), jnp.int32)]
 
 
+def _chunk_attention():
+    """The chunk kernel at the latent cell's shapes: 64 heads x 512 rows,
+    192 against 128, one tile of cached lines as the chunk body walks
+    them, the running state donated."""
+    from paddle_tpu.ops.pallas.chunk_attention import chunk_attention
+    from paddle_tpu.text.generation import _LATENT_TILE
+
+    def fold(q, q_shared, lines, w, gpos, first, top, total, acc):
+        return chunk_attention(q, q_shared, lines, w, gpos, first,
+                               (top, total, acc), scale=0.1446796)
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    return fold, [((64, 512, 128), bf), ((64, 512, 64), bf),
+                  ((_LATENT_TILE, 640), bf), ((512, 64, 256), bf),
+                  ((512,), jnp.int32), ((), jnp.int32), ((64, 512), f32),
+                  ((64, 512), f32), ((64, 512, 128), f32)]
+
+
 def _fused_ce(grad):
     from paddle_tpu.ops.pallas.fused_ce import fused_ce_loss
     fn = jax.grad(fused_ce_loss, argnums=(0, 1)) if grad else fused_ce_loss
@@ -117,6 +135,8 @@ _CASES = {
         _paged_attention, 6 * 2049, 32, 128),
     "paged_attention-4_kv_heads": functools.partial(
         _paged_attention, 8 * 8193, 4, 512),
+    # kimi-k2.6 as one chip of 32: a chunk of 512 rows against 2048 lines
+    "chunk_attention-64_heads": _chunk_attention,
     "fused_ce_loss-fwd": functools.partial(_fused_ce, False),
     "fused_ce_loss-grad": functools.partial(_fused_ce, True),
     "int8_linear": _int8_linear,
@@ -137,6 +157,10 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
     if case.startswith("paged_attention"):
         # the pool is read where it lies: nothing of its size is planned
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    if case.startswith("chunk_attention"):
+        # neither the scores nor the lines' expansion is an array
+        assert "chunk_attention" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 22
 
 
 def _over_jit(mesh):
@@ -478,7 +502,10 @@ def test_latent_program_compiles_and_moves_neither_pool_nor_banks(
     layer's held banks are copied, the three programs fit beside 10.4 GB
     of weights and pool, the decode program holds the latent kernel once
     a layer and no ``[slots, max_len, ...]`` view of the lines, and the
-    chunk expands no more than a tile of the cached prefix at a time."""
+    chunk program holds the chunk kernel once a layer and neither the
+    float32 scores ``[heads, chunk rows, tile]`` nor a tile's expansion
+    ``[tile, heads, nope + v]`` as a buffer: it plans less beside its
+    arguments than the 0.43 GB the plain form did."""
     fn, shapes, statics = _latent_program(kind)
     args = jax.tree.map(
         lambda sd: jax.ShapeDtypeStruct(*sd, sharding=one_chip), shapes,
@@ -504,6 +531,14 @@ def test_latent_program_compiles_and_moves_neither_pool_nor_banks(
                               text)) == _K_LAYERS
         assert "paged_latent_attention" in text
         assert not re.search(r"\[32,16384,", text)
+    if kind == "chunk":
+        from paddle_tpu.text.generation import _LATENT_TILE
+        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                              text)) == _K_LAYERS
+        assert "chunk_attention" in text
+        assert not re.search(rf"\[{_K_HEADS},512,{_LATENT_TILE}\]", text)
+        assert not re.search(rf"\[{_LATENT_TILE},{_K_HEADS},256\]", text)
+        assert mem.temp_size_in_bytes < 0.43e9
 
 
 _LOWER = """

@@ -22,17 +22,21 @@ tokens a second over all seeds, and how sets of six seeds spread: a new
 cell is admitted only under half of each bound (2.2 % here).
 
 The defaults are fitted to the chip's readings of
-``kimi_k26_agent_closed_16k`` (PERF.md section 6, PR 32): at sigma 0.7 it
-gives 293.3 tokens/s, 587 steps and a p95 step of 110.3 ms where the chip
-read 291.5, 558-586 and 111.1; at 0.2, 301.7 / 623 / 90.2 against 304.9 /
-629 / 91.3. The chip adds noise of its own (about 0.7 % in quadrature), so
-read a spread here as a floor. The p95 of the gaps is a p95 of step
-lengths, and a chunk 512 positions deeper is a step 2.5 ms (2.6 %) longer:
-where the 95th percentile lies at the edge between two depths, runs fall
-on either side of it and a set of six spreads by that much or by nearly
-nothing. The chip read 1.87 % at sigma 0.3 (five runs at 96.8-98.1 ms, one
-at 93.6), where this gives 19 % of the sets over 2.15 %; take the figure
-as a warning of such an edge, not as the chip's spread.
+``kimi_k26_agent_closed_16k`` since the chunk's attention is a kernel
+(PERF.md section 6, PR 33): 483.0 tokens/s and 997 steps at sigma 0.3
+where the chip read 481.5-485.0 and 997-1002; a chunk 512 positions deeper
+is a step 0.7 ms (1.4 %) longer. With the costs of the plain form
+(``--chunk-ms 40 --chunk-ms-per-k 5``; PR 32) it gave 293.3 tokens/s, 587
+steps and a p95 step of 110.3 ms at sigma 0.7 where the chip read 291.5,
+558-586 and 111.1, and 301.7 / 623 / 90.2 against 304.9 / 629 / 91.3 at
+0.2. The chip adds noise of its own (about 0.7 % in quadrature), so read a
+spread here as a floor. The p95 of the gaps is a p95 of step lengths:
+where a depth costs 2.6 % of a step (the plain form's 2.5 ms) and the 95th
+percentile lies at the edge between two depths, runs fall on either side
+of it and a set of six spreads by that much or by nearly nothing (the chip
+read 1.87 % at sigma 0.3 then, where this gave 19 % of the sets over
+2.15 %); the host's jitter is not in it, so the chip's p95 gap lies over
+this one (59.9-60.9 ms against 54.3).
 """
 from __future__ import annotations
 
@@ -112,8 +116,8 @@ def main(argv=None):
     ap.add_argument("--seconds", type=float, default=51.0)
     ap.add_argument("--half-bound", type=float, default=0.022)
     ap.add_argument("--gap-half-bound", type=float, default=0.0215)
-    ap.add_argument("--chunk-ms", type=float, default=40.0)
-    ap.add_argument("--chunk-ms-per-k", type=float, default=5.0)
+    ap.add_argument("--chunk-ms", type=float, default=29.2)
+    ap.add_argument("--chunk-ms-per-k", type=float, default=1.4)
     ap.add_argument("--decode-ms", type=float, default=10.0)
     ap.add_argument("--decode-ms-per-row", type=float, default=0.16)
     args = ap.parse_args(argv)
