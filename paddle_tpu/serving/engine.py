@@ -701,8 +701,10 @@ def _serving_code_token():
         from ..nn import routed_ffn as _rf
         from ..ops.pallas import paged_attention as _pa
         from ..text import generation as G
+        from ..text import sambay as _sb
+        from . import sambay_programs as _sp
         from . import speculative as _spec
-        _CODE_TOKEN = _akeys.code_token(G, _cm, _pa, _rf, _spec,
+        _CODE_TOKEN = _akeys.code_token(G, _cm, _pa, _rf, _spec, _sb, _sp,
                                         sys.modules[__name__])
     return _CODE_TOKEN
 
@@ -769,6 +771,8 @@ _SPEC_VERIFY = jax.jit(_spec_verify_impl, static_argnames=_PAGED_STATICS)
 _SPEC_VERIFY_DONATED = jax.jit(_spec_verify_impl,
                                static_argnames=_PAGED_STATICS,
                                donate_argnums=(1, 2))
+# one row of the vocab masks the decode program keeps on the device
+_SET_ROW = jax.jit(lambda masks, slot, row: masks.at[slot].set(row))
 
 
 def _make_arch(model):
@@ -812,6 +816,15 @@ def _make_arch(model):
                   router=c.router())
         kvh, hd = 1, -(-(c.kv_lora_rank + c.qk_rope_head_dim) // 128) * 128
         dtype = w["embed"].dtype
+    elif name == "Phi4FlashForCausalLM":
+        # programs of its own (``sambay_programs.py``) over bodies of its
+        # own: three kinds of state a slot. A cached line is a KV PAIR's,
+        # ``[k1 | k2]`` beside ``[v1 | v2]``: half the KV heads at twice
+        # the head size, whole lanes (theta is never read)
+        w = model.stacked_weights()
+        hp = dict(arch="sambay", theta=0.0, **model.serving_statics())
+        kvh, hd = c.num_key_value_heads // 2, 2 * hd
+        dtype = w["embed"].dtype
     elif name == "GPTForCausalLM":
         w = G._gpt_stacked_weights(model)
         hp = dict(arch="gpt", n_heads=c.num_attention_heads,
@@ -821,7 +834,8 @@ def _make_arch(model):
     else:
         raise TypeError(
             f"serving.Engine supports LlamaForCausalLM / GPTForCausalLM / "
-            f"MellumForCausalLM / KimiK2ForCausalLM, got {name}")
+            f"MellumForCausalLM / KimiK2ForCausalLM / Phi4FlashForCausalLM, "
+            f"got {name}")
     # what one position keeps in one layer's pool, and whether a V pool of
     # the same lines stands beside it
     geo = dict(n_layers=c.num_hidden_layers, line=(kvh, hd),
@@ -831,6 +845,20 @@ def _make_arch(model):
         # the bytes of a line as published: what a roofline counts
         geo["line_bytes"] = (c.kv_lora_rank + c.qk_rope_head_dim) \
             * jnp.dtype(dtype).itemsize
+    if hp["arch"] == "sambay":
+        # what a position keeps, by the kind of its layer: whole lines in
+        # the pool (the one full layer), at most a window of lines (the
+        # sliding layers), a state a slot (the recurrent layers), nothing
+        # (the gated units and the query-only layers); ``readers``: the
+        # layers that read the pool's one layer, the full one among them
+        n = {k: hp["kinds"].count(k) for k in set(hp["kinds"])}
+        di = c.d_inner
+        geo.update(
+            n_layers=n["full_attention"], vocab=c.vocab_size,
+            window=(n["sliding_attention"], c.sliding_window),
+            recurrent={"ssm": (n["mamba"], (c.mamba_d_state, di), "float32"),
+                       "conv": (n["mamba"], (c.mamba_d_conv - 1, di), dtype)},
+            readers=n["full_attention"] + n["cross_attention"])
     return w, hp, geo
 
 
@@ -973,7 +1001,23 @@ class Engine:
                  prefill_chunk=None, prefix_sharing=True, tp=1,
                  mesh=None, replica_id=None, speculative=None):
         self._w, self._hp, geo = _make_arch(model)
-        if "kinds" in self._hp:
+        if self._hp["arch"] == "sambay":
+            # a radix hit would need the recurrent state and the window's
+            # lines at the hit's boundary, and no snapshot is kept
+            for asked, missing in (
+                    (int(tp) > 1, "tp > 1: no tensor-parallel program "
+                     "carries recurrent state or window rings"),
+                    (speculative is not None, "speculative=...: the verify "
+                     "program carries no recurrent state"),
+                    (bool(prefix_sharing), "prefix_sharing=True: no "
+                     "recurrent state or window lines are kept at a block "
+                     "boundary for a sharer to start from (pass "
+                     "prefix_sharing=False)")):
+                if asked:
+                    raise ValueError(
+                        f"serving.Engine cannot serve "
+                        f"{type(model).__name__} with {missing}")
+        elif "kinds" in self._hp:
             # a model of layer kinds with routed experts runs through the
             # single-device programs alone: what else it is asked for is
             # refused by name, never served another way
@@ -1040,7 +1084,10 @@ class Engine:
         self.cache = PagedKVCache(geo["n_layers"], self.n_slots,
                                   self.max_len, geo["line"], geo["dtype"],
                                   block_size=self.block_size,
-                                  n_blocks=n_blocks, values=geo["values"])
+                                  n_blocks=n_blocks, values=geo["values"],
+                                  window=geo.get("window"),
+                                  recurrent=geo.get("recurrent"),
+                                  folded="window" in geo)
         self._paged_statics = dict(self._statics,
                                    block_size=self.block_size)
         # threaded device state (numpy until the first jit call)
@@ -1052,8 +1099,14 @@ class Engine:
         # a plain [n_slots, V] runtime operand of the decode AND verify
         # programs — all-ones rows are unconstrained, so the feature
         # costs zero lowerings and leaves unmasked sampling bit-exact
-        self._vocab = int(self._w["head"].shape[-1])
+        self._vocab = int(geo.get("vocab") or self._w["head"].shape[-1])
         self._vmask = np.ones((self.n_slots, self._vocab), np.float32)
+        # the decode program's copy of the masks stays on the device: a row
+        # changes only when a slot's request does, and is written then
+        # (``_mask_row``); uploaded whole every step it was 6.55 MB of the
+        # dense cell's dispatch, and 51 MB at 64 slots x 200 064 rows
+        self._vmask_dev = None
+        self._vmask_plain = np.ones(self.n_slots, bool)
         if self.tp > 1:
             # commit the KV pool (head dim split over tp) and the small
             # replicated state up front so every program call sees one
@@ -1100,6 +1153,9 @@ class Engine:
         if "line_bytes" in geo:
             self.metrics.latent = EngineMetrics.latent_counters(
                 int(geo["line_bytes"]))
+        if "readers" in geo:
+            self.metrics.recurrent = EngineMetrics.recurrent_counters(
+                geo["readers"], self.cache.bytes_by_kind())
         self._steps = 0           # step() calls so far: the next index
         self._step = None         # index of the step() now running
         # tracer on: spans of the running step's launches, held until
@@ -1128,6 +1184,11 @@ class Engine:
             self._chunk = _tp_jitted(mesh, "chunk", arch, donate, items)
             # baked into the shard_map programs: no call passes a static
             self._paged_statics = {}
+        elif self._hp["arch"] == "sambay":
+            from . import sambay_programs as sp
+            self._prefill = sp.PREFILL_DONATED if donate else sp.PREFILL
+            self._decode = sp.DECODE_DONATED if donate else sp.DECODE
+            self._chunk = sp.CHUNK_DONATED if donate else sp.CHUNK
         else:
             self._prefill = (_PAGED_PREFILL_DONATED if donate
                              else _PAGED_PREFILL)
@@ -1318,8 +1379,7 @@ class Engine:
             buckets = self._aot_buckets()
         specs = []
         vrow = jax.ShapeDtypeStruct((self._vocab,), np.float32)
-        moe = () if self.metrics.moe is None else (
-            jax.tree.map(sds, self.metrics.moe),)
+        moe = tuple(jax.tree.map(sds, a) for a in self._moe_in())
         mb = self.cache.block_tables.shape[1]
         trow = jax.ShapeDtypeStruct((mb,), np.int32)
         tables = jax.ShapeDtypeStruct((S, mb), np.int32)
@@ -1371,17 +1431,36 @@ class Engine:
     # -- stamps (module docstring) ----------------------------------------
 
     def _moe_in(self):
-        """The routed layers' counters as a program's last argument: a
-        one-tuple, or none where the model routes nothing."""
+        """What a program takes last, beside the pool: the routed layers'
+        counters, or the state of recurrent and window layers
+        (``PagedKVCache.state``); a one-tuple, or none where the model has
+        neither."""
+        if self.cache.state is not None:
+            return (self.cache.state,)
         return () if self.metrics.moe is None else (self.metrics.moe,)
 
     def _moe_out(self, out):
-        """Keep the counters a program returned last; the rest of its
-        values, as a model that routes nothing returns them."""
-        if self.metrics.moe is None:
-            return out
-        *out, self.metrics.moe = out
+        """Keep what a program returned last of the above; the rest of its
+        values, as a model that has neither returns them."""
+        if self.cache.state is not None:
+            *out, self.cache.state = out
+        elif self.metrics.moe is not None:
+            *out, self.metrics.moe = out
         return out
+
+    def _mask_row(self, slot, mask):
+        """Slot ``slot``'s vocab mask from now on (None: every token). The
+        host's copy serves the programs of one slot; the decode program's
+        copy lives on the device and takes the row only where it differs
+        from what is there (a slot whose old and new request are both
+        unconstrained costs nothing)."""
+        plain = mask is None
+        self._vmask[slot] = 1.0 if plain else mask
+        if self._vmask_dev is not None and not (
+                plain and self._vmask_plain[slot]):
+            self._vmask_dev = _SET_ROW(self._vmask_dev, np.int32(slot),
+                                       self._vmask[slot])
+        self._vmask_plain[slot] = plain
 
     def _launched(self, program, called, dispatched, fetched, span=None,
                   h=None, tokens=0, radix_tokens=0, **attrs):
@@ -1580,8 +1659,7 @@ class Engine:
         h.slot = slot
         self._by_slot[slot] = h
         self._temps[slot] = h.temperature
-        self._vmask[slot] = (1.0 if h.logit_mask is None
-                             else h.logit_mask)
+        self._mask_row(slot, h.logit_mask)
         self.metrics.prompt_tokens += n_eff
         self.metrics.prefix_hit_tokens += min(n_shared, n_eff)
         if cow:
@@ -1604,6 +1682,7 @@ class Engine:
                 _ChunkState(h, full, n_eff, n_shared, start))
             self.metrics.chunked_prefills += 1
             return True
+        self.metrics.mark_scan(n_eff, first=True, replay=k > 0)
         Lb = self._bucket(n_eff)
         self.buckets_seen.add(Lb)
         ids = np.zeros((1, Lb), np.int32)
@@ -1681,6 +1760,8 @@ class Engine:
             start=start, final=is_final)
         self.chunk_used = True
         self.metrics.mark_chunk(end)
+        self.metrics.mark_scan(end - start, first=start == 0,
+                               replay=cs.skip > 0)
         cs.next = end
         if is_final:
             self._chunking.pop(0)
@@ -1901,13 +1982,18 @@ class Engine:
         called = time.perf_counter()
         self.metrics.mark_lines_seen(self.cache.cur_pos[active] + 1,
                                      self._hp.get("window"))
+        if self._vmask_dev is None:
+            self._vmask_dev = jax.device_put(
+                self._vmask, None if self._mesh is None else
+                jax.sharding.NamedSharding(self._mesh,
+                                           jax.sharding.PartitionSpec()))
         with _compile_scope("decode"):
             out = self._run_program(
                 "decode", ("decode",), self._decode,
                 (self._w, self.cache.kc, self.cache.vc,
                  self.cache.block_tables.copy(), self._tok,
                  self._cur, active, self._keys, self._temps,
-                 self._vmask.copy()) + self._moe_in(),
+                 self._vmask_dev) + self._moe_in(),
                 self._paged_statics, "decode")
         nxt, self.cache.kc, self.cache.vc, self._cur, self._keys = \
             self._moe_out(out)

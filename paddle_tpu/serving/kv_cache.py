@@ -252,10 +252,26 @@ class PagedKVCache:
     the same shape holds V beside K. A latent cache has ``values=False``:
     ``vc`` is None and one line is all there is of a position. Blocks,
     tables and the index count lines, whatever their shape.
+
+    ``n_layers`` counts the layers whose lines are kept WHOLE: the pool
+    spans those alone. Two further kinds of state are sized apart and held
+    in ``state`` (None where a model has neither), a dict of arrays the
+    engine threads through its programs beside the pool: ``window =
+    (layers, lines)``, for layers that read no further back than ``lines``
+    positions, gives ``wk`` / ``wv`` ``[layers, 1 + n_slots * lines /
+    block_size, block_size, *line]``, a ring of ``lines`` a slot behind a
+    constant block table (block 0 is trash), so such a layer never holds
+    more than its window; ``recurrent = {name: (layers, shape, dtype)}``
+    gives ``[layers, n_slots, *shape]`` of what a recurrent layer carries
+    from token to token. ``folded`` holds every block as ``[block_size *
+    kv_heads, head_dim]``. Neither is allocated by blocks: a slot owns its
+    share while it is occupied, and the program that first writes a
+    prompt's rows resets it.
     """
 
     def __init__(self, n_layers, n_slots, max_len, line, dtype,
-                 block_size=16, n_blocks=None, values=True):
+                 block_size=16, n_blocks=None, values=True, window=None,
+                 recurrent=None, folded=False):
         if n_slots < 1:
             raise ValueError("n_slots must be >= 1")
         if max_len < 2:
@@ -278,11 +294,28 @@ class PagedKVCache:
             n_blocks = self.n_slots * self.max_blocks + 1
         self.pool = BlockPool(n_blocks)
         self.radix = RadixIndex(self.block_size)
-        shape = (self.n_layers, self.pool.n_blocks, self.block_size) \
-            + self.line
+        self.folded = bool(folded)
+        shape = (self.n_layers, self.pool.n_blocks) \
+            + self._block(self.block_size)
         # plain numpy zeros: first jit call device-puts them (no compile)
         self.kc = np.zeros(shape, self.dtype)
         self.vc = np.zeros(shape, self.dtype) if self.values else None
+        self.window = None if window is None else tuple(map(int, window))
+        self.state = None
+        if window is not None or recurrent:
+            self.state = {}
+        if window is not None:
+            layers, lines = self.window
+            if lines % self.block_size:
+                raise ValueError(f"a window of {lines} lines is no whole "
+                                 f"number of blocks of {self.block_size}")
+            ring = (layers, 1 + self.n_slots * lines // self.block_size) \
+                + self._block(self.block_size)
+            self.state["wk"] = np.zeros(ring, self.dtype)
+            self.state["wv"] = np.zeros(ring, self.dtype)
+        for name, (layers, per_slot, dt) in (recurrent or {}).items():
+            self.state[name] = np.zeros(
+                (int(layers), self.n_slots) + tuple(per_slot), np.dtype(dt))
         self.block_tables = np.zeros((self.n_slots, self.max_blocks),
                                      np.int32)      # 0 = trash/unused
         self.cur_pos = np.zeros(self.n_slots, np.int32)
@@ -293,6 +326,15 @@ class PagedKVCache:
         self._slot_shared = np.zeros(self.n_slots, np.int32)  # blocks
         # pool telemetry for serving metrics
         self.low_watermark = self.pool.n_free
+
+    def _block(self, lines):
+        """The shape of a block of ``lines`` lines: ``(lines, kv, hd)``,
+        or ``folded`` to ``(lines * kv, hd)``, lines and heads one run of
+        rows (the same bytes; what a pool whose heads fill no whole tile
+        of the chip is held as, ``text/sambay.py::_scatter`` says why)."""
+        if self.folded:
+            return (lines * self.kv_heads, self.head_dim)
+        return (lines,) + self.line
 
     # -- slot surface ------------------------------------------------------
 
@@ -338,11 +380,22 @@ class PagedKVCache:
     def owner(self, slot):
         return self._owner[slot]
 
+    def bytes_by_kind(self):
+        """What is held, by kind of state: ``pool_bytes`` (the paged
+        pools, padding of a line included), ``window_bytes`` (the rings of
+        the window layers, their trash blocks included) and
+        ``state_bytes`` (what the recurrent layers carry)."""
+        held = {k: int(np.prod(a.shape)) * a.dtype.itemsize
+                for k, a in (self.state or {}).items()}
+        ring = held.pop("wk", 0) + held.pop("wv", 0)
+        return {"pool_bytes": (1 + self.values) * self.n_layers
+                * self.pool.n_blocks * self.block_size * self.kv_heads
+                * self.head_dim * self.dtype.itemsize,
+                "window_bytes": ring, "state_bytes": sum(held.values())}
+
     def nbytes(self):
-        """What the pools really hold, padding of a line included."""
-        return (1 + self.values) * self.n_layers * self.pool.n_blocks \
-            * self.block_size * self.kv_heads * self.head_dim \
-            * self.dtype.itemsize
+        """What the cache really holds, of every kind."""
+        return sum(self.bytes_by_kind().values())
 
     # -- paged admission ---------------------------------------------------
 
@@ -471,4 +524,5 @@ class PagedKVCache:
                 "blocks_low_watermark": int(self.low_watermark),
                 "radix_nodes": self.radix.n_nodes,
                 "pool_occupancy_now": round(
-                    self.pool.n_used / max(1, self.pool.n_blocks - 1), 4)}
+                    self.pool.n_used / max(1, self.pool.n_blocks - 1), 4),
+                **(self.bytes_by_kind() if self.state is not None else {})}

@@ -189,6 +189,9 @@ class EngineMetrics:
         # cache has no K and V lines, so ``lines_seen`` stays at zero
         # there
         self.latent = None
+        # a model of recurrent and window layers beside one KV layer
+        # (None elsewhere; ``recurrent_counters``)
+        self.recurrent = None
         self.kv_pool_bytes_per_device = None
         self.collectives_per_decode_step = None
         # decode-step wall times, histogram-backed: the ~64-observation
@@ -273,6 +276,37 @@ class EngineMetrics:
         return {"line_bytes": line_bytes, "decode_calls": 0, "lines": 0,
                 "chunk_calls": 0, "chunk_lines": 0}
 
+    @staticmethod
+    def recurrent_counters(shared_kv_readers, held):
+        """What an engine over recurrent and window layers counts, all on
+        the host: the bytes ``held`` by kind (``PagedKVCache
+        .bytes_by_kind``: constants of the geometry); ``scan_tokens``, the
+        tokens that went through the prefill and chunk scans;
+        ``decode_lines_seen``, the lines of the ONE KV layer the
+        decode-active rows could see, summed over ``decode_calls`` fused
+        decode calls (each of ``shared_kv_readers`` layers reads them), and
+        ``decode_lines_in_window``, the same with each row cut to the
+        window (what a window layer's ring holds; ``lines_seen`` stays at
+        zero here, as for a latent cache: its readers count a llama's
+        bytes);
+        ``state_resets``, prompts whose first rows started a slot's state
+        from zero, and ``state_replays``, those of them that rebuilt it
+        from ``prompt + tokens`` after a pre-emption or an ``adopt()``."""
+        return dict(held, scan_tokens=0, decode_calls=0, decode_lines_seen=0,
+                    decode_lines_in_window=0,
+                    shared_kv_readers=int(shared_kv_readers),
+                    state_resets=0, state_replays=0)
+
+    def mark_scan(self, tokens, first=False, replay=False):
+        """One prefill or chunk call over ``tokens`` of a prompt; its
+        ``first`` rows reset the slot's state (a ``replay``: of a request
+        that had already emitted tokens)."""
+        r = self.recurrent
+        if r is not None:
+            r["scan_tokens"] += int(tokens)
+            r["state_resets"] += bool(first)
+            r["state_replays"] += bool(first and replay)
+
     def mark_chunk(self, end):
         """One call of the chunk program, over the positions before
         ``end``: the last row sees ``end`` lines."""
@@ -287,6 +321,12 @@ class EngineMetrics:
         if self.latent is not None:
             self.latent["decode_calls"] += 1
             self.latent["lines"] += int(seen.sum())
+            return
+        if self.recurrent is not None:
+            r = self.recurrent
+            r["decode_calls"] += 1
+            r["decode_lines_seen"] += int(seen.sum())
+            r["decode_lines_in_window"] += int(seen.clip(max=window).sum())
             return
         t = self.lines_seen
         t["calls"] += 1
@@ -394,6 +434,8 @@ class EngineMetrics:
                 self.collectives_per_decode_step,
             **({} if self.moe is None else {"moe": self.moe_counters()}),
             **({} if self.latent is None else {"latent": dict(self.latent)}),
+            **({} if self.recurrent is None
+               else {"recurrent": dict(self.recurrent)}),
         }
 
 

@@ -17,6 +17,9 @@ from .llama_pipe import LlamaForCausalLMPipe  # noqa: F401
 from .mellum import (  # noqa: F401
     MELLUM_TINY, MellumConfig, MellumForCausalLM,
 )
+from .phi4flash import (  # noqa: F401
+    PHI4FLASH_TINY, Phi4FlashConfig, Phi4FlashForCausalLM,
+)
 from .t5 import (  # noqa: F401
     T5_TINY, T5Config, T5ForConditionalGeneration, T5Model,
 )

@@ -541,6 +541,111 @@ def test_latent_program_compiles_and_moves_neither_pool_nor_banks(
         assert mem.temp_size_in_bytes < 0.43e9
 
 
+# the hybrid serving cell's engine: 32 layers of five kinds at hidden 2560
+# (9 recurrent of d_inner 5120 x state 16, 8 window of 512, one full, 7 gated
+# units, 7 query-only), 40 query / 20 KV heads of 64 (a KV pair's line 128
+# wide), a tied 200064-row head, 64 slots x 8192, block 16, chunk 512
+_S_HIDDEN, _S_FF, _S_INNER, _S_STATE, _S_RANK, _S_VOCAB = 2560, 10240, 5120, \
+    16, 160, 200064
+_S_SLOTS, _S_TABLE, _S_WINDOW, _S_PAIRS = 64, 512, 512, 10
+_S_POOL = (1, _S_SLOTS * _S_TABLE + 1, _BS * _S_PAIRS, _HD)
+_S_RINGS = (8, _S_SLOTS * _S_WINDOW // _BS + 1, _BS * _S_PAIRS, _HD)
+
+
+def _sambay_program(kind):
+    """A program of ``serving/sambay_programs.py`` as the engine jits it:
+    every per-layer leaf a tuple of the layers' own arrays (None where a
+    layer has no such leaf), the pool of ONE layer and the rings folded
+    (lines and KV pairs of a block one run of rows), the state last."""
+    from paddle_tpu.serving import sambay_programs as sp
+    from paddle_tpu.text.models.phi4flash import Phi4FlashConfig
+
+    kinds = Phi4FlashConfig().layer_kinds()
+    h, f, di, ds, r, V, S = (_S_HIDDEN, _S_FF, _S_INNER, _S_STATE, _S_RANK,
+                             _S_VOCAB, _S_SLOTS)
+    bf, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+    lam = {k: ((64,), f32) for k in ("lq1", "lk1", "lq2", "lk2")}
+    every = {"ln1w": ((h,), bf), "ln1b": ((h,), bf), "ln2w": ((h,), bf),
+             "ln2b": ((h,), bf), "wgu": ((h, 2 * f), bf), "wd": ((f, h), bf)}
+    own = {
+        "mamba": {"win": ((h, 2 * di), bf), "convw": ((4, di), bf),
+                  "convb": ((di,), bf), "wx": ((di, r + 2 * ds), bf),
+                  "wdt": ((r, di), bf), "bdt": ((di,), f32),
+                  "alog": ((di, ds), f32), "dskip": ((di,), f32),
+                  "wout": ((di, h), bf)},
+        "gmu": {"gin": ((h, di), bf), "gout": ((di, h), bf)},
+        "sliding_attention": dict(lam, wqkv=((h, 2 * h), bf),
+                                  wo=((h, h), bf), subln=((128,), bf)),
+        "cross_attention": dict(lam, wq=((h, h), bf), wo=((h, h), bf),
+                                subln=((128,), bf))}
+    own["full_attention"] = own["sliding_attention"]
+    names = dict.fromkeys(n for d in own.values() for n in d)
+    w = {n: tuple(own[k].get(n) for k in kinds) for n in names}
+    w.update({n: (sd,) * len(kinds) for n, sd in every.items()})
+    w.update(embed=((V, h), bf), normw=((h,), bf), normb=((h,), bf))
+    state = {"wk": (_S_RINGS, bf), "wv": (_S_RINGS, bf),
+             "ssm": ((9, S, ds, di), f32), "conv": ((9, S, 3, di), bf)}
+    statics = dict(arch="sambay", theta=0.0, do_sample=False, top_k=0,
+                   top_p=None, block_size=_BS, kinds=kinds, n_heads=40,
+                   n_kv=20, eps=1e-5, window=_S_WINDOW)
+    pool, scalar = (_S_POOL, bf), ((), i32)
+    slots, keys = ((S,), i32), ((S, 2), jnp.uint32)
+    row, temp, vmask = ((_S_TABLE,), i32), ((), f32), ((V,), f32)
+    if kind == "decode":
+        return (sp.DECODE_DONATED,
+                [w, pool, pool, ((S, _S_TABLE), i32), slots, slots,
+                 ((S,), jnp.bool_), keys, ((S,), f32), ((S, V), f32), state],
+                statics)
+    if kind == "chunk":
+        return (sp.CHUNK_DONATED,
+                [w, pool, pool, slots, slots, keys, ((1, 512), i32), scalar,
+                 scalar, scalar, row, scalar, scalar, ((), jnp.uint32),
+                 scalar, temp, vmask, state], statics)
+    return (sp.PREFILL_DONATED,
+            [w, pool, pool, slots, slots, keys, ((1, 512), i32), scalar,
+             scalar, ((), jnp.uint32), scalar, temp, row, scalar, vmask,
+             state], statics)
+
+
+@pytest.mark.parametrize("kind", ("decode", "chunk", "prefill"))
+def test_hybrid_program_compiles_and_moves_no_pool(kind, one_chip,
+                                                   no_compile_cache):
+    """At the published widths and the cell's sizes, uncut: the pool's one
+    layer (2.68 GB), the window layers' rings (1.34 GB) and the recurrent
+    states are donated and written in place, none copied or relaid whole
+    (held ``[.., 16, 10, 128]`` the chip's compiler relaid the pools,
+    padded, around every scatter: 4.9 GB of temporaries); the three
+    programs fit beside 12.0 GB of weights and state; the decode program
+    holds the paged kernel once an attention layer (8 window layers over
+    the rings, the full layer and 7 query-only layers over the pool)."""
+    fn, shapes, statics = _sambay_program(kind)
+    args = jax.tree.map(
+        lambda sd: jax.ShapeDtypeStruct(*sd, sharding=one_chip), shapes,
+        is_leaf=lambda x: isinstance(x, tuple) and len(x) == 2
+        and isinstance(x[1], type(jnp.bfloat16)))
+    compiled = fn.lower(*args, **statics).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    held = 2 * 2 * (int(np.prod(_S_POOL)) + int(np.prod(_S_RINGS)))
+    assert mem.alias_size_in_bytes >= held                    # donated
+    assert mem.argument_size_in_bytes < 12.1e9
+    assert mem.temp_size_in_bytes < 0.4e9
+    sizes = {int(np.prod(_S_POOL)), int(np.prod(_S_RINGS)),
+             int(np.prod(_S_RINGS)) // 8}
+    # (a line's scatter is a dynamic-update-slice of the donated pool, in
+    # place: a copy of a pool would show among the temporaries above)
+    moved = [(op, shape) for shape, op in re.findall(
+        r"= \w+\[([\d,]+)\]\S* (copy|slice|dynamic-slice)\(", text)
+        if int(np.prod([int(d) for d in shape.split(",")])) in sizes]
+    assert not moved
+    kernels = len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                             text))
+    assert kernels == (16 if kind == "decode" else 0)
+    if kind == "decode":
+        assert "paged_attention" in text
+        assert not re.search(r"\[64,8192,", text)       # no gathered view
+
+
 _LOWER = """
 import json, os, sys
 root = os.path.dirname(os.path.abspath(__file__))
