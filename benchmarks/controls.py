@@ -34,10 +34,15 @@ The faults, each a departure from the equations that still runs:
                         broken: this is the reading from above of the
                         comparison's limits, as the program itself is the
                         reading from below.
+``altered_token``       one token altered where it is produced: the second
+                        token of the first request to reach one, its
+                        lowest bit flipped as the engine emits it. One row
+                        of the check scores like a random token.
 
 The first two need a routed model, the next two one with layer kinds; on
 any other model they change nothing and the control reads ``correct:
-true``, which says so. The last lowers any llama-bodied program.
+true``, which says so. The fifth lowers any llama-bodied program; the
+last runs on any engine.
 """
 from __future__ import annotations
 
@@ -113,12 +118,28 @@ def _eight_bit_activations():
     return mock.patch.object(generation, "_rms", rms)
 
 
+def _altered_token():
+    from paddle_tpu.serving import engine
+
+    true = engine.Engine._emit
+    altered = []
+
+    def emit(self, h, token):
+        if not altered and len(h.tokens) == 1:
+            altered.append(h)
+            token ^= 1
+        return true(self, h, token)
+
+    return mock.patch.object(engine.Engine, "_emit", emit)
+
+
 FAULTS = {"none": contextlib.nullcontext,
           "unnormalised_top_k": _unnormalised_top_k,
           "dropped_pick": _dropped_pick,
           "no_window": _no_window,
           "plain_table_on_full_layers": _plain_table_on_full_layers,
-          "eight_bit_activations": _eight_bit_activations}
+          "eight_bit_activations": _eight_bit_activations,
+          "altered_token": _altered_token}
 
 
 def main(argv=None):
@@ -158,7 +179,8 @@ def main(argv=None):
     with FAULTS[args.fault]():
         correct = driver.setup()
     print(json.dumps({"control": args.fault, "correct": bool(correct),
-                      "setup_phases": dict(run.phases)}), flush=True)
+                      "setup_phases": dict(run.phases),
+                      "compared": run.compared}), flush=True)
     return 0
 
 

@@ -18,7 +18,11 @@ Every fault but ``none`` has to come out ``correct: false``:
 ``no_latent_norm``     ``kv_a_layernorm`` skipped: the raw latent is cached
                        and expanded;
 ``eight_bit_activations``  ``controls.py``'s: the program in the nearest
-                       precision below the one it is served in.
+                       precision below the one it is served in;
+``altered_token``      ``controls.py``'s: one token altered as the engine
+                       emits it, one row far under the best, which the
+                       count of rows over the tolerance lets through and
+                       the ceiling does not.
 """
 from __future__ import annotations
 
@@ -107,7 +111,8 @@ FAULTS = {"none": contextlib.nullcontext,
           "no_routed_scale": _no_routed_scale,
           "no_shared_expert": _no_shared_expert,
           "no_latent_norm": _no_latent_norm,
-          "eight_bit_activations": controls.FAULTS["eight_bit_activations"]}
+          "eight_bit_activations": controls.FAULTS["eight_bit_activations"],
+          "altered_token": controls.FAULTS["altered_token"]}
 
 
 def main(argv=None):

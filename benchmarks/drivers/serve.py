@@ -27,6 +27,25 @@ KEYS = {"driver", "about", "engine", "clients", "warmup_requests", "check"} \
     | traffic_mod.Requests.KEYS
 
 
+def verdict(reference, gaps):
+    """The check's two numbers over its rows' gaps (bf16 steps under the
+    reference's best), each with the most it may read: the rows over the
+    reference's ``LOGIT_TOL_ULPS``, and the worst row. A reference module
+    that sets ``ROWS_PER_ROW_OVER`` lets one row in that many (rounded
+    down) lie over the tolerance, and then holds every row to its
+    ``CEILING_ULPS``; one that sets neither lets no row over, which is the
+    worst row held to the tolerance."""
+    gaps = np.asarray(gaps, np.float64)
+    tolerance = reference.LOGIT_TOL_ULPS
+    per = getattr(reference, "ROWS_PER_ROW_OVER", None)
+    return {"worst_gap_bf16_steps": {
+                "value": float(gaps.max(initial=0.0)),
+                "at_most": getattr(reference, "CEILING_ULPS", tolerance)},
+            "rows_over_tolerance": {
+                "value": int((gaps > tolerance).sum()),
+                "at_most": len(gaps) // per if per else 0}}
+
+
 class Driver:
     def __init__(self, run):
         self.run = run
@@ -47,13 +66,15 @@ class Driver:
         (the sample as one right-padded batch: under a causal mask the
         padding changes nothing before it), and at every generated
         position its logit of the engine's token must lie within the
-        tolerance of its largest logit."""
+        tolerance of its largest logit (:func:`verdict` says how many rows
+        may not, and by how much). Every number compared, with its limit,
+        goes to ``run.compared``."""
         run, reference = self.run, self.ref
         handles = [self._submit(p, n)["handle"] for p, n in sample]
         self.engine.drain()
         run.phase("sample_through_engine")
-        ok = all(h.finish_reason == "length" and len(h.tokens) == n
-                 for (_, n), h in zip(sample, handles))
+        unfinished = sum(h.finish_reason != "length" or len(h.tokens) != n
+                         for (_, n), h in zip(sample, handles))
         seqs = [np.concatenate([p, np.asarray(h.tokens, np.int32)])
                 for (p, _), h in zip(sample, handles)]
         ids = np.zeros((len(seqs), max(map(len, seqs))), np.int32)
@@ -63,16 +84,24 @@ class Driver:
         rows = np.stack([np.arange(len(p) - 1, len(p) - 1 + n)
                          for p, n in sample])
         z = np.asarray(reference.logits(weights, run.config, ids, rows=rows))
-        worst = 0.0
-        for zi, h in zip(z, handles):
-            for zt, tok in zip(zi, h.tokens):
-                step = reference.bf16_step(np.abs(zt).max())
-                worst = max(worst, float(zt.max() - zt[tok]) / step)
+        gaps = [float(zt.max() - zt[tok])
+                / reference.bf16_step(np.abs(zt).max())
+                for zi, h in zip(z, handles) for zt, tok in zip(zi, h.tokens)]
+        limits = verdict(reference, gaps)
+        limits["requests_not_finished_by_length"] = {"value": unfinished,
+                                                     "at_most": 0}
+        failed_on = [k for k, v in limits.items() if v["value"] > v["at_most"]]
+        run.compared.update(limits)
         run.info("reference", requests=len(sample),
-                 prompt_tokens=[len(p) for p, _ in sample],
-                 worst_gap_bf16_steps=worst,
-                 tolerance_bf16_steps=reference.LOGIT_TOL_ULPS)
-        return ok and worst <= reference.LOGIT_TOL_ULPS
+                 prompt_tokens=[len(p) for p, _ in sample], rows=len(gaps),
+                 worst_gap_bf16_steps=limits["worst_gap_bf16_steps"]["value"],
+                 tolerance_bf16_steps=reference.LOGIT_TOL_ULPS,
+                 ceiling_bf16_steps=limits["worst_gap_bf16_steps"]["at_most"],
+                 rows_over_tolerance=limits["rows_over_tolerance"]["value"],
+                 rows_allowed_over_tolerance=limits[
+                     "rows_over_tolerance"]["at_most"],
+                 failed_on=failed_on)
+        return not failed_on
 
     def setup(self):
         from paddle_tpu.serving import Engine

@@ -110,6 +110,15 @@ class Driver:
                  matmul_params=arithmetic.matmul_params(run.config),
                  train_flops_per_token=flops)
         self.flops_per_token = flops
+        run.compared.update({
+            "first_loss_gap": {"value": abs(got - want),
+                               "at_most": ref.LOSS_TOL},
+            "least_share_against_gradient": {
+                "value": min(share for share, _ in update.values()),
+                "at_least": ref.SIGN_TOL},
+            "largest_size_off_lr": {
+                "value": max(abs(size - 1.0) for _, size in update.values()),
+                "at_most": ref.SIZE_TOL}})
         return (math.isfinite(got) and abs(got - want) <= ref.LOSS_TOL
                 and all(share >= ref.SIGN_TOL
                         and abs(size - 1.0) <= ref.SIZE_TOL

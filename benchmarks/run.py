@@ -13,7 +13,9 @@ and its metric is left out.
 
 Information lines (one JSON object each, key ``info``) come first; the last
 line of standard output is the result: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, in a traced run, ``breakdown``.
+``failed``, ``metrics``, ``device``, in a traced run ``breakdown``, and last
+``compared``: each number the check compared, with its limit (also the
+last lines of standard error).
 With ``--trace 0`` the metrics are the end-to-end ones, with ``--trace 1``
 the per-layer ones. Without a TPU, or with another number of chips than the
 cell asks for, the run exits non-zero and prints no result; ``--allow-cpu``
@@ -62,6 +64,9 @@ class Run:
         self.samples = {}
         self.trace = None
         self.phases = []
+        # what the cell's check compared: name -> {"value", and the limit
+        # as "at_most" or "at_least"}
+        self.compared = {}
 
     def phase(self, name):
         """Mark the end of a phase of set-up, by the process's clock."""
@@ -191,6 +196,11 @@ def main(argv=None):
         result["breakdown"] = {"device_ops": reduced["device_ops"],
                                "idle_gaps": reduced["idle_gaps"]}
     result["device"] = device
+    # the numbers that decided ``correct``, last on standard error and last
+    # in the result
+    for name, limit in run.compared.items():
+        print(f"compared {name} {json.dumps(limit)}", file=sys.stderr)
+    result["compared"] = run.compared
     print(json.dumps(result), flush=True)
     return 0
 

@@ -344,4 +344,8 @@ def test_every_planted_fault_fails_the_comparison(fault, correct):
     assert p.returncode == 0, p.stderr[-2000:]
     last = json.loads(p.stdout.strip().splitlines()[-1])
     assert last == {"control": fault, "correct": correct,
-                    "setup_phases": last["setup_phases"]}
+                    "setup_phases": last["setup_phases"],
+                    "compared": last["compared"]}
+    # the hybrid check holds the worst row to the tolerance: no row over
+    assert last["compared"]["worst_gap_bf16_steps"]["at_most"] == 4
+    assert last["compared"]["rows_over_tolerance"]["at_most"] == 0

@@ -97,6 +97,24 @@ def test_moves_names_an_end_to_end_metric_of_the_same_cells(manifest):
         assert cells_of(m, manifest) <= cells_of(e2e[m["moves"]], manifest), m
 
 
+def names_a_width(key):
+    """A key that ``reduced`` may never name: a size ending in ``_dim`` or
+    ``_rank``, a head's, or any ``_size`` but the vocabulary's, which is a
+    count of rows and no width."""
+    return key.endswith(("_dim", "_rank")) or "head" in key \
+        or (key.endswith("_size") and key != "vocab_size")
+
+
+@pytest.mark.parametrize("key,width", [
+    ("vocab_size", False), ("num_hidden_layers", False),
+    ("n_routed_experts", False), ("layer_types", False),
+    ("hidden_size", True), ("intermediate_size", True),
+    ("moe_intermediate_size", True), ("kv_lora_rank", True),
+    ("qk_rope_head_dim", True), ("num_attention_heads", True)])
+def test_reduced_may_name_the_vocabulary_and_no_width(key, width):
+    assert names_a_width(key) is width
+
+
 def test_every_named_file_exists_and_agrees(manifest):
     for c in manifest["configs"]:
         assert c["file"] == f"benchmarks/configs/{c['name']}.json"
@@ -105,8 +123,7 @@ def test_every_named_file_exists_and_agrees(manifest):
         assert cfg["source"] == c["source"]
         assert all(k in cfg["published"] and cfg["published"][k] != cfg[k]
                    for k in c["reduced"])
-        assert not any(k.endswith(("_dim", "_rank", "_size")) or "head" in k
-                       for k in c["reduced"])
+        assert not any(names_a_width(k) for k in c["reduced"])
     for w in manifest["workloads"]:
         cell = load(os.path.join(BENCH, "workloads", f"{w['name']}.json"))
         assert {k: cell[k] for k in ("name", "config", "traffic", "chips")} \

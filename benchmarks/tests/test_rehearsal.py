@@ -36,9 +36,15 @@ def test_a_cell_runs_from_files_alone(cell, trace, expected):
     lines = p.stdout.strip().splitlines()
     assert all("info" in json.loads(ln) for ln in lines[:-1])
     result = json.loads(lines[-1])
-    # a CPU run reports no device metric and no breakdown
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    # a CPU run reports no device metric and no breakdown; the numbers the
+    # check compared come last, each within its limit
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]
+    assert result["compared"] and all(
+        m["value"] <= m["at_most"] if "at_most" in m
+        else m["value"] >= m["at_least"] for m in result["compared"].values())
+    err = p.stderr.strip().splitlines()[-len(result["compared"]):]
+    assert [ln.split()[1] for ln in err] == list(result["compared"])
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0
     assert set(result["metrics"]) == expected
