@@ -4,39 +4,66 @@
 (the GShard form its training path wants: static ``[E, C]`` shapes).
 Serving a published top-k model may drop nothing, so this is the other
 form: scores over the experts in float32 (a softmax, or a sigmoid with a
-selection bias and a scale), the ``k`` largest, renormalised, then every
-expert held here applied to every row and each row's result the weighted
-sum over its picks (weight zero elsewhere). The banks may be a share of
-the layer's experts (``routed_ffn``'s ``first``): the router still scores
-all of them. The
-shapes depend on the rows and the banks alone: no factor pads anything,
-and a load that sends every token to one expert is computed like any
-other.
+selection bias and a scale), the ``k`` largest, renormalised, and each
+row's result the weighted sum of its picked experts' SwiGLUs. The banks
+may be a share of the layer's experts (``routed_ffn``'s ``first``): the
+router still scores all of them. A load that sends every token to one
+expert is computed like any other.
 
-Why all experts, and not the picks grouped by expert: an expert's three
-matrices take 3 h f 2 B / 819 GB/s to read and ``rows`` x 6 h f / 197
-TFLOP/s to multiply, so under ~240 rows (a v5e's ridge) the read is the
-longer and rows an expert was not picked for cost no time. Measured at
-64 experts of 2304 x 896, top 8 (my chip run, PR 27), the whole function
-at 16 / 128 / 512 rows: 1.13 / 1.11 / 2.51 ms (a decode step's layers
-read their banks at 737 GB/s; at 512 rows the products run at 162
-TFLOP/s), against 3.77 / 5.50 / 6.16 ms with the picks sorted by expert
-through ``jax.lax.ragged_dot`` (the chip's grouped-matmul kernel: 1.26
-ms a call at 128 picks, 1.96 ms at 4096, 4 % of the peak). No serving
-program passes more rows than a prefill chunk (512 in the benchmark's
-cell). Beyond the ridge this form multiplies ``E / k`` times the picks'
-work: a grouped kernel that beats it there is worth writing when a
-measured workload sends such batches (``PERF.md`` section 7).
+Two forms compute it, and the platform chooses: on a TPU :func:`grouped`,
+the picks grouped by expert through ``ops/pallas/grouped_swiglu.py``
+(each expert some row picked is read once a call, and multiplies only
+the rows that picked it); anywhere else, and on a TPU where the shapes do
+not tile, :func:`plain`, every held expert applied to every row and the
+unpicked weighted zero, which is also the kernel's parity oracle and its
+backward.
+
+Why grouped: the whole function at 16 / 128 / 512 rows of the layer's
+own input and router, on one TPU v5e (``tools/time_routed_ffn.py``),
+in ms:
+
+================================  ====  ====  =====
+64 experts of 2304 x 896, top 8    16   128    512
+================================  ====  ====  =====
+``plain``                          1.13  1.11   2.53
+``jax.lax.ragged_dot``             3.08  5.53   6.34
+megablox ``gmm``                   5.59  8.91  12.15
+``grouped``                        0.79  1.13   1.22
+================================  ====  ====  =====
+
+================================  ====  ====  =====
+12 held of 384, 7168 x 2048        16   128    512
+================================  ====  ====  =====
+``plain``                          1.45  1.48   3.42
+``jax.lax.ragged_dot``             0.64  2.36   4.66
+megablox ``gmm``                   3.58  6.58  12.76
+``grouped``                        0.55  0.97   1.81
+================================  ====  ====  =====
+
+At 16 rows the first model's rows hit 45 of its 64 experts, which the
+kernel reads at 706 GB/s (86 % of the chip's bandwidth) where the plain
+form reads all 64; at 512 rows they hit all 64 and the plain form
+multiplies eight times the picks' products. At 128 rows they hit all 64
+and the products are still under a v5e's ridge (~240 rows), so both
+forms read every bank and come within 3 % of each other; the serving
+programs pass 16 or 32 rows (decode) and 512 (a chunk), so no row count
+selects the plain form. ``gmm`` copies an expert's weights again for
+every tile of its rows; ``ragged_dot`` is slower than the kernel at
+every row count measured.
 
 One function serves every program kind: the model's ``forward``, the
 engine's prefill, chunk and decode bodies (``text/generation.py``).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route", "routed_ffn"]
+from ..ops.pallas import grouped_swiglu as _gs
+
+__all__ = ["grouped", "plain", "route", "routed_ffn"]
 
 
 def route(m, wr, k, scoring="softmax", bias=None, scale=1.0):
@@ -68,6 +95,46 @@ def route(m, wr, k, scoring="softmax", bias=None, scale=1.0):
     return experts.astype(jnp.int32), weights
 
 
+def plain(m, local, w, wg, wu, wd):
+    """Every held expert applied to every row, each row's result the
+    weighted sum over its picks (weight zero elsewhere): the form off a
+    TPU, the grouped kernel's parity oracle and its backward. Arguments as
+    :func:`grouped`'s."""
+    T, held = m.shape[0], wg.shape[0]
+    c = jnp.zeros((T, held), jnp.float32).at[
+        jnp.arange(T)[:, None], local].set(w, mode="drop")
+    act = jax.nn.silu(jnp.einsum("th,ehf->etf", m, wg)) \
+        * jnp.einsum("th,ehf->etf", m, wu)
+    out = jnp.einsum("etf,efh->eth", act, wd)                 # [held, T, h]
+    y = jnp.einsum("te,eth->th", c, out.astype(jnp.float32))
+    return y.astype(m.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def grouped(m, local, w, wg, wu, wd, interpret=False):
+    """The picks grouped by expert through ``ops/pallas/grouped_swiglu.py``
+    (looked up at call time). ``local`` ``[T, k]`` indexes the banks,
+    ``held`` where a pick is not computed here; ``w`` ``[T, k]`` float32,
+    zero there. Its gradients are :func:`plain`'s."""
+    return _gs.grouped_swiglu(m, local, w, wg, wu, wd, interpret=interpret)
+
+
+def _grouped_fwd(m, local, w, wg, wu, wd, interpret):
+    return grouped(m, local, w, wg, wu, wd, interpret), \
+        (m, local, w, wg, wu, wd)
+
+
+def _grouped_bwd(interpret, res, ct):
+    m, local, w, wg, wu, wd = res
+    _, vjp = jax.vjp(lambda m, w, wg, wu, wd: plain(m, local, w, wg, wu, wd),
+                     m, w, wg, wu, wd)
+    dm, dw, dg, du, dd = vjp(ct)
+    return dm, None, dw, dg, du, dd
+
+
+grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
 def routed_ffn(m, wr, wg, wu, wd, k, valid=None, first=0, **router):
     """``sum_e c_e * (silu(m Wg_e) * (m Wu_e)) Wd_e`` over those of each
     row's ``k`` routed experts that are held here. ``m`` ``[T, h]``;
@@ -83,18 +150,23 @@ def routed_ffn(m, wr, wg, wu, wd, k, valid=None, first=0, **router):
     not): the others touch no expert's count and come back as zero rows.
     Every pick on a held expert is computed, whatever its expert's load.
 
+    The platform decides the form: on a TPU, wherever the banks tile,
+    :func:`grouped`; anywhere else :func:`plain`.
+
     Returns ``(y [T, h], picks [held] int32)``, ``picks`` the rows that
     picked each held expert."""
     experts, weights = route(m, wr, k, **router)
-    T, E, held = m.shape[0], wr.shape[-1], wg.shape[0]
-    c = jnp.zeros((T, E), jnp.float32).at[
-        jnp.arange(T)[:, None], experts].set(weights)
-    if held != E:
-        c = jax.lax.slice_in_dim(c, first, first + held, axis=1)
+    held = wg.shape[0]
+    local = experts - first
+    here = (local >= 0) & (local < held) & (weights > 0)
     if valid is not None:
-        c = jnp.where(valid[:, None], c, 0.0)
-    act = jax.nn.silu(jnp.einsum("th,ehf->etf", m, wg)) \
-        * jnp.einsum("th,ehf->etf", m, wu)
-    out = jnp.einsum("etf,efh->eth", act, wd)                 # [held, T, h]
-    y = jnp.einsum("te,eth->th", c, out.astype(jnp.float32))
-    return y.astype(m.dtype), jnp.sum(c > 0, axis=0, dtype=jnp.int32)
+        here = here & valid[:, None]
+    local = jnp.where(here, local, held)
+    weights = jnp.where(here, weights, 0.0)
+    picks = jnp.sum(local[..., None] == jnp.arange(held), axis=(0, 1),
+                    dtype=jnp.int32)
+    args = (m, local, weights, wg, wu, wd)
+    if _gs.blocks(m.shape[0], *wg.shape[1:], m.dtype.itemsize) is None:
+        return plain(*args), picks
+    return jax.lax.platform_dependent(*args, tpu=grouped,
+                                      default=plain), picks

@@ -121,6 +121,16 @@ def _ragged_group_matmul():
                                  ((8,), jnp.int32)]
 
 
+def _grouped_swiglu(T, held, h, f):
+    """The routed layer's kernel with its layout around it, as a TPU runs
+    ``routed_ffn``: 64 experts of 2304 x 896 (a whole expert a block), or
+    12 held of 7168 x 2048 (blocks of 256 of the width), top-8."""
+    from paddle_tpu.nn.routed_ffn import grouped
+    return grouped, [((T, h), jnp.bfloat16), ((T, 8), jnp.int32),
+                     ((T, 8), jnp.float32), ((held, h, f), jnp.bfloat16),
+                     ((held, h, f), jnp.bfloat16), ((held, f, h), jnp.bfloat16)]
+
+
 def _stochastic(name):
     from paddle_tpu.nn import quant
     return (functools.partial(getattr(quant, name), seed=7),
@@ -137,6 +147,11 @@ _CASES = {
         _paged_attention, 8 * 8193, 4, 512),
     # kimi-k2.6 as one chip of 32: a chunk of 512 rows against 2048 lines
     "chunk_attention-64_heads": _chunk_attention,
+    # mellum2 and kimi-k2.6 as one chip of 32: a decode step, a chunk
+    "grouped_swiglu-64_experts_16_rows": functools.partial(
+        _grouped_swiglu, 16, 64, 2304, 896),
+    "grouped_swiglu-12_held_512_rows": functools.partial(
+        _grouped_swiglu, 512, 12, 7168, 2048),
     "fused_ce_loss-fwd": functools.partial(_fused_ce, False),
     "fused_ce_loss-grad": functools.partial(_fused_ce, True),
     "int8_linear": _int8_linear,
@@ -333,6 +348,27 @@ def test_paged_program_keeps_the_pool_in_place(kind, one_chip,
         assert not re.search(r"= bf16\[16,\d+,32,128\]\S* gather\(", text)
 
 
+def _banks_seen(compiled, bank):
+    """The calls of the grouped kernel in ``compiled`` as a device trace
+    names an operation (``benchmarks/trace_reduce.short_name``: the
+    instruction with its operands' shapes, without layouts, cut at 160
+    characters), each of which must name the whole bank ``[E, h, f]``
+    there: the trace's readers find a routed layer's time by it."""
+    from jax._src.lib import xla_client as xc
+
+    from benchmarks import trace_reduce
+    options = xc._xla.HloPrintOptions()
+    options.print_operand_shape = True
+    options.print_metadata = False
+    text = "\n".join(m.to_string(options)
+                     for m in compiled.runtime_executable().hlo_modules())
+    calls = [trace_reduce.short_name(line) for line in re.findall(
+        r"^\s*(?:ROOT )?(%grouped_swiglu[.\d]* = .*)$", text, re.M)]
+    mark = "[" + ",".join(map(str, bank)) + "]"
+    assert all(mark in call for call in calls), calls
+    return calls
+
+
 # the routed serving cell's engine: 64 experts of 2304 x 896, 32 query and
 # 4 KV heads of 128 under a hidden size of 2304, a 98304-row head, depth 8
 # (three window layers of 1024 to one full layer, twice), 16 slots x 8192,
@@ -399,9 +435,9 @@ def test_routed_program_compiles_and_copies_neither_pool_nor_banks(
     step), the decode program holds the paged-attention kernel once a
     layer and no gathered view, and the chunk's
     full layer walks its 8192 keys in tiles (one pass over float32
-    ``[4, 8, 512, 8192]`` scores took 47 ms on the chip). Every expert is
-    applied to every row: no grouped-matmul kernel is in any of the
-    three."""
+    ``[4, 8, 512, 8192]`` scores took 47 ms on the chip). Each routed
+    layer is one call of the grouped kernel, which a device trace names
+    beside the whole banks' shapes."""
     fn, shapes, statics = _routed_program(kind)
     args = jax.tree.map(
         lambda sd: jax.ShapeDtypeStruct(*sd, sharding=one_chip), shapes,
@@ -425,11 +461,16 @@ def test_routed_program_compiles_and_copies_neither_pool_nor_banks(
         if n in (pool_elements, pool_elements // _R_LAYERS, bank_elements):
             moved.append((op, shape))
     assert not moved
+    calls = len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text))
+    assert len(_banks_seen(compiled, (_R_EXPERTS, _R_HIDDEN, _R_FF))) \
+        == _R_LAYERS
     if kind == "decode":
-        # window layers and full ones run the same kernel, one a layer
-        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
-                              text)) == _R_LAYERS
+        # window layers and full ones run the same kernel, one a layer,
+        # beside the grouped one
+        assert calls == 2 * _R_LAYERS
         assert not re.search(r"= bf16\[16,\d+,4,128\]\S* gather\(", text)
+    else:
+        assert calls == _R_LAYERS
 
 
 # the latent serving cell's engine: hidden 7168, 64 heads over a latent of
@@ -526,15 +567,17 @@ def test_latent_program_compiles_and_moves_neither_pool_nor_banks(
         if n in (pool_elements, pool_elements // _K_LAYERS, bank_elements):
             moved.append((op, shape))
     assert not moved
+    calls = len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text))
+    # each routed layer's held experts: one call of the grouped kernel
+    assert len(_banks_seen(compiled, (_K_HELD, _K_HIDDEN, 2048))) \
+        == _K_LAYERS - 1
     if kind == "decode":
-        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
-                              text)) == _K_LAYERS
+        assert calls == _K_LAYERS + _K_LAYERS - 1
         assert "paged_latent_attention" in text
         assert not re.search(r"\[32,16384,", text)
     if kind == "chunk":
         from paddle_tpu.text.generation import _LATENT_TILE
-        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
-                              text)) == _K_LAYERS
+        assert calls == _K_LAYERS + _K_LAYERS - 1
         assert "chunk_attention" in text
         assert not re.search(rf"\[{_K_HEADS},512,{_LATENT_TILE}\]", text)
         assert not re.search(rf"\[{_LATENT_TILE},{_K_HEADS},256\]", text)
