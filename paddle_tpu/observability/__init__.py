@@ -28,11 +28,17 @@ Four pieces, one import:
 * **The serving engine's stamps** (``serving.metrics``, always on, no
   switch): the clock reads behind those spans are kept whether or not
   the tracer is on, as ``StepRecord`` / ``LaunchRecord`` /
-  ``SubmitRecord`` tuples in bounded rings on ``EngineMetrics``
-  (``.steps``, ``.launches``, ``.submits``). Read them through
+  ``SubmitRecord`` / ``AdmitRecord`` tuples in bounded rings on
+  ``EngineMetrics`` (``.steps``, ``.launches``, ``.submits``,
+  ``.admits``), a step and a submit with its thread's CPU seconds,
+  context switches, page faults and collector pauses. Read them through
   ``Engine.stats()["step_phase_seconds"]`` (cumulative seconds by
-  phase), ``serving.metrics.live_metrics()`` (every live engine's rings)
-  or the ``paddle_serving_step_phase_seconds_total{phase}`` family.
+  phase), ``["host_cpu_seconds"]`` (the thread's CPU seconds in steps
+  and in submits), ``["gc_pause_seconds"]``,
+  ``serving.metrics.live_metrics()`` (every live engine's rings) or the
+  ``paddle_serving_step_phase_seconds_total{phase}``,
+  ``paddle_serving_host_cpu_seconds_total{call}`` and
+  ``paddle_serving_gc_pause_seconds_total`` families.
 * **Compile attribution** (``compile_attr``): every XLA backend
   compile counted + timed under the subsystem that triggered it
   (``compile_scope``), as metrics and (when tracing) ``xla.compile``

@@ -53,6 +53,15 @@ def _serving_families():
                "fetch, emit) summed across live engines",
                [({"phase": k}, v)
                 for k, v in t["step_phase_seconds"].items()])
+    yield _fam("paddle_serving_host_cpu_seconds_total", "counter",
+               "CPU seconds of the engine's host thread in its calls "
+               "(Engine.step(), submit()), summed across live engines",
+               [({"call": k}, v)
+                for k, v in t["host_cpu_seconds"].items()])
+    yield _fam("paddle_serving_gc_pause_seconds_total", "counter",
+               "seconds of collector pauses inside Engine.step() and "
+               "submit() calls, summed across live engines",
+               [({}, t["gc_pause_seconds"])])
     gauges = [("engines", t["engines"]),
               ("peak_queue_depth", t["peak_queue_depth"]),
               ("peak_active", t["peak_active"])]

@@ -60,11 +60,14 @@ decoding with zero extra lowerings, replay/migration-safe.
 
 The engine stamps itself (``time.perf_counter()``, always on): every
 ``step()`` with decode-active rows leaves a ``StepRecord`` (begin,
-scheduled, dispatched, fetched, end) and every program launch a
-``LaunchRecord`` (called, dispatched, fetched) in the bounded rings of
-``self.metrics`` (serving/metrics.py), and every ``submit()`` a
-``SubmitRecord`` (entry, return: admission runs inside it when a slot is
-free). The decode launch's called -> fetched is what
+admitted, chunked, scheduled, dispatched, fetched, end, and what its
+thread went through: CPU seconds, switches, faults, collector pauses),
+every program launch a ``LaunchRecord`` (called, dispatched,
+fetched) and every admission attempt an ``AdmitRecord`` (begin, placed,
+end) in the bounded rings of ``self.metrics`` (serving/metrics.py), and
+every ``submit()`` a ``SubmitRecord`` (entry, return: admission runs
+inside it when a slot is free; its thread's account too). The decode
+launch's called -> fetched is what
 ``metrics.mark_decode`` is given, so the ITL estimate behind
 ``retry_after_s``, brownout and fleet routing covers the token fetch; and
 when the span tracer is on the same stamps become ``serving.step`` with
@@ -86,8 +89,9 @@ from ..observability import tracing as _tracing
 from ..observability.compile_attr import compile_scope as _compile_scope
 from ..tensor import Tensor
 from .kv_cache import PagedKVCache
-from .metrics import (EngineMetrics, LaunchRecord, RequestMetrics,
-                      StepRecord, SubmitRecord)
+from .metrics import (AdmitRecord, EngineMetrics, LaunchRecord,
+                      RequestMetrics, StepRecord, SubmitRecord,
+                      thread_counters, thread_delta)
 from .scheduler import (EngineOverloaded, FIFOScheduler,  # noqa: F401
                         PriorityScheduler)
 
@@ -1539,6 +1543,7 @@ class Engine:
         untouched, and adopt()/replay re-samples under the same mask so
         constrained requests migrate token-identically."""
         begin = time.perf_counter()
+        before = thread_counters()
         ids = self._as_ids(prompt)
         if ids.shape[0] < 1:
             raise ValueError("empty prompt")
@@ -1571,25 +1576,29 @@ class Engine:
             max_time_s=max_time_s, priority=priority,
             logit_mask=logit_mask)
         self.metrics.requests_submitted += 1
-        _tracing.instant("serving.submit", cat="serving",
-                         trace_id=h.trace_id, request_id=rid,
-                         n_prompt=h.n_prompt, priority=h.priority)
+        if _tracing._ENABLED:
+            _tracing.instant("serving.submit", cat="serving",
+                             trace_id=h.trace_id, request_id=rid,
+                             n_prompt=h.n_prompt, priority=h.priority)
         try:
             self.scheduler.enqueue(h, retry_after_s=self._retry_after_hint())
         except EngineOverloaded:
             self.metrics.requests_rejected += 1
             raise
         self._admit()
-        self._submitted(h, begin)
+        self._submitted(h, begin, before)
         return h
 
-    def _submitted(self, h, begin):
-        """Close the stamps of a ``submit()`` / ``adopt()`` call."""
+    def _submitted(self, h, begin, before):
+        """Close the stamps of a ``submit()`` / ``adopt()`` call that
+        began at ``begin`` with its thread's counters at ``before``."""
         end = time.perf_counter()
-        self.metrics.mark_submit(SubmitRecord(h.request_id, begin, end))
-        _tracing.span_event("serving.submit_call", begin, end,
-                            cat="serving", trace_id=h.trace_id,
-                            request_id=h.request_id)
+        self.metrics.mark_submit(SubmitRecord(
+            h.request_id, begin, end, thread_delta(before)))
+        if _tracing._ENABLED:
+            _tracing.span_event("serving.submit_call", begin, end,
+                                cat="serving", trace_id=h.trace_id,
+                                request_id=h.request_id)
 
     def _retry_after_hint(self):
         """Seconds until a slot plausibly frees: the rolling inter-token
@@ -1635,14 +1644,17 @@ class Engine:
             [h.prompt_ids, np.asarray(h.tokens, np.int32)])
 
     def _admit_one(self, h):
+        """Admit ``h`` into a free slot, False where the pool cannot cover
+        it even after radix eviction; either way leave its
+        ``AdmitRecord``."""
         # supervisor replay (adopt()) re-prefills prompt + the k tokens
         # the crashed incarnation already emitted and fast-forwards the
         # PRNG chain k splits — the next sampled token is exactly what
         # the uninterrupted run would have produced. Normal admission is
         # the k=0 degenerate case (same program). Preemption on pool
         # exhaustion re-enters through the same path.
-        k = len(h.tokens)
-        n_eff = h.n_prompt + k
+        begin = time.perf_counter()
+        evicted = self.cache.radix.evicted
         full = self._full_ids(h)
         slot = self.cache.alloc(h.request_id)
         # wire block-table coverage for [0, n_eff] (prompt + replay
@@ -1650,12 +1662,25 @@ class Engine:
         # any cached full-block prefix (memory dedup + skipped chunk
         # compute), copy-on-write on a partial tail block
         match_ids = full if self.prefix_sharing else full[:0]
-        admitted = self.cache.admit(slot, match_ids, n_eff + 1)
+        admitted = self.cache.admit(slot, match_ids, len(full) + 1)
+        placed = time.perf_counter()
         if admitted is None:
             self.cache.free(slot)
             h.slot = None
-            return False
-        n_shared, cow = admitted
+        else:
+            self._place(h, slot, full, *admitted)
+        self.metrics.mark_admit(AdmitRecord(
+            h.request_id, self._step, begin, placed, time.perf_counter(),
+            0 if admitted is None else min(admitted[0], len(full)),
+            self.cache.radix.evicted - evicted))
+        return admitted is not None
+
+    def _place(self, h, slot, full, n_shared, cow):
+        """Start ``h``, admitted into ``slot`` with ``n_shared`` tokens of
+        ``full`` from the radix index: queue its chunked prefill, or
+        launch its bucket prefill and emit its first token."""
+        k = len(h.tokens)
+        n_eff = len(full)
         h.slot = slot
         self._by_slot[slot] = h
         self._temps[slot] = h.temperature
@@ -1674,23 +1699,25 @@ class Engine:
             # the last prompt token (the sampling row) always runs.
             C = self.prefill_chunk
             start = (min(n_shared, n_eff - 1) // C) * C
-            _tracing.span_event("serving.queue", h._queued_t,
-                                time.perf_counter(), cat="serving",
-                                trace_id=h.trace_id,
-                                request_id=h.request_id)
+            if _tracing._ENABLED:
+                _tracing.span_event("serving.queue", h._queued_t,
+                                    time.perf_counter(), cat="serving",
+                                    trace_id=h.trace_id,
+                                    request_id=h.request_id)
             self._chunking.append(
                 _ChunkState(h, full, n_eff, n_shared, start))
             self.metrics.chunked_prefills += 1
-            return True
+            return
         self.metrics.mark_scan(n_eff, first=True, replay=k > 0)
         Lb = self._bucket(n_eff)
         self.buckets_seen.add(Lb)
         ids = np.zeros((1, Lb), np.int32)
         ids[0, :n_eff] = full
         called = time.perf_counter()
-        _tracing.span_event("serving.queue", h._queued_t, called,
-                            cat="serving", trace_id=h.trace_id,
-                            request_id=h.request_id)
+        if _tracing._ENABLED:
+            _tracing.span_event("serving.queue", h._queued_t, called,
+                                cat="serving", trace_id=h.trace_id,
+                                request_id=h.request_id)
         with _compile_scope(f"prefill:L{Lb}"):
             out = self._run_program(
                 "prefill", ("prefill", Lb), self._prefill,
@@ -1717,7 +1744,6 @@ class Engine:
         self._emit(h, tok0)
         if self._spec is not None and not h.finished:
             self._spec.on_admit(h, full)
-        return True
 
     def _chunk_tick(self):
         """Advance the oldest in-progress chunked prefill by ONE chunk
@@ -1859,16 +1885,18 @@ class Engine:
         handle.replica_id = self.replica_id
         handle.model_fingerprint = self.model_fingerprint
         begin = handle._queued_t = time.perf_counter()
+        before = thread_counters()
         self._next_id = max(self._next_id, handle.request_id + 1)
         self.metrics.requests_submitted += 1
-        _tracing.instant("serving.adopt", cat="serving",
-                         trace_id=handle.trace_id,
-                         request_id=handle.request_id,
-                         replayed_tokens=len(handle.tokens))
+        if _tracing._ENABLED:
+            _tracing.instant("serving.adopt", cat="serving",
+                             trace_id=handle.trace_id,
+                             request_id=handle.request_id,
+                             replayed_tokens=len(handle.tokens))
         self.scheduler.enqueue(handle,
                                retry_after_s=self._retry_after_hint())
         self._admit()
-        self._submitted(handle, begin)
+        self._submitted(handle, begin, before)
         return handle
 
     def cancel(self, handle):
@@ -1936,11 +1964,14 @@ class Engine:
         the reads become the step's ``StepRecord`` and, with the tracer
         on, its spans."""
         begin = time.perf_counter()
+        before = thread_counters()
         launches = self.metrics.launches_recorded
         self._expire()
         self._admit()
+        admitted = chunked = time.perf_counter()
         if self._chunking:
             self._chunk_tick()
+            chunked = time.perf_counter()
         active = self._decode_active()
         self._ensure_decode_capacity(active)
         active = self._decode_active()     # preemption may shrink it
@@ -1962,7 +1993,8 @@ class Engine:
             dispatched, fetched = self._decode_once(active, n_active)
         end = time.perf_counter()
         rec = StepRecord(self._step, kind, begin, scheduled,
-                         dispatched or end, fetched or end, end, n_active)
+                         dispatched or end, fetched or end, end, n_active,
+                         admitted, chunked, thread_delta(before))
         self.metrics.mark_step(rec)
         if _tracing._ENABLED:
             sid = _tracing.span_event("serving.step", begin, end,

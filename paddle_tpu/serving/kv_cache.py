@@ -116,6 +116,7 @@ class RadixIndex:
         self._clock = 0
         self._touch = {}            # node -> last-use tick (LRU eviction)
         self._blocks = set()        # the pool blocks the nodes hold
+        self.evicted = 0            # blocks ``evict`` has freed, ever
 
     def _chunks(self, tokens):
         bs = self.block_size
@@ -228,6 +229,7 @@ class RadixIndex:
                 heapq.heappush(heap, (self._touch.get(parent, 0), order,
                                       parent))
                 order += 1
+        self.evicted += freed
         return freed
 
     def clear(self, pool):

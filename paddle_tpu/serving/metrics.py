@@ -7,17 +7,28 @@ every decode step, admission/eviction counters. Snapshots surface through
 the eager dispatch cache).
 
 Per-engine too, always on: the ``perf_counter`` stamps the engine takes
-inside ``Engine.step()`` and around every program it launches, as
-:class:`StepRecord` / :class:`LaunchRecord` / :class:`SubmitRecord`
-tuples in bounded rings (``EngineMetrics.steps``, ``.launches``,
-``.submits``; :func:`live_metrics` finds every live engine's), with
-cumulative seconds by phase beside them. The same stamps feed
-``mark_decode`` (so the ITL estimate covers the token fetch) and, when
-the span tracer is on, its ``serving.step`` spans.
+inside ``Engine.step()``, around every program it launches and every
+admission, as :class:`StepRecord` / :class:`LaunchRecord` /
+:class:`SubmitRecord` / :class:`AdmitRecord` tuples in bounded rings
+(``EngineMetrics.steps``, ``.launches``, ``.submits``, ``.admits``;
+:func:`live_metrics` finds every live engine's), with cumulative seconds
+by phase beside them. The same stamps feed ``mark_decode`` (so the ITL
+estimate covers the token fetch) and, when the span tracer is on, its
+``serving.step`` spans.
+
+The host thread accounts for itself on the same records: a step or a
+``submit()`` call carries a :class:`ThreadDelta`, what its thread went
+through over the call: CPU seconds, context switches and page faults
+(``getrusage(RUSAGE_THREAD)``, read at the call's entry and exit) and the
+collector's pauses. CPU seconds are the whole call's: a kernel that
+counts a thread's time in scheduler ticks steps them by 10 ms, too
+coarse for a phase of a millisecond, fine for a call that stalls.
 """
 from __future__ import annotations
 
 import collections
+import gc
+import resource
 import time
 import weakref
 
@@ -81,24 +92,86 @@ class RequestMetrics:
 
 
 PHASES = ("schedule", "dispatch", "fetch", "emit")
+# the schedule phase's three parts, in order, and the stamps that bound
+# each (a part's seconds are its end stamp less its start stamp)
+PARTS = {"admission": ("begin", "admitted"),
+         "chunk": ("admitted", "chunked"),
+         "capacity": ("chunked", "scheduled")}
+CALLS = ("step", "submit")   # the calls whose thread CPU is counted
 RING = 8192     # records a ring keeps: minutes of steps at 10 ms a step
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", resource.RUSAGE_SELF)
+
+
+# the collector's pauses over the process's life: seconds, collections,
+# and the perf_counter at which the running one started (None between)
+_GC = [0.0, 0, None]
+
+
+def _on_collection(phase, info):
+    if phase == "start":
+        _GC[2] = time.perf_counter()
+    elif _GC[2] is not None:      # a stop whose start the hook saw
+        _GC[0] += time.perf_counter() - _GC[2]
+        _GC[1] += 1
+        _GC[2] = None
+
+
+def _watch_collector():
+    """Hook the collector once a process (``gc.callbacks``)."""
+    if _on_collection not in gc.callbacks:
+        gc.callbacks.append(_on_collection)
+
+
+# What a thread went through over one call: its CPU seconds (user and
+# system), involuntary and voluntary context switches and minor and major
+# page faults (``getrusage(RUSAGE_THREAD)``), and the seconds and number
+# of the collector's pauses (process-wide: a collection holds every
+# thread).
+ThreadDelta = collections.namedtuple(
+    "ThreadDelta", "cpu involuntary voluntary minor_faults major_faults "
+                   "gc_seconds gc_collections")
+
+
+def thread_counters():
+    """The calling thread's counters so far, in ``ThreadDelta``'s order."""
+    ru = resource.getrusage(_RUSAGE_THREAD)
+    return (ru.ru_utime + ru.ru_stime, ru.ru_nivcsw, ru.ru_nvcsw,
+            ru.ru_minflt, ru.ru_majflt, _GC[0], _GC[1])
+
+
+def thread_delta(before):
+    """The ``ThreadDelta`` since ``before`` (a :func:`thread_counters`)."""
+    ru = resource.getrusage(_RUSAGE_THREAD)
+    return ThreadDelta(ru.ru_utime + ru.ru_stime - before[0],
+                       ru.ru_nivcsw - before[1], ru.ru_nvcsw - before[2],
+                       ru.ru_minflt - before[3], ru.ru_majflt - before[4],
+                       _GC[0] - before[5], _GC[1] - before[6])
 
 
 class StepRecord(collections.namedtuple(
         "StepRecord", "index kind begin scheduled dispatched fetched end "
-                      "n_active")):
+                      "n_active admitted chunked thread",
+        defaults=(None, None, None))):
     """One ``Engine.step()`` that had decode-active rows. ``kind`` is
     ``decode`` (nothing prefilled), ``admit`` (a bucket prefill or a
     chunk ran beside the decode) or ``spec``. The stamps are
-    ``time.perf_counter()`` reads, in order: ``begin``; ``scheduled``
-    (expiry, admission, the chunk tick, decode capacity and the
-    occupancy sample are done); ``dispatched`` (the decode program's
-    call has returned: argument copies, upload and enqueue are behind
-    it); ``fetched`` (its tokens are on the host); ``end`` (every token
-    emitted). A ``spec`` step launches several programs: ``dispatched``
-    is the first target launch's (the draft's proposals lie before it)
-    and ``fetched`` the last one's; the launches between them are in
-    the launch ring under the step's index."""
+    ``time.perf_counter()`` reads, in order: ``begin``; ``admitted``
+    (expiry and the admission attempt are done: the free-token count, the
+    radix match, block allocation and evictions of each request admitted,
+    and any bucket prefill launched, its first token fetched);
+    ``chunked`` (the chunk tick is done: a chunk launched, and on a
+    request's final chunk its first token fetched; ``admitted`` itself
+    where no chunk ran); ``scheduled`` (decode capacity, evictions and
+    pre-emption included, and the occupancy sample are done);
+    ``dispatched`` (the decode program's call has returned: argument
+    copies, upload and enqueue are behind it); ``fetched`` (its tokens
+    are on the host); ``end`` (every token emitted). ``admitted`` and ``chunked`` split the schedule phase into
+    the three :data:`PARTS` (``parts()``). A ``spec`` step launches
+    several programs: ``dispatched`` is the first target launch's (the
+    draft's proposals lie before it) and ``fetched`` the last one's; the
+    launches between them are in the launch ring under the step's index.
+    ``thread`` is the step's :class:`ThreadDelta`. The last three fields
+    are None on a record made without them."""
     __slots__ = ()
 
     def intervals(self):
@@ -108,6 +181,17 @@ class StepRecord(collections.namedtuple(
     def phases(self):
         """Seconds of the four phases; they sum to ``end - begin``."""
         return {p: t1 - t0 for p, t0, t1 in self.intervals()}
+
+    def part_intervals(self):
+        """``[(part, t0, t1)]`` of the schedule phase's three parts, in
+        order."""
+        return [(p, getattr(self, a), getattr(self, b))
+                for p, (a, b) in PARTS.items()]
+
+    def parts(self):
+        """Seconds of the schedule phase's three parts; they sum to
+        ``scheduled - begin``."""
+        return {p: t1 - t0 for p, t0, t1 in self.part_intervals()}
 
 
 # One engine program invoked: ``program`` as the compile scope names it
@@ -124,10 +208,22 @@ LaunchRecord = collections.namedtuple(
 
 # One ``Engine.submit()`` or ``adopt()`` that enqueued its request, from
 # entry to return: validation, the queue, and, where a slot was free, the
-# admission itself (block allocation with radix eviction, a bucket
-# prefill's launch: that one also has its ``LaunchRecord``, with no step).
-SubmitRecord = collections.namedtuple("SubmitRecord",
-                                      "request_id begin end")
+# admission itself (an ``AdmitRecord`` with no step, and a bucket
+# prefill's ``LaunchRecord``, with no step either). ``thread`` is the
+# call's ``ThreadDelta``.
+SubmitRecord = collections.namedtuple(
+    "SubmitRecord", "request_id begin end thread", defaults=(None,))
+
+# One admission attempt (``Engine._admit_one``), inside ``submit()`` /
+# ``adopt()`` (``step`` None) or inside a step: ``begin``; ``placed``
+# when the cache had answered (a slot, the radix match, block allocation
+# with evictions, the copy-on-write of a shared tail; or no room, and
+# the request went back to the queue); ``end`` when the prefill was
+# launched and its first token emitted, or the chunked prefill queued.
+# ``shared`` is the prompt tokens the radix index served, ``evicted``
+# the index's blocks evicted meanwhile.
+AdmitRecord = collections.namedtuple(
+    "AdmitRecord", "request_id step begin placed end shared evicted")
 
 
 class EngineMetrics:
@@ -206,17 +302,33 @@ class EngineMetrics:
         self.steps = collections.deque(maxlen=RING)
         self.launches = collections.deque(maxlen=RING)
         self.submits = collections.deque(maxlen=RING)
+        self.admits = collections.deque(maxlen=RING)
         self.steps_recorded = 0
         self.launches_recorded = 0
         self.phase_seconds = dict.fromkeys(PHASES, 0.0)
         self.submit_seconds = 0.0
+        # the host thread's CPU seconds in its steps and its
+        # ``submit()`` calls, and what they sat through
+        self.host_cpu_seconds = dict.fromkeys(CALLS, 0.0)
+        self.gc_pause_seconds = 0.0
+        self.gc_collections = 0
+        self.involuntary_switches = 0
+        _watch_collector()
         _register(self)
+
+    def _mark_thread(self, call, delta):
+        if delta is not None:
+            self.host_cpu_seconds[call] += delta.cpu
+            self.gc_pause_seconds += delta.gc_seconds
+            self.gc_collections += delta.gc_collections
+            self.involuntary_switches += delta.involuntary
 
     def mark_step(self, rec):
         self.steps.append(rec)
         self.steps_recorded += 1
         for phase, t0, t1 in rec.intervals():
             self.phase_seconds[phase] += t1 - t0
+        self._mark_thread("step", rec.thread)
 
     def mark_launch(self, rec):
         self.launches.append(rec)
@@ -225,6 +337,10 @@ class EngineMetrics:
     def mark_submit(self, rec):
         self.submits.append(rec)
         self.submit_seconds += rec.end - rec.begin
+        self._mark_thread("submit", rec.thread)
+
+    def mark_admit(self, rec):
+        self.admits.append(rec)
 
     def sample(self, occupancy, queue_depth, active=0, pool_free=None,
                pool_total=None):
@@ -427,6 +543,11 @@ class EngineMetrics:
             "step_phase_seconds": {k: round(v, 6) for k, v in
                                    self.phase_seconds.items()},
             "submit_seconds": round(self.submit_seconds, 6),
+            "host_cpu_seconds": {k: round(v, 6) for k, v in
+                                 self.host_cpu_seconds.items()},
+            "gc_pause_seconds": round(self.gc_pause_seconds, 6),
+            "gc_collections": self.gc_collections,
+            "involuntary_switches": self.involuntary_switches,
             "replica": self.replica,
             "tp": self.tp,
             "kv_pool_bytes_per_device": self.kv_pool_bytes_per_device,
@@ -448,10 +569,11 @@ def _register(m):
 
 def live_metrics():
     """The ``EngineMetrics`` of every live engine, oldest first: the way
-    to their rings (``.steps``, ``.launches``, ``.submits``:
-    :class:`StepRecord`, :class:`LaunchRecord` and :class:`SubmitRecord`,
-    oldest record first, every stamp a ``time.perf_counter()`` of this
-    process) for whoever holds no engine."""
+    to their rings (``.steps``, ``.launches``, ``.submits``, ``.admits``:
+    :class:`StepRecord`, :class:`LaunchRecord`, :class:`SubmitRecord` and
+    :class:`AdmitRecord`, oldest record first, every stamp a
+    ``time.perf_counter()`` of this process) for whoever holds no
+    engine."""
     live = [(ref, ref()) for ref in _ENGINES]
     _ENGINES[:] = [ref for ref, m in live if m is not None]
     return [m for _, m in live if m is not None]
@@ -473,12 +595,17 @@ def global_counters():
         "spec_accepted_tokens": 0, "spec_emitted_tokens": 0,
         "spec_acceptance_rate": None,
         "step_phase_seconds": dict.fromkeys(PHASES, 0.0),
+        "host_cpu_seconds": dict.fromkeys(CALLS, 0.0),
+        "gc_pause_seconds": 0.0,
     }
     for m in live_metrics():
         s = m.snapshot()
         total["engines"] += 1
         for k, v in m.phase_seconds.items():
             total["step_phase_seconds"][k] += v
+        for k, v in m.host_cpu_seconds.items():
+            total["host_cpu_seconds"][k] += v
+        total["gc_pause_seconds"] += m.gc_pause_seconds
         for k in ("requests_submitted", "requests_completed",
                   "requests_rejected", "requests_timed_out",
                   "requests_cancelled", "requests_shed",

@@ -13,12 +13,19 @@ reports them (when on).
 * the rings are bounded, fill with the tracer off, and with it on the
   same stamps are ``serving.step`` with its phases as children;
 * a live ``tracing.span`` and ``profiler.RecordEvent`` are in a
-  ``jax.profiler`` trace by name.
+  ``jax.profiler`` trace by name;
+* the host thread accounts for itself: ``admitted`` and ``chunked`` split
+  the schedule phase into admission, chunk and capacity, which sum to it
+  exactly and take what a slow radix match, eviction or first-token wait
+  costs; every admission leaves an ``AdmitRecord``; a step's and a
+  submit's thread CPU lies within its wall time; a collection inside a
+  step shows on the step's record.
 
 One tiny module-scope model at the geometry the other serving suites use
 (shared jit programs).
 """
 import dataclasses
+import gc
 import glob
 import os
 import time
@@ -423,20 +430,240 @@ def test_live_spans_and_record_events_are_in_the_profilers_trace(
 
 
 def test_stamping_a_step_costs_microseconds():
-    """The always-on part of a step: seven clock reads, a StepRecord and
-    a LaunchRecord into the rings. Budget 5 us; the bound here is loose
-    (a loaded CI core), the measured figure is in PERF.md."""
+    """The always-on part of a step: nine clock reads, the thread's
+    counters at its begin and its end, a StepRecord and a LaunchRecord
+    into the rings. The bound here is loose (a loaded CI core); the
+    figures measured on a CPU host and on the chip's host, where the
+    thread's counters cost a kernel call each, are in PERF.md."""
     m = serving_metrics.EngineMetrics()
     clock = time.perf_counter
     n = 20_000
     t0 = clock()
     for i in range(n):
-        a, b, c, d, e, f, g = (clock(), clock(), clock(), clock(),
-                               clock(), clock(), clock())
+        a = clock()
+        before = serving_metrics.thread_counters()
+        b, c, d, e, f, g, h = (clock(), clock(), clock(), clock(), clock(),
+                               clock(), clock())
         m.mark_launch(serving_metrics.LaunchRecord(
-            "decode", i, c, d, e, f, 16, 0))
+            "decode", i, e, f, g, None, 16, 0))
         m.mark_step(serving_metrics.StepRecord(
-            i, "decode", a, b, d, e, g, 16))
+            i, "decode", a, d, f, g, h, 16, b, c,
+            serving_metrics.thread_delta(before)))
     per_step = (clock() - t0) / n
     assert per_step < 50e-6
     assert m.steps_recorded == n and len(m.steps) == serving_metrics.RING
+    assert m.host_cpu_seconds["step"] > 0
+
+
+def _parts_sum_to_schedule(r):
+    """The three parts lie inside the schedule phase, in order, and add
+    up to it to the last bit (each is a difference of two nearby clock
+    reads, exact in floating point, and so is their sum)."""
+    assert r.begin <= r.admitted <= r.chunked <= r.scheduled
+    parts = r.parts()
+    assert list(parts) == list(serving_metrics.PARTS)
+    assert sum(parts.values()) == r.phases()["schedule"]
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_the_schedule_phase_splits_into_three_parts(model, layout):
+    eng = Engine(model, **LAYOUTS[layout])
+    _churn(eng)
+    steps = list(eng.metrics.steps)
+    for r in steps:
+        _parts_sum_to_schedule(r)
+        # the four phases keep their stamps and their meaning
+        assert [p for p, _, _ in r.intervals()] == list(
+            serving_metrics.PHASES)
+        assert [t for _, t, _ in r.intervals()] == [
+            r.begin, r.scheduled, r.dispatched, r.fetched]
+    # a step that ran a chunk spent it between admitted and chunked, one
+    # that ran a bucket prefill between begin and admitted
+    launches = list(eng.metrics.launches)
+    for r in steps:
+        mine = [x for x in launches if x.step == r.index]
+        for x in mine:
+            if x.program == "chunk":
+                assert r.admitted <= x.called and \
+                    (x.fetched or x.dispatched) <= r.chunked
+            elif x.program.startswith("prefill:"):
+                assert r.begin <= x.called and x.fetched <= r.admitted
+    if layout == "paged_chunked":
+        assert any(x.program == "chunk" for x in launches)
+
+
+def _slow(obj, name, seconds=SLOW):
+    """Make ``obj.name`` take ``seconds`` longer, in the calling thread
+    (a sleep: off the CPU, so wall time and not CPU time)."""
+    fn = getattr(obj, name)
+
+    def slow(*args, **kwargs):
+        time.sleep(seconds)
+        return fn(*args, **kwargs)
+
+    setattr(obj, name, slow)
+
+
+def test_a_slow_radix_match_lands_in_admission_and_placed(model):
+    eng = Engine(model, **GEO)
+    _churn(eng)                  # compiles
+    warm_steps, warm_admits = (eng.metrics.steps_recorded,
+                               len(eng.metrics.admits))
+    _slow(eng.cache.radix, "match")
+    # two requests fill both slots inside submit(); the third is admitted
+    # inside the step after the first finishes
+    handles = [eng.submit(p, max_new_tokens=n)
+               for p, n in zip(_prompts([5, 6, 7], 9), (2, 6, 3))]
+    eng.drain()
+    assert all(h.finish_reason == "length" for h in handles)
+    admits = list(eng.metrics.admits)[warm_admits:]
+    assert [a.request_id for a in admits] == [h.request_id
+                                             for h in handles]
+    assert [a.step is None for a in admits] == [True, True, False]
+    for a in admits:
+        assert a.placed - a.begin >= SLOW
+        assert a.end - a.placed < SLOW
+    steps = list(eng.metrics.steps)[
+        -(eng.metrics.steps_recorded - warm_steps):]
+    admitting = [r for r in steps if r.index == admits[2].step]
+    assert len(admitting) == 1
+    r = admitting[0]
+    assert r.parts()["admission"] >= SLOW
+    assert r.admitted >= admits[2].end >= admits[2].begin >= r.begin
+    # the sleep is wall time the thread spent off the CPU
+    assert r.thread.cpu <= r.end - r.begin - SLOW / 2
+    others = [x for x in steps if x is not r]
+    assert others and all(x.parts()["admission"] < SLOW for x in others)
+    assert all(x.parts()["capacity"] < SLOW for x in steps)
+
+
+def test_a_slow_eviction_lands_in_capacity(model):
+    eng = Engine(model, **GEO)
+    _churn(eng)
+    warm = eng.metrics.steps_recorded
+    # a decode row that crosses into a new block asks ensure() for it
+    _slow(eng.cache, "_alloc_or_evict")
+    h = eng.submit(_prompts([6], 10)[0], max_new_tokens=6)
+    eng.drain()
+    assert h.finish_reason == "length"
+    steps = list(eng.metrics.steps)[-(eng.metrics.steps_recorded - warm):]
+    slow = [r for r in steps if r.parts()["capacity"] >= SLOW]
+    # positions 6 .. 10: the row's next line crosses into block 1 once
+    assert len(slow) == 1
+    assert all(r.parts()["admission"] < SLOW for r in steps)
+    for r in steps:
+        _parts_sum_to_schedule(r)
+
+
+def test_a_final_chunks_token_wait_lands_in_chunk(model):
+    eng = Engine(model, **LAYOUTS["paged_chunked"])
+    _churn(eng)
+    warm = eng.metrics.steps_recorded
+    first = eng.submit(_prompts([5], 11)[0], max_new_tokens=12)
+    eng.step()                   # first decodes while the next chunks
+    _slow_results(eng, "chunk", 5)
+    second = eng.submit(_prompts([21], 12)[0], max_new_tokens=2)
+    eng.drain()
+    assert {first.finish_reason, second.finish_reason} == {"length"}
+    steps = list(eng.metrics.steps)[-(eng.metrics.steps_recorded - warm):]
+    launches = [x for x in eng.metrics.launches if x.program == "chunk"
+                and x.request_id == second.request_id]
+    final = [x for x in launches if x.fetched is not None]
+    assert len(launches) == 3 and len(final) == 1
+    waited = [r for r in steps if r.index == final[0].step]
+    assert len(waited) == 1 and waited[0].parts()["chunk"] >= SLOW
+    assert waited[0].thread.cpu <= waited[0].end - waited[0].begin - SLOW / 2
+    assert all(r.parts()["chunk"] < SLOW for r in steps
+               if r is not waited[0])
+
+
+def test_a_collection_inside_a_step_shows_on_its_record(model):
+    eng = Engine(model, **GEO)
+    _churn(eng)
+    before = eng.stats()
+    collected = []
+
+    def on_token(h, token):
+        if len(h.tokens) == 3:
+            gc.collect()
+            collected.append(eng._step)
+
+    eng.submit(_prompts([5], 13)[0], max_new_tokens=5, on_token=on_token)
+    eng.drain()
+    assert len(collected) == 1 and collected[0] is not None
+    by_index = {r.index: r for r in eng.metrics.steps}
+    r = by_index[collected[0]]
+    assert r.thread.gc_collections >= 1 and r.thread.gc_seconds > 0
+    # the pause sits in the emit phase, where the callback ran
+    assert r.phases()["emit"] >= r.thread.gc_seconds
+    after = eng.stats()
+    assert after["gc_collections"] >= before["gc_collections"] + 1
+    assert after["gc_pause_seconds"] - before["gc_pause_seconds"] \
+        >= r.thread.gc_seconds - 1e-6       # the snapshot rounds to 1 us
+
+
+# A thread's CPU time never passes its wall time, but the kernel splits
+# a thread's CPU into user and system time from scheduler ticks, so over
+# a call of a few milliseconds the sum it reports can run past the wall
+# clock by up to a tick (0.2 ms over 2.4 ms has been seen): the bound
+# keeps a tick of room on each call and a few per cent on their sum,
+# which still fails a CPU clock that counts other threads (the runtime's
+# workers) or another interval.
+TICK = 0.010
+
+
+def _within_wall(cpu, wall):
+    return cpu <= wall * 1.05 + TICK
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_the_cpu_clock_lies_within_the_wall_clock(model, layout):
+    eng = Engine(model, **LAYOUTS[layout])
+    _churn(eng)
+    m = eng.metrics
+    calls = list(m.steps) + list(m.submits)
+    for r in calls:
+        assert _within_wall(r.thread.cpu, r.end - r.begin)
+        assert min(r.thread) >= 0
+    assert sum(r.thread.cpu for r in calls) <= 1.05 * sum(
+        r.end - r.begin for r in calls) + TICK
+    for r in m.admits:
+        assert r.begin <= r.placed <= r.end
+    snap = eng.stats()
+    walls = {"step": sum(snap["step_phase_seconds"].values()),
+             "submit": snap["submit_seconds"]}
+    assert set(snap["host_cpu_seconds"]) == set(walls)
+    for call, cpu in snap["host_cpu_seconds"].items():
+        assert 0 < cpu and _within_wall(cpu, walls[call]), call
+
+
+def test_admissions_are_recorded_in_submit_and_in_steps(model):
+    """Each admission leaves one record, inside ``submit()`` (no step) or
+    inside a step; a radix hit shows as ``shared``, an eviction as
+    ``evicted``, counted as the cache counts them."""
+    eng = Engine(model, **dict(GEO, n_blocks=10))
+    system = _prompts([8], 14)[0]
+    turns = _prompts([12, 13, 14, 15, 16, 17, 12, 13, 14], 15)
+    handles = []
+    for i in range(0, len(turns), 3):   # three requests for two slots
+        handles += [eng.submit(np.concatenate([system, t]),
+                               max_new_tokens=4) for t in turns[i:i + 3]]
+        eng.drain()
+    assert all(h.finish_reason == "length" for h in handles)
+    admits = list(eng.metrics.admits)
+    # a request the pool could not cover at once is tried again later
+    assert set(a.request_id for a in admits) == set(
+        h.request_id for h in handles)
+    assert {a.step is None for a in admits} == {True, False}
+    steps = {r.index for r in eng.metrics.steps}
+    assert all(a.step in steps for a in admits if a.step is not None)
+    m = eng.metrics
+    assert sum(a.shared for a in admits) == m.prefix_hit_tokens > 0
+    # the rest of the index's evictions made room for decode lines
+    assert 0 < sum(a.evicted for a in admits) <= eng.cache.radix.evicted
+    # every admission inside a submit() lies inside that call's record
+    calls = {r.request_id: r for r in m.submits}
+    for a in admits:
+        if a.step is None:
+            c = calls[a.request_id]
+            assert c.begin <= a.begin <= a.end <= c.end
