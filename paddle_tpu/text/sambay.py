@@ -242,25 +242,24 @@ def attention_prefill(x, lw, *, n_heads, n_kv, eps, layer, window=None,
     return _combine(x, o, lw, layer, eps), (k, v)
 
 
-def _scatter(pool, dest, lines):
-    """``lines`` ``[R, n, w]`` into ``pool`` ``[nb, rows, w]``, slab ``j``
-    at rows ``dest[j] * n`` on: with ``n`` KV pairs a line and ``dest`` a
-    line's place (``block * bs + line in the block``) a line a slab
-    (decode's write: a line a slot); with ``n == rows`` and ``dest`` block
-    ids a whole block a slab. A pool is held folded like this, lines and
-    KV pairs of a block one run of rows, because that is the form the
-    decode kernel reads and the pairs (10 at the published sizes) fill no
-    whole tile: held ``[nb, bs, n, w]`` the chip's compiler lays the
-    lines out minor to the pairs and relays the whole pool, padded to 16
-    pairs, around every scatter (4.9 GB of temporaries in the decode
-    program, compiled for a described v5e)."""
-    nb, rows, w = pool.shape
-    return jax.lax.scatter(
-        pool.reshape(nb * rows, w), (dest * lines.shape[1])[:, None], lines,
-        jax.lax.ScatterDimensionNumbers(
-            update_window_dims=(1, 2), inserted_window_dims=(),
-            scatter_dims_to_operand_dims=(0,)),
-        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS).reshape(pool.shape)
+def _scatter(kc, vc, dest, k, v):
+    """Decode's write, a line a slot: ``k`` / ``v`` ``[R, n, w]`` into the
+    folded pools ``kc`` / ``vc`` ``[nb, rows, w]``, line ``j`` at line
+    place ``dest[j]`` (``block * bs + line in the block``), ``n`` rows at
+    row ``dest[j] * n``. A pool is held folded like this, lines and KV
+    pairs of a block one run of rows, because that is the form the decode
+    kernel reads and the pairs (10 at the published sizes) fill no whole
+    tile: held ``[nb, bs, n, w]`` the chip's compiler lays the lines out
+    minor to the pairs and relays the whole pool, padded to 16 pairs,
+    around every scatter (4.9 GB of temporaries in the decode program,
+    compiled for a described v5e). The lines go through one kernel call
+    for K and V (``ops/pallas/line_write.py``): a decode step's 18 writes
+    take 0.21 ms on a TPU v5e, where a scatter of a ``[n, w]`` window a
+    line, a ``while`` loop of one iteration a line, took 4.45 ms, and a
+    scatter of the line's ``n`` rows, a row an index, 0.91 ms
+    (``tools/time_pool_writes.py``)."""
+    from ..ops.pallas import line_write
+    return line_write.write_lines((kc, vc), dest, (k, v))
 
 
 def _lines(pool, blocks, n):
@@ -272,11 +271,16 @@ def _lines(pool, blocks, n):
 
 def _put_blocks(pool, blocks, lines):
     """``lines`` ``[len(blocks) * bs, n, w]`` as whole blocks to the blocks
-    ``blocks`` of a folded pool: one slab a block. A prompt's rows are
-    written so (a chunk is 32 blocks where it is 512 lines): a scatter
-    runs a slab at a time on the chip, 3.3 us each, and line by line the
-    writes were 34 of a chunk's 69 ms (my chip run, PR 35)."""
-    return _scatter(pool, blocks, lines.reshape((-1,) + pool.shape[1:]))
+    ``blocks`` of a folded pool, the block index the scatter's inserted
+    dimension: one scatter fusion in place on the chip. A prompt's rows
+    are written so (a chunk is 32 blocks where it is 512 lines): line by
+    line they were 9216 windows of a ``while`` loop, 34 of a chunk's 69
+    ms, and a block as one ``[rows, w]`` window of the flat pool still
+    made a loop of one iteration a block: on a TPU v5e a chunk's 18
+    writes of 32 blocks take 0.15 ms as fusions, 2.31 ms as windows
+    (``tools/time_pool_writes.py``)."""
+    return pool.at[blocks].set(lines.reshape((-1,) + pool.shape[1:]),
+                               mode="promise_in_bounds")
 
 
 def pool_write(pool, table_row, lines, first, n_keep, block_size):
@@ -309,7 +313,7 @@ def ring_write(pool, ring_row, lines, first, n_keep, *, window, old=None):
     of them tokens): ring line ``i`` takes the last kept position that is
     ``i`` mod ``window``, where there is one, and keeps what it held
     (``old`` ``[window, n, w]``; nothing, for a prompt's first rows)
-    where there is none. The ring is written back whole, a block a slab
+    where there is none. The ring is written back whole, in whole blocks
     (:func:`_put_blocks`)."""
     at = jnp.arange(window)
     last = first + n_keep - 1
@@ -416,7 +420,7 @@ def attention_decode(x, lw, kc, vc, tables, dest, seen, *, n_heads, n_kv,
     ring holds the window and nothing else. Returns ``(x, kc, vc)``."""
     q, k, v = _project(x, lw, n_heads, n_kv, eps)
     if k is not None:
-        kc, vc = _scatter(kc, dest, k[:, 0]), _scatter(vc, dest, v[:, 0])
+        kc, vc = _scatter(kc, vc, dest, k[:, 0], v[:, 0])
     from ..ops.pallas import paged_attention as kernel
 
     # the kernel's own form of a pool, which it folds again: no copy

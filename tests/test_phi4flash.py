@@ -164,6 +164,113 @@ def test_ring_write_leaves_the_newest_lines():
     assert np.all(np.asarray(got[1:3]) == 0)        # the other slot's
 
 
+def _window_scatter(pool, dest, lines):
+    """The writes' earlier form: slab ``j`` of ``lines`` ``[R, n, w]`` as
+    one scatter window at rows ``dest[j] * n`` of the flat pool."""
+    nb, rows, w = pool.shape
+    return jax.lax.scatter(
+        pool.reshape(nb * rows, w), (dest * lines.shape[1])[:, None], lines,
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1, 2), inserted_window_dims=(),
+            scatter_dims_to_operand_dims=(0,)),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS).reshape(pool.shape)
+
+
+def _window_blocks(pool, blocks, lines):
+    return _window_scatter(pool, blocks,
+                           lines.reshape((-1,) + pool.shape[1:]))
+
+
+_BS, _N, _W, _WINDOW = 4, 3, 8, 8
+
+
+def _random_pool(blocks, seed):
+    return jax.random.normal(jax.random.PRNGKey(seed),
+                             (blocks, _BS * _N, _W)).astype(jnp.bfloat16)
+
+
+def _random_lines(rows, seed):
+    return jax.random.normal(jax.random.PRNGKey(seed),
+                             (rows, _N, _W)).astype(jnp.bfloat16)
+
+
+def _decode_lines(pool_blocks, per, n_slots, inactive):
+    """64 lines a line a slot, as the decode program places them: an
+    active slot at its own block's line, an inactive one in the trash
+    block, at the line its position names."""
+    pos = jnp.arange(n_slots) * 7 % (per * _BS)
+    owned = (1 + jnp.arange(n_slots) * per + pos // _BS) * _BS + pos % _BS
+    active = jnp.arange(n_slots) % inactive != 0
+    dest = jnp.where(active, owned, pos % _BS)
+    pool = _random_pool(pool_blocks, 1)
+    lines = _random_lines(n_slots, 2)
+    return pool, dest, lines
+
+
+def _decode_k(pool, dest, lines):
+    """K's pool after decode's write of K and V lines."""
+    return S._scatter(pool, -pool, dest, lines, -lines)[0]
+
+
+def _decode_v(pool, dest, lines):
+    """V's pool after decode's write of K and V lines."""
+    return S._scatter(-pool, pool, dest, -lines, lines)[1]
+
+
+def _window_pair(kc, vc, dest, k, v):
+    return _window_scatter(kc, dest, k), _window_scatter(vc, dest, v)
+
+
+def _write_cases():
+    """``(name, write, args, per)``: a write of ``text/sambay.py`` and its
+    arguments; ``per`` blocks to a run that begins with a trash block."""
+    table = 1 + jnp.arange(4, dtype=jnp.int32)
+    rings = S.ring_tables(3, _WINDOW, _BS)
+    nbw = 1 + 3 * _WINDOW // _BS
+    yield ("decode_64_lines_some_inactive", _decode_k,
+           _decode_lines(1 + 64 * 4, 4, 64, 5), 1 + 64 * 4)
+    yield ("decode_into_the_second_ring", _decode_v,
+           (_random_pool(2 * nbw, 3),
+            nbw * _BS + jnp.array([0, 9, 13, 17, 2, 1]),
+            _random_lines(6, 4)), nbw)
+    yield ("blocks", S._put_blocks,
+           (_random_pool(9, 5), jnp.array([3, 0, 7, 1, 0]),
+            _random_lines(5 * _BS, 6)), 9)
+    # a prompt shorter than a block, at position 0
+    yield ("prompt_shorter_than_a_block",
+           lambda p, r, x: S.pool_write(p, r, x, 0, 3, _BS),
+           (_random_pool(5, 7), table, _random_lines(3, 8)), 5)
+    # 11 tokens of a 16-row chunk from position 4: blocks past them to trash
+    yield ("chunk_with_blocks_past_the_prompt",
+           lambda p, r, x: S.pool_write(p, r, x, 4, 11, _BS),
+           (_random_pool(5, 9), table, _random_lines(16, 10)), 5)
+    # positions 5..17 into a ring of 8: wrapped past its window
+    yield ("ring_wrapped_past_its_window",
+           lambda p, r, x: S.ring_write(p, r, x, 5, 13, window=_WINDOW,
+                                        old=_random_lines(_WINDOW, 11)),
+           (_random_pool(nbw, 12), rings[1], _random_lines(13, 13)), nbw)
+    yield ("ring_first_rows",
+           lambda p, r, x: S.ring_write(p, r, x, 0, 5, window=_WINDOW),
+           (_random_pool(nbw, 14), rings[2], _random_lines(6, 15)), nbw)
+
+
+@pytest.mark.parametrize("case", list(_write_cases()),
+                         ids=lambda case: case[0])
+def test_pool_writes_are_the_window_forms_bytes(case, monkeypatch):
+    """The writes into a folded pool (decode's lines, whole blocks by an
+    inserted block index) leave the same bytes as one scatter window a
+    slab did, but in the trash blocks: inactive rows and blocks past a
+    prompt all write there, so their indices repeat."""
+    _, write, args, per = case
+    got = np.asarray(jax.jit(write)(*args))
+    monkeypatch.setattr(S, "_scatter", _window_pair)
+    monkeypatch.setattr(S, "_put_blocks", _window_blocks)
+    want = np.asarray(jax.jit(write)(*args))
+    keep = np.arange(got.shape[0]) % per != 0
+    np.testing.assert_array_equal(got[keep], want[keep])
+    assert not np.array_equal(got[keep], np.asarray(args[0])[keep])
+
+
 def test_a_scan_in_two_chunks_is_the_scan_in_one(built):
     model, _ = built
     w = model.stacked_weights()
@@ -347,6 +454,43 @@ def test_engine_decodes_through_the_kernel_as_through_the_gathered_form(
     # over the pool: eight query rows of 128 against two KV pairs
     rings, pool = (2 * (1 + 2 * 2), 8, 2, 128), (2 * 6 + 1, 8, 2, 128)
     assert traced == [((2, 8, 128), rings)] * 2 + [((2, 8, 128), pool)] * 2
+    assert [h.tokens for h in handles] == [h.tokens for h in plain]
+    assert _worst_gap(weights, dataclasses.asdict(cfg), sample,
+                      handles) < 0.05
+
+
+def test_engine_writes_lines_through_the_kernel_as_through_the_scatter(
+        monkeypatch):
+    """On a TPU decode writes each attention layer's K and V lines through
+    one kernel call (``ops/pallas/line_write.py``); here through the
+    interpreter the tokens are the XLA scatter's and every call wrote K
+    and V of a line a slot into blocks of whole tiles."""
+    from paddle_tpu.ops.pallas import line_write as L
+    from paddle_tpu.serving import sambay_programs as sp
+
+    cfg = dataclasses.replace(CFG, hidden_size=512, intermediate_size=256,
+                              mamba_d_state=4, sliding_window=16)
+    model, weights = _model(cfg, seed=9)
+    sample = [(_ids(n, seed=50 + n), 6) for n in (7, 21)]
+    how = dict(n_slots=2, max_len=48, block_size=8, prefill_chunk=16)
+
+    plain = _serve(model, sample, **how)[1]
+    traced, kernel = [], L.write_lines
+
+    def interpreted(pools, dest, lines):
+        traced.append(tuple(p.shape for p in pools))
+        return kernel(pools, dest, lines, interpret=True)
+
+    sp.DECODE.clear_cache()
+    monkeypatch.setattr(L, "write_lines", interpreted)
+    try:
+        handles = _serve(model, sample, **how)[1]
+    finally:
+        sp.DECODE.clear_cache()
+    # the two window layers' rings and the full layer's pool, K and V a
+    # call: blocks of 8 lines of 2 KV pairs, 16 rows of 128 lanes
+    assert len(traced) == 3
+    assert all(len(t) == 2 and t[0][1:] == (16, 128) for t in traced)
     assert [h.tokens for h in handles] == [h.tokens for h in plain]
     assert _worst_gap(weights, dataclasses.asdict(cfg), sample,
                       handles) < 0.05

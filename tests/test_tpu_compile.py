@@ -131,6 +131,16 @@ def _grouped_swiglu(T, held, h, f):
                      ((held, h, f), jnp.bfloat16), ((held, f, h), jnp.bfloat16)]
 
 
+def _write_lines():
+    """Decode's line writes of the hybrid cell: K and V of a line a slot
+    (10 KV pairs of 128) into the one KV layer's folded pools, 64 slots."""
+    from paddle_tpu.ops.pallas.line_write import write_lines
+    pool = ((512 * 64 + 1, 16 * 10, 128), jnp.bfloat16)
+    lines = ((64, 10, 128), jnp.bfloat16)
+    return (lambda kc, vc, dest, k, v: write_lines((kc, vc), dest, (k, v)),
+            [pool, pool, ((64,), jnp.int32), lines, lines])
+
+
 def _stochastic(name):
     from paddle_tpu.nn import quant
     return (functools.partial(getattr(quant, name), seed=7),
@@ -152,6 +162,8 @@ _CASES = {
         _grouped_swiglu, 16, 64, 2304, 896),
     "grouped_swiglu-12_held_512_rows": functools.partial(
         _grouped_swiglu, 512, 12, 7168, 2048),
+    # phi-4-mini-flash-reasoning: 64 slots x 8192, blocks of 16 lines
+    "write_lines-10_pairs": _write_lines,
     "fused_ce_loss-fwd": functools.partial(_fused_ce, False),
     "fused_ce_loss-grad": functools.partial(_fused_ce, True),
     "int8_linear": _int8_linear,
@@ -650,6 +662,19 @@ def _sambay_program(kind):
              state], statics)
 
 
+def _loops_writing(text, sizes):
+    """The ``while`` loops of a compiled program whose body makes a bf16
+    value of one of ``sizes`` elements: which write such an operand, where
+    a loop that only reads one passes it through."""
+    bodies = dict(re.findall(r"^(%\S+) [^\n]*\{\n(.*?)^\}", text,
+                             re.M | re.S))
+    made = re.compile(r"= bf16\[([\d,]+)\]\S* (?!parameter|get-tuple-"
+                      r"element|tuple|bitcast)[\w-]+\(")
+    return [body for body in re.findall(r" while\(.*body=(%[\w.-]+)", text)
+            if any(int(np.prod([int(d) for d in shape.split(",")])) in sizes
+                   for shape in made.findall(bodies[body]))]
+
+
 @pytest.mark.parametrize("kind", ("decode", "chunk", "prefill"))
 def test_hybrid_program_compiles_and_moves_no_pool(kind, one_chip,
                                                    no_compile_cache):
@@ -660,7 +685,8 @@ def test_hybrid_program_compiles_and_moves_no_pool(kind, one_chip,
     padded, around every scatter: 4.9 GB of temporaries); the three
     programs fit beside 12.0 GB of weights and state; the decode program
     holds the paged kernel once an attention layer (8 window layers over
-    the rings, the full layer and 7 query-only layers over the pool)."""
+    the rings, the full layer and 7 query-only layers over the pool) and
+    the line-writing kernel once a layer with keys."""
     fn, shapes, statics = _sambay_program(kind)
     args = jax.tree.map(
         lambda sd: jax.ShapeDtypeStruct(*sd, sharding=one_chip), shapes,
@@ -681,11 +707,18 @@ def test_hybrid_program_compiles_and_moves_no_pool(kind, one_chip,
         r"= \w+\[([\d,]+)\]\S* (copy|slice|dynamic-slice)\(", text)
         if int(np.prod([int(d) for d in shape.split(",")])) in sizes]
     assert not moved
+    # every write into the pool or a ring is one scatter fusion in place,
+    # no ``while`` loop of one iteration a slab (a scatter window a slab
+    # made one a write: 18 in each program; the chunk's walks over the pool
+    # carry it and only read it)
+    assert not _loops_writing(text, sizes)
     kernels = len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
                              text))
-    assert kernels == (16 if kind == "decode" else 0)
+    assert kernels == (16 + 9 if kind == "decode" else 0)
     if kind == "decode":
-        assert "paged_attention" in text
+        assert len(re.findall(r"%paged_attention[.\d]* = ", text)) == 16
+        # K and V of a line a slot, one call an attention layer with keys
+        assert len(re.findall(r"%write_lines[.\d]* = ", text)) == 9
         assert not re.search(r"\[64,8192,", text)       # no gathered view
 
 
